@@ -863,6 +863,8 @@ def main() -> None:
                          "fw_kernel_analytics rows to PATH (use --json-dir)")
     args = ap.parse_args()
     SCALE, REPS = args.scale, args.reps
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     print("name,us_per_call,derived")
     for bench in select_benches(BENCHES, args.filter, args.only):
         t0 = time.time()

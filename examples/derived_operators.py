@@ -14,6 +14,7 @@ derived quantities riding the same store.
     PYTHONPATH=src python examples/derived_operators.py [--n 192]
 """
 import argparse
+import os
 import time
 
 import numpy as np
@@ -23,9 +24,11 @@ from repro.analytics import query
 from repro.analytics.engine import BatchedAnalytics
 from repro.core import by_name, expr
 from repro.store import FieldStore
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=192)
     args = ap.parse_args()

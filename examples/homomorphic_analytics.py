@@ -7,6 +7,7 @@ reports ratio / throughput / error vs full decompression.
     PYTHONPATH=src python examples/homomorphic_analytics.py [--scale 16]
 """
 import argparse
+import os
 import time
 
 import numpy as np
@@ -18,9 +19,11 @@ from repro.core import region as region_mod
 from repro.data.scientific import DATASETS, ScientificStore, dataset_dims
 from repro.serve import AnalyticsFrontend, AnalyticsRequest
 from repro.store import FieldStore
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=16)
     ap.add_argument("--rel-eb", type=float, default=1e-3)

@@ -2,6 +2,7 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+import os
 import time
 
 import numpy as np
@@ -9,9 +10,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import Stage, hszp_nd, hszx_nd, homomorphic as H
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     # a smooth 2-D field with noise (think: sea-surface temperature)
     rng = np.random.default_rng(0)
     g = np.linspace(0, 4 * np.pi, 1200)

@@ -12,12 +12,15 @@ spec's symbol bounds admit:
   on the array edges, and every grid symbol distinguishes the output index
   map (no write races between grid cells) — except where a spec declares
   the sequential-accumulator pattern (``sequential_revisit``);
+* **tiling** — every block's last two dims are multiples of ``(8, 128)``
+  or equal to the array's dims (the last dim alone for 1-D blocks): the
+  rule Mosaic enforces when it compiles the kernel for a TPU;
 * **VMEM** — the declared worst-case per-cell footprint fits the budget
   (default 16 MiB, the per-core VMEM size) under the audit envelope;
-* **unpack lemma** — the in-kernel bitplane unpack's guarded carry read
-  (``words[widx + 1]``) never escapes the ``WPB_EXTRA``-padded word
-  window, by bounded-exhaustive sweep over every (bits, in-word offset,
-  band-length residue) combination;
+* **unpack lemma** — the in-kernel bitplane unpack's low and carry word
+  reads stay inside each lane tile's ``4*bits + WPB_EXTRA`` words, and
+  that window fits one 128-lane gather, by bounded-exhaustive sweep over
+  every (bits, in-word offset) combination;
 * **no output multiply** — no float multiply is the final op feeding an
   output ref (the FMA-contraction hazard PR 8 debugged bitwise: XLA's CPU
   fusion duplicates a trailing kernel multiply into downstream consumers
@@ -35,7 +38,6 @@ which surfaces as a finding to fix or respecify, never silence).
 from __future__ import annotations
 
 import ast
-import math
 import re
 from pathlib import Path
 
@@ -390,6 +392,36 @@ def _check_coverage(ctx: _SpecCtx) -> list[Finding]:
     return out
 
 
+#: Mosaic's block tiling: (sublane, lane) multiples of the last two dims.
+_TILING = (8, 128)
+
+
+def _divisible(p: Poly, k: int) -> bool:
+    """``k`` divides ``p`` for every value of its symbols (sufficient:
+    every coefficient is a multiple of ``k``)."""
+    return all(c % k == 0 for c in p.terms.values())
+
+
+def _check_tiling(ctx: _SpecCtx) -> list[Finding]:
+    out = []
+    for tile in ctx.spec.inputs + ctx.spec.outputs:
+        nd = len(tile.block)
+        for d, k in zip(range(max(0, nd - 2), nd), _TILING[-min(nd, 2):]):
+            blk = ctx.poly(tile.block[d])
+            if blk == ctx.poly(tile.extent[d]) or _divisible(blk, k):
+                continue
+            out.append(_finding(
+                "block-tiling", ctx.spec,
+                f"{tile.name!r} dim {d}: block {tile.block[d]} is neither a "
+                f"multiple of {k} nor the array extent {tile.extent[d]} — "
+                "the TPU compiler refuses such a block",
+                subject=f"{ctx.spec.name}.{tile.name}",
+                suggestion=f"size the block in multiples of {k} (declare the "
+                           "fact, e.g. r == 8*rq) or make it span the dim"))
+            break
+    return out
+
+
 def _check_vmem(ctx: _SpecCtx, env: Envelope, budget: int) -> list[Finding]:
     p = ctx.poly(ctx.spec.vmem_elems).subst(
         "F", Poly.const(env.max_field_elems))
@@ -420,39 +452,36 @@ def _check_vmem(ctx: _SpecCtx, env: Envelope, budget: int) -> list[Finding]:
 # ---------------------------------------------------------------------------
 
 def check_unpack_lemma(wpb_extra: int | None = None) -> list[Finding]:
-    """Prove the in-kernel unpack word window is wide enough.
+    """Prove the in-kernel unpack's per-tile word window is wide enough.
 
-    ``band_payload`` gives each band ``nv*bits // 32 + WPB_EXTRA`` words.
-    Writing ``nv*bits = 32*Q + m``, the last value's bit offset is
-    ``s0 + nv*bits - bits``, so its word index is ``Q + floor((s0 + m -
-    bits)/32)`` and a carry read adds one more.  Sweeping every
-    ``(bits, s0, m)`` in ``[1,32) x [0,32) x [0,32)`` covers all bands of
-    all lengths — offsets grow monotonically in the value index, so the
-    last value dominates.
+    ``bitpack.unpack_lanes`` unpacks 128-value lane tiles; tile ``c``
+    starts ``4*bits*c`` words into its row, and lane ``l`` reads the words
+    ``(s + l*bits) >> 5`` and one past it (the carry word, read
+    unconditionally), with ``s`` the row's in-word offset.  The tile's
+    values span ``Q = 4*bits`` words, so the window must hold
+    ``Q + WPB_EXTRA`` words and still fit one 128-lane gather.  Offsets
+    grow with the lane, so lane 127 dominates; sweeping every ``(bits,
+    s)`` in ``[1,32) x [0,32)`` covers all rows of all lengths.
     """
     if wpb_extra is None:
         from repro.kernels import specs as kspecs
         wpb_extra = kspecs.WPB_EXTRA
     for bits in range(1, 32):
+        q = 4 * bits
         for s0 in range(32):
-            for m in range(32):
-                d = s0 + m - bits
-                widx_rel = math.floor(d / 32)
-                shift = d % 32
-                carry = shift > 32 - bits
-                hi_read = widx_rel + (1 if carry else 0)
-                if max(widx_rel, hi_read) > wpb_extra - 1:
-                    return [Finding(
-                        _ANALYZER, "unpack-oob",
-                        f"in-kernel unpack at bits={bits}, in-word offset "
-                        f"{s0}, band-bit residue {m} reads relative word "
-                        f"Q{max(widx_rel, hi_read):+d} but the window has "
-                        f"only {wpb_extra} words past Q",
-                        subject="fused._unpack_span",
-                        file="src/repro/kernels/fused.py",
-                        suggestion="restore WPB_EXTRA = 2 in "
-                                   "repro.kernels.specs (offset word + "
-                                   "carry word)")]
+            hi_read = ((s0 + 127 * bits) >> 5) + 1 - q
+            if hi_read > wpb_extra - 1 or q + wpb_extra > 128:
+                return [Finding(
+                    _ANALYZER, "unpack-oob",
+                    f"in-kernel unpack at bits={bits}, in-word offset "
+                    f"{s0} reads relative word Q{hi_read:+d} but the "
+                    f"window has {wpb_extra} words past Q (and must fit "
+                    "128 lanes)",
+                    subject="bitpack.unpack_lanes",
+                    file="src/repro/kernels/bitpack.py",
+                    suggestion="restore WPB_EXTRA = 2 in "
+                               "repro.kernels.specs (offset word + "
+                               "carry word)")]
     return []
 
 
@@ -686,6 +715,7 @@ def check_spec(spec, env: Envelope = DEFAULT_ENVELOPE, *,
     findings = _check_halos(ctx)
     findings += _check_input_tiles(ctx)
     findings += _check_coverage(ctx)
+    findings += _check_tiling(ctx)
     findings += _check_vmem(ctx, env, vmem_budget_bytes)
     return findings
 

@@ -1,10 +1,4 @@
-"""Portability shims across the jax versions this repo supports.
-
-The code targets the current jax API (``jax.shard_map``,
-``jax.sharding.AxisType``); these wrappers degrade gracefully on older
-releases (>= 0.4.3x) where the same functionality lives under
-``jax.experimental.shard_map`` with ``check_rep``/``auto`` spellings.
-"""
+"""Thin wrappers over the jax API spellings this repo uses."""
 from __future__ import annotations
 
 
@@ -17,27 +11,10 @@ def shard_map(f, *, mesh, in_specs, out_specs,
     """``jax.shard_map`` with manual axes ``axis_names`` (all axes if None).
 
     ``check=None`` keeps the upstream default (replication checking ON) —
-    callers opt *out* explicitly, never silently.  On older jax this maps to
-    ``jax.experimental.shard_map.shard_map`` whose ``auto`` parameter is the
-    complement of ``axis_names`` and whose ``check_rep`` corresponds to
-    ``check_vma`` — except that old partial-auto shard_map cannot
-    replication-check, so ``auto`` forces ``check_rep=False`` there.
+    callers opt *out* explicitly, never silently.
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
-        if check is not None:
-            kwargs["check_vma"] = check
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {}
+    kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
     if check is not None:
-        kwargs["check_rep"] = check
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-            kwargs.setdefault("check_rep", False)  # unsupported with auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+        kwargs["check_vma"] = check
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

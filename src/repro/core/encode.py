@@ -123,16 +123,9 @@ def unpack_uniform(payload: jax.Array, n: int, bits: int) -> jax.Array:
         return jnp.zeros((n,), jnp.uint32)
     if bits == 32:
         return payload[:n].astype(jnp.uint32)
-    mask = jnp.uint32((1 << bits) - 1)
     offs = jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(bits)
     widx = (offs >> 5).astype(jnp.int32)
-    shift = offs & jnp.uint32(31)
-    pad = jnp.concatenate([payload, jnp.zeros((1,), jnp.uint32)])
-    lo = pad[widx] >> shift
-    carry = shift > jnp.uint32(32 - bits)
-    hi_shift = jnp.where(carry, jnp.uint32(32) - shift, jnp.uint32(31))
-    hi = jnp.where(carry, pad[widx + 1] << hi_shift, jnp.uint32(0))
-    return (lo | hi) & mask
+    return unpack_words(payload, widx, widx + 1, offs & jnp.uint32(31), bits)
 
 
 def encode_device(c: Compressed, bits: int) -> Encoded:
@@ -183,6 +176,22 @@ def decode_device(e: Encoded) -> Compressed:
 # region fast path: gather-unpack only the words covering a block subset
 # ---------------------------------------------------------------------------
 
+def unpack_words(words: jax.Array, pos0, pos1, shift, bits: int) -> jax.Array:
+    """Unpack values whose low / carry words sit at ``pos0`` / ``pos1`` of
+    ``words``, ``shift`` bits in (the packer's word/shift/mask arithmetic).
+
+    ``pos1`` may be ``len(words)``: that carry word reads as zero through a
+    fill-mode gather, never from a zero-padded copy of ``words`` — XLA
+    compiles a gather from a padded copy of a large payload slowly."""
+    mask = jnp.uint32(0xFFFFFFFF if bits == 32 else (1 << bits) - 1)
+    shift = jnp.asarray(shift)
+    lo = words[jnp.asarray(pos0)] >> shift
+    carry = shift > jnp.uint32(32 - bits)
+    hi_shift = jnp.where(carry, jnp.uint32(32) - shift, jnp.uint32(31))
+    hi = words.at[jnp.asarray(pos1)].get(mode="fill", fill_value=0)
+    return (lo | jnp.where(carry, hi << hi_shift, jnp.uint32(0))) & mask
+
+
 def unpack_gather(payload: jax.Array, *, word_idx=None, pos0, pos1, shift,
                   bits: int) -> jax.Array:
     """Unpack a *subset* of a uniform-width payload via static word gathers.
@@ -194,18 +203,11 @@ def unpack_gather(payload: jax.Array, *, word_idx=None, pos0, pos1, shift,
     ``payload`` *is* the gathered word set already (the sharded store's
     scatter/psum word merge produces exactly that — ``repro.shard.exec``).
     """
-    m = int(np.asarray(pos0).shape[0])
+    m = int(jnp.shape(pos0)[0])
     if bits == 0:
         return jnp.zeros((m,), jnp.uint32)
-    mask = jnp.uint32(0xFFFFFFFF if bits == 32 else (1 << bits) - 1)
-    gathered = payload if word_idx is None else payload[jnp.asarray(word_idx)]
-    words = jnp.concatenate([gathered, jnp.zeros((1,), jnp.uint32)])
-    shift = jnp.asarray(shift)
-    lo = words[jnp.asarray(pos0)] >> shift
-    carry = shift > jnp.uint32(32 - bits)
-    hi_shift = jnp.where(carry, jnp.uint32(32) - shift, jnp.uint32(31))
-    hi = jnp.where(carry, words[jnp.asarray(pos1)] << hi_shift, jnp.uint32(0))
-    return (lo | hi) & mask
+    words = payload if word_idx is None else payload[jnp.asarray(word_idx)]
+    return unpack_words(words, pos0, pos1, shift, bits)
 
 
 def decode_region(e: Encoded, plan) -> Compressed:
@@ -213,11 +215,18 @@ def decode_region(e: Encoded, plan) -> Compressed:
 
     ``plan`` is a :class:`repro.core.region.RegionPlan`; the result is the
     honest sub-field over the gathered blocks (metadata / bitwidths / valid
-    counts restricted to them), never the full residual array.
+    counts restricted to them), never the full residual array.  nd plans
+    address the payload by indices built on device
+    (:meth:`~repro.core.region.RegionPlan.value_words`); 1-D plans gather
+    their static word set.
     """
-    gi = plan.payload_gather(e.bits)
-    u = unpack_gather(e.payload, word_idx=gi.word_idx, pos0=gi.pos0,
-                      pos1=gi.pos1, shift=gi.shift, bits=e.bits)
+    if plan.scheme.is_nd and e.bits > 0:
+        w0, shift = plan.value_words(e.bits)
+        u = unpack_words(e.payload, w0, w0 + 1, shift, e.bits)
+    else:
+        gi = plan.payload_gather(e.bits)
+        u = unpack_gather(e.payload, word_idx=gi.word_idx, pos0=gi.pos0,
+                          pos1=gi.pos1, shift=gi.shift, bits=e.bits)
     residuals = unzigzag(u).reshape(plan.sub_padded_shape)
     return plan.assemble(residuals, e)
 

@@ -13,7 +13,8 @@ an XLA rule to fall back to, so disabling kernels can never make an op
 infeasible.
 
 Coverage matrix (2-D nd schemes only — 1-D partitioning has no spatial
-stencils, and rank != 2 fields fall back):
+stencils, and rank != 2 fields fall back, as do planes whose row count no
+multiple of 8 rows divides, see ``kernels.fused.band_rows``):
 
 =============  ==========================  ==========================
 op             lorenzo (HSZP_ND)           blockmean (HSZX_ND)
@@ -84,9 +85,18 @@ class FusedRule:
 
 def _covers_2d(ctx) -> bool:
     """Rank-2 nd fields only: the kernels are 2-D band kernels, and the
-    1-D schemes have no spatial stencils to fuse.  Judged on the container
-    layout (not ``ctx.sub``) so coverage never forces a decode."""
-    return ctx.scheme.is_nd and len(ctx.field.padded_shape) == 2
+    1-D schemes have no spatial stencils to fuse.  The plane the kernel
+    runs on (the region plan's gathered blocks, or the padded field) must
+    split into bands of a multiple of 8 rows (and of the block rows, for
+    the block-mean upsample).  Judged on the container layout and the
+    static plan (not ``ctx.sub``) so coverage never forces a decode."""
+    f = ctx.field
+    if not (ctx.scheme.is_nd and len(f.padded_shape) == 2):
+        return False
+    shape = (ctx.plan.sub_padded_shape if ctx.plan is not None
+             else f.padded_shape)
+    mult = f.block[0] if ctx.scheme.is_blockmean else 1
+    return fk.band_rows(*shape, mult) is not None
 
 
 def _payload2(ctx) -> bool:

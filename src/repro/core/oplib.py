@@ -207,10 +207,14 @@ class StageContext:
                 # encode.decode_region, so the result is bit-identical to
                 # gathering from the resident single-device payload
                 e = self.field
-                gi = self.plan.payload_gather(e.bits)
+                if self.plan.scheme.is_nd and e.bits > 0:
+                    pos0, pos1, shift = self.plan.gathered_positions(e.bits)
+                else:
+                    gi = self.plan.payload_gather(e.bits)
+                    pos0, pos1, shift = gi.pos0, gi.pos1, gi.shift
                 u = encode_mod.unpack_gather(
-                    self._words, word_idx=None, pos0=gi.pos0, pos1=gi.pos1,
-                    shift=gi.shift, bits=e.bits)
+                    self._words, word_idx=None, pos0=pos0, pos1=pos1,
+                    shift=shift, bits=e.bits)
                 residuals = encode_mod.unzigzag(u).reshape(
                     self.plan.sub_padded_shape)
                 return self.plan.assemble(residuals, e)

@@ -23,7 +23,11 @@ homomorphic operators reuse their existing stage arithmetic on it:
 All plan geometry (block ranges, flat indices, payload word indices, window
 index maps, statistic weights) is computed host-side with numpy from static
 shapes, memoized, and enters traced code only as constants — region ops stay
-``jit``/``vmap``-composable exactly like their full-field counterparts.
+``jit``/``vmap``-composable exactly like their full-field counterparts.  The
+one exception is the per-value payload addressing of nd plans, which is
+affine in the box coordinates and built on device from iotas
+(:meth:`RegionPlan.value_words`): as host constants it made a 1/8 window of
+a 512^3 field a 1 GB program.
 """
 from __future__ import annotations
 from collections.abc import Sequence
@@ -78,7 +82,8 @@ class GatherIndex:
 
     ``word_idx`` are the only payload words touched; ``pos0``/``pos1``/
     ``shift`` address each gathered value's (<= 2) word contributions within
-    that gathered word set (``pos1`` may point at the appended zero word).
+    that gathered word set (``pos1`` may point one past it: a zero word).
+    nd plans leave them ``None`` and build them on device instead.
     """
 
     def __init__(self, word_idx: np.ndarray, pos0: np.ndarray, pos1: np.ndarray,
@@ -205,9 +210,26 @@ class RegionPlan:
 
     # -- payload word gather (Encoded fast path) ----------------------------
     def payload_gather(self, bits: int) -> GatherIndex:
-        """Static word-gather arrays for a uniform-width payload at ``bits``."""
+        """Static word-gather arrays for a uniform-width payload at ``bits``.
+
+        nd plans (``bits > 0``) carry only ``word_idx``: their per-value
+        positions are built on device (:meth:`value_words`,
+        :meth:`gathered_positions`), and the gathered words are the union of
+        each run's contiguous word range, found without a per-value sort."""
         gi = self._gather_cache.get(bits)
         if gi is not None:
+            return gi
+        if self.scheme.is_nd and bits > 0:
+            a, b = self._run_words(bits)
+            prev_end = np.concatenate([[-1], np.maximum.accumulate(b)[:-1]])
+            start = np.maximum(a, prev_end + 1)
+            length = np.maximum(b - start + 1, 0)
+            offset = np.cumsum(length) - length
+            word_idx = (np.repeat(start - offset, length)
+                        + np.arange(int(length.sum()), dtype=np.int64))
+            gi = GatherIndex(word_idx.astype(np.int32), None, None, None,
+                             self.gathered_elems)
+            self._gather_cache[bits] = gi
             return gi
         if self.scheme.is_nd:
             axes = [np.arange(lo * b, hi * b)
@@ -235,6 +257,71 @@ class RegionPlan:
                              (offs & 31).astype(np.uint32), m)
         self._gather_cache[bits] = gi
         return gi
+
+    def _run_words(self, bits: int) -> tuple[np.ndarray, np.ndarray]:
+        """First and last payload word each run of an nd plan touches (a run
+        is the gathered values along the last axis, in sub-field order):
+        ``w0`` of its first value, and ``w0 + 1`` of its last, clipped to
+        the payload."""
+        total = encode.words_for(int(np.prod(self.padded_shape)), bits)
+        first = np.zeros(self.sub_padded_shape[:-1], np.int64)
+        stride = int(np.prod(self.padded_shape[1:], dtype=np.int64))
+        for a, ((lo, hi), b) in enumerate(zip(self.grid_ranges[:-1],
+                                              self.block[:-1])):
+            ax = (np.arange((hi - lo) * b, dtype=np.int64) + lo * b) * stride
+            first = first + ax.reshape([-1 if i == a else 1
+                                        for i in range(first.ndim)])
+            stride //= self.padded_shape[a + 1]
+        first = first.reshape(-1) + self.grid_ranges[-1][0] * self.block[-1]
+        last = first + self.sub_padded_shape[-1] - 1
+        return (first * bits) >> 5, np.minimum(((last * bits) >> 5) + 1,
+                                               total - 1)
+
+    def value_words(self, bits: int) -> tuple[jax.Array, jax.Array]:
+        """(low word, in-word bit shift) of every gathered value of an nd
+        plan, in sub-field order, built on device from iotas.
+
+        The per-value arrays of :meth:`payload_gather` enter a program as
+        constants; for a large window (1/8 of a 512^3 field) that is
+        hundreds of MB of program.  An nd plan gathers a box, so each
+        value's flat index is affine in its box coordinates and needs no
+        host array.  ``idx*bits`` is split as ``(idx >> 5)*bits +
+        ((idx & 31)*bits >> 5)`` so no int32 product overflows.
+        """
+        nd = len(self.block)
+        idx, stride = None, 1
+        for a in reversed(range(nd)):
+            (lo, hi), b = self.grid_ranges[a], self.block[a]
+            ax = (jnp.arange((hi - lo) * b, dtype=jnp.int32) + lo * b) * stride
+            term = ax.reshape([-1 if i == a else 1 for i in range(nd)])
+            idx = term if idx is None else idx + term
+            stride *= self.padded_shape[a]
+        # the barrier keeps XLA from folding these data-independent arrays
+        # into program constants at compile time (slow, and large)
+        idx = jax.lax.optimization_barrier(idx.reshape(-1))
+        frac = (idx & 31) * bits
+        return (idx >> 5) * bits + (frac >> 5), (frac & 31).astype(jnp.uint32)
+
+    def gathered_positions(self, bits: int):
+        """``(pos0, pos1, shift)`` of :meth:`payload_gather`, for an nd
+        plan, computed on device.
+
+        The gathered words are ``payload_gather(bits).word_idx`` (sorted,
+        unique).  A run of values along the last axis touches one
+        contiguous word range, so within a run ``pos0 = w0 + delta_r`` with
+        one host offset per run (the word's rank in the gathered set minus
+        the word itself), and ``pos1 = pos0 + 1`` unless ``w0 + 1`` is past
+        the payload (then the appended zero word)."""
+        total = encode.words_for(int(np.prod(self.padded_shape)), bits)
+        gi = self.payload_gather(bits)
+        w0, shift = self.value_words(bits)
+        run_len = self.sub_padded_shape[-1]
+        a_r = self._run_words(bits)[0]
+        delta = (np.searchsorted(gi.word_idx, a_r) - a_r).astype(np.int32)
+        pos0 = (w0.reshape(-1, run_len)
+                + jnp.asarray(delta)[:, None]).reshape(-1)
+        pos1 = jnp.where(w0 + 1 < total, pos0 + 1, gi.n_words)
+        return pos0, pos1, shift
 
     # -- sub-field assembly --------------------------------------------------
     def gather_metadata(self, c: Compressed | Encoded) -> jax.Array:

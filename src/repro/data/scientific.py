@@ -12,6 +12,7 @@ from collections.abc import Iterator
 
 import dataclasses
 import os
+import zlib
 
 import numpy as np
 import jax
@@ -29,18 +30,29 @@ DATASETS = {
 }
 
 
+def field_seed(name: str, field: int, seed: int = 0) -> int:
+    """RNG seed of one synthetic field, stable across processes (``hash``
+    of a ``str`` is randomized per process, ``crc32`` is not)."""
+    return zlib.crc32(f"{name}/{field}/{seed}".encode())
+
+
 def synth_field(name: str, field: int, dims: tuple[int, ...], seed: int = 0) -> np.ndarray:
-    """Multi-scale smooth field + noise (compression behaviour like real data)."""
-    rng = np.random.default_rng(hash((name, field, seed)) % (2 ** 32))
-    grids = np.meshgrid(*[np.linspace(0, 1, d, dtype=np.float32) for d in dims],
-                        indexing="ij")
+    """Multi-scale smooth field + noise (compression behaviour like real data).
+
+    Each octave is a sum of one sine per axis, so the sines are evaluated
+    on the 1-D axes and broadcast: the same values as on a full meshgrid,
+    without building one (a 512^3 field stays a few host arrays)."""
+    rng = np.random.default_rng(field_seed(name, field, seed))
+    axes = [np.linspace(0, 1, d, dtype=np.float32) for d in dims]
     out = np.zeros(dims, np.float32)
     for k in range(1, 5):  # superposed octaves
         phase = rng.uniform(0, 2 * np.pi, size=len(dims))
         freq = rng.uniform(1.5, 4.0) * (2.0 ** k)
         wave = np.zeros(dims, np.float32)
-        for g, ph in zip(grids, phase):
-            wave = wave + np.sin(2 * np.pi * freq * g + ph).astype(np.float32)
+        for a, (g, ph) in enumerate(zip(axes, phase)):
+            line = np.sin(2 * np.pi * freq * g + ph).astype(np.float32)
+            wave = wave + line.reshape([-1 if i == a else 1
+                                        for i in range(len(dims))])
         out += wave / (2.0 ** k)
     out += rng.normal(0, 0.02, dims).astype(np.float32)
     return out

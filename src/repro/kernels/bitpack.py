@@ -8,6 +8,13 @@ Each grid step packs ``VALS`` zigzag values at a static width ``bits`` into
 
 ``VALS`` is chosen so V*bits is a multiple of 32 for every bits in 1..32
 (V = multiple of 32) and the bit matrix fits VMEM comfortably.
+
+The unpacker — the one on the decode path — works on a 2-D layout instead:
+rows of ``ROW_VALS`` values, whose words start on a word boundary, unpacked
+by :func:`unpack_lanes` one 128-value lane tile at a time.  A tile of 128
+values spans exactly ``4*bits`` words, so every value's word lies in a
+128-word window of its row and the word fetch is a gather within one
+vector register (Mosaic's lane gather), never a gather across the row.
 """
 from __future__ import annotations
 
@@ -18,6 +25,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 VALS = 4096  # values per grid step; V*bits <= 128K int32 = 512 KiB VMEM
+LANES = 128
+ROW_VALS = 1024   # values per row of the unpacker's 2-D layout (8 lane tiles)
+ROW_BLOCK = 64    # rows per unpack grid step (64K values)
 
 
 def _words_for(n: int, bits: int) -> int:
@@ -34,12 +44,48 @@ def _pack_kernel(u_ref, o_ref, *, bits: int):
     o_ref[...] = jnp.sum(stream * powers[None, :], axis=1, dtype=jnp.uint32)
 
 
+def window_words(nv: int, bits: int) -> int:
+    """Word columns :func:`unpack_lanes` reads for a row of ``nv`` values:
+    the last lane tile's 128-word window starts at word ``4*bits*(T-1)``."""
+    return 4 * bits * (pl.cdiv(nv, LANES) - 1) + LANES
+
+
+def unpack_lanes(w: jax.Array, s, nv: int, bits: int) -> jax.Array:
+    """In-kernel unpack of one value row per word row (int32 throughout).
+
+    Value ``j`` of row ``i`` starts at bit ``s[i] + j*bits`` of the row's
+    words ``w[i]`` (``s``: ``(rows, 1)`` in-word offsets, or ``0``); ``w``
+    has at least :func:`window_words` columns.  Lane tile ``c`` holds
+    values ``128c ..``, which start ``128*bits*c`` bits = ``4*bits*c``
+    words in, so its words are a 128-word window of the row and each lane
+    fetches its low and carry words with a gather inside that window.  The
+    shifts and masks are ``encode.unpack_uniform``'s, so the integers are
+    the same bits.  Returns the ``(rows, nv)`` zigzag values.
+    """
+    rows = w.shape[0]
+    off = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1) * bits + s
+    lo_idx = off >> 5
+    hi_idx = lo_idx + 1
+    shift = off & 31
+    carry = shift > 32 - bits
+    hi_shift = jnp.where(carry, 32 - shift, 0)
+    mask = (1 << bits) - 1
+    tiles = []
+    for c in range(pl.cdiv(nv, LANES)):
+        win = w[:, 4 * bits * c:4 * bits * c + LANES]
+        lo = jnp.take_along_axis(win, lo_idx, axis=1,
+                                 mode="promise_in_bounds")
+        hi = jnp.take_along_axis(win, hi_idx, axis=1,
+                                 mode="promise_in_bounds")
+        v = (jax.lax.shift_right_logical(lo, shift)
+             | jnp.where(carry, jax.lax.shift_left(hi, hi_shift), 0))
+        tiles.append(v & mask)
+    out = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
+    return out[:, :nv]
+
+
 def _unpack_kernel(w_ref, o_ref, *, bits: int):
-    w = w_ref[...].astype(jnp.uint32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bitmat = ((w[:, None] >> shifts[None, :]) & jnp.uint32(1)).reshape(-1, bits)
-    powers = (jnp.uint32(1) << jnp.arange(bits, dtype=jnp.uint32))
-    o_ref[...] = jnp.sum(bitmat * powers[None, :], axis=1, dtype=jnp.uint32)
+    o_ref[...] = unpack_lanes(w_ref[...], 0, ROW_VALS, bits)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
@@ -77,26 +123,30 @@ def pack(u: jax.Array, bits: int, *, interpret: bool = False) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("n", "bits", "interpret"))
 def unpack(words: jax.Array, n: int, bits: int, *, interpret: bool = False) -> jax.Array:
-    """Inverse of :func:`pack` for any ``n`` (tail words zero-padded)."""
+    """Inverse of :func:`pack` for any ``n`` (tail words zero-padded).
+
+    The stream is cut into rows of ``ROW_VALS`` values (``32*bits`` words,
+    so every row starts on a word boundary), each row padded to the
+    :func:`window_words` columns the lane-tile unpack reads.
+    """
     if bits == 0:
         return jnp.zeros((n,), jnp.uint32)
     if bits == 32:
         return words[:n].astype(jnp.uint32)
-    pad = -n % VALS
-    n_pad = n + pad
-    words_per = VALS * bits // 32
-    nw_pad = n_pad * bits // 32
-    words = words.astype(jnp.uint32)
-    if words.shape[0] < nw_pad:
-        words = jnp.concatenate(
-            [words, jnp.zeros((nw_pad - words.shape[0],), jnp.uint32)])
-    grid = (n_pad // VALS,)
+    rows = pl.cdiv(pl.cdiv(n, ROW_VALS), 8) * 8
+    blk = min(ROW_BLOCK, rows)
+    rows = pl.cdiv(rows, blk) * blk
+    wpr = ROW_VALS * bits // 32
+    w = jax.lax.bitcast_convert_type(words.astype(jnp.uint32), jnp.int32)
+    w = jnp.pad(w[:rows * wpr], (0, max(0, rows * wpr - w.shape[0])))
+    w = jnp.pad(w.reshape(rows, wpr),
+                ((0, 0), (0, window_words(ROW_VALS, bits) - wpr)))
     out = pl.pallas_call(
         functools.partial(_unpack_kernel, bits=bits),
-        grid=grid,
-        in_specs=[pl.BlockSpec((words_per,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((VALS,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.uint32),
+        grid=(rows // blk,),
+        in_specs=[pl.BlockSpec((blk, w.shape[1]), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((blk, ROW_VALS), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, ROW_VALS), jnp.int32),
         interpret=interpret,
-    )(words[:nw_pad])
-    return out[:n]
+    )(w)
+    return out.reshape(-1)[:n].astype(jnp.uint32)

@@ -13,13 +13,13 @@ Each family has two kernel variants sharing one band body: the
 (``lorenzo_enc2d`` / ``blockmean_enc2d``) go one step further for
 :class:`~repro.core.stages.Encoded` fields — each grid cell takes its
 band's *gathered payload words*, bitplane-unpacks them in VMEM
-(``_unpack_span``, the same word/shift/mask arithmetic as
+(``bitpack.unpack_lanes``, the same word/shift/mask arithmetic as
 ``encode.unpack_uniform``, hence bit-identical integers), recorrelates,
 and writes only the stencil plane: decode + op in a single pass, with the
 residual plane never existing in HBM either.  Cross-band state stays
-tiny: halo rows are unpacked host-side at row cost, and the Lorenzo
-cross-band ``base`` prefix comes from a payload-input column-sum pass
-(int32 modular, so any summation order is exact).
+tiny: halo rows are unpacked outside the kernel at row cost, and the
+Lorenzo cross-band ``base`` prefix comes from a payload-input column-sum
+pass (int32 modular, so any summation order is exact).
 
 Design constraints (why these kernels look the way they do):
 
@@ -28,9 +28,19 @@ Design constraints (why these kernels look the way they do):
   which silently breaks ``pl.program_id``-keyed sequential carries (see
   ``prefix_stats.py``, which is why *that* kernel stays unwired).  Here
   every grid cell is independent: cross-band prefix state enters as a tiny
-  precomputed ``base`` input (exclusive band prefix of per-band column
-  sums, ``n_bands x n1`` — R× smaller than the D-plane it replaces), and
-  ±1-row halos enter as strided ``(n_bands, n1)`` row gathers.
+  precomputed ``base`` row (exclusive band prefix of per-band column
+  sums), and ±1-row halos enter as row gathers; both ride in one
+  ``(8, n1)`` halo tile per band.
+
+* **Mosaic-shaped.**  Every block's last two dims are multiples of
+  ``(8, 128)`` or the whole array dim: bands are multiples of 8 rows sized
+  by bytes (:func:`band_rows`), per-band rows travel as 8-row halo tiles,
+  and payload words as one word row per plane row (``row_words``).  Inside
+  a kernel only rolls, selects, broadcasts and elementwise int32/f32 math
+  run: row and column shifts are ``pltpu.roll`` plus a halo select,
+  prefix sums are log-step shifted adds (exact in modular int32), the
+  block-mean upsample is a sublane broadcast of a column-upsampled metadata
+  band, and the unpack gathers only inside 128-lane windows.
 
 * **Bit-identity via integer outputs.**  Each kernel emits the *exact
   integer* stencil plane (int32, modular — associative, so any in-kernel
@@ -56,74 +66,96 @@ Design constraints (why these kernels look the way they do):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-MAX_BAND = 256  # target rows per grid step (VMEM residency, f32 min-tile ok)
-_WORD_BITS = 32
+from .bitpack import unpack_lanes, window_words
+
+BAND_BYTES = 256 << 10  # target int32 bytes of one band plane in VMEM
+SUBLANES = 8
 
 
-def band_rows(n0: int, mult: int = 1) -> int:
-    """Largest divisor of ``n0`` that is a multiple of ``mult`` and at most
-    ``MAX_BAND`` (falls back to ``mult``, which always divides ``n0``)."""
-    g = n0 // mult
-    best = mult
-    for d in range(1, g + 1):
-        if g % d == 0 and mult * d <= MAX_BAND:
-            best = mult * d
+def band_rows(n0: int, n1: int, mult: int = 1) -> int | None:
+    """Rows per grid step: the largest multiple of ``lcm(8, mult)`` that
+    divides ``n0`` and keeps an int32 band within ``BAND_BYTES`` (at least
+    one such step), or ``None`` when no multiple of it divides ``n0`` — the
+    kernels then do not cover the field."""
+    step = math.lcm(SUBLANES, mult)
+    if n0 % step:
+        return None
+    best = step
+    for r in range(step, n0 + 1, step):
+        if n0 % r == 0 and r * n1 * 4 <= BAND_BYTES:
+            best = r
     return best
 
 
-def _row_halo(x: jax.Array, r: int, side: str) -> jax.Array:
-    """Per-band ±1 halo rows of ``x``: ``prev[b] = x[b*r - 1]`` (zeros for
-    band 0), ``next[b] = x[(b+1)*r]`` (zeros for the last band)."""
+def _bands(n0: int, n1: int, mult: int = 1) -> tuple[int, int]:
+    r = band_rows(n0, n1, mult)
+    if r is None:
+        raise ValueError(
+            f"no band of a multiple of {math.lcm(SUBLANES, mult)} rows "
+            f"divides {n0}; the fused kernels do not cover this shape")
+    return r, n0 // r
+
+
+def _halo_tiles(*rows: jax.Array) -> jax.Array:
+    """Stack per-band rows (each ``(nb, n)``) into the ``(nb*8, n)`` halo
+    array: band ``b``'s rows sit at ``8b, 8b+1, ...`` of its 8-row tile."""
+    nb, n = rows[0].shape
+    h = jnp.stack(rows, axis=1)
+    h = jnp.pad(h, ((0, 0), (0, SUBLANES - len(rows)), (0, 0)))
+    return h.reshape(nb * SUBLANES, n)
+
+
+def _prev_rows(x: jax.Array, r: int) -> jax.Array:
+    """``prev[b] = x[b*r - 1]`` (zeros for band 0)."""
     zero = jnp.zeros((1, x.shape[1]), x.dtype)
-    if side == "prev":
-        return jnp.concatenate([zero, x[r - 1::r][:-1]], axis=0)
+    return jnp.concatenate([zero, x[r - 1::r][:-1]], axis=0)
+
+
+def _next_rows(x: jax.Array, r: int) -> jax.Array:
+    """``next[b] = x[(b+1)*r]`` (zeros for the last band)."""
+    zero = jnp.zeros((1, x.shape[1]), x.dtype)
     return jnp.concatenate([x[r::r], zero], axis=0)
 
 
+def _exclusive_band_prefix(colsums: jax.Array) -> jax.Array:
+    zero = jnp.zeros((1, colsums.shape[1]), colsums.dtype)
+    return jnp.concatenate([zero, jnp.cumsum(colsums, axis=0)[:-1]], axis=0)
+
+
+def _iota(x: jax.Array, axis: int) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+
+
 def _shift_rows(x, prev, nxt):
-    """(x_{i-1}, x_{i+1}) with cross-band halo rows."""
-    up = jnp.concatenate([prev, x[:-1]], axis=0)
-    dn = jnp.concatenate([x[1:], nxt], axis=0)
+    """(x_{i-1}, x_{i+1}) with cross-band halo rows ``prev``/``nxt``."""
+    r = x.shape[0]
+    row = _iota(x, 0)
+    up = jnp.where(row == 0, prev, pltpu.roll(x, 1, 0))
+    dn = jnp.where(row == r - 1, nxt, pltpu.roll(x, r - 1, 0))
     return up, dn
 
 
 def _shift_cols(x):
     """(x_{j-1}, x_{j+1}); boundary columns are don't-care (sliced off)."""
-    zero = jnp.zeros((x.shape[0], 1), x.dtype)
-    left = jnp.concatenate([zero, x[:, :-1]], axis=1)
-    right = jnp.concatenate([x[:, 1:], zero], axis=1)
-    return left, right
+    return pltpu.roll(x, 1, 1), pltpu.roll(x, x.shape[1] - 1, 1)
 
 
-# ---------------------------------------------------------------------------
-# in-kernel bitplane unpack (payload-input kernel variants)
-# ---------------------------------------------------------------------------
-
-def _unpack_span(words: jax.Array, bit0: jax.Array, nv: int,
-                 bits: int) -> jax.Array:
-    """Unpack ``nv`` zigzag values starting ``bit0`` bits into ``words``.
-
-    Identical arithmetic to ``encode.unpack_uniform`` with the global bit
-    offset split into a word base (resolved by the caller's band gather)
-    and the residual in-word offset ``bit0`` — same words, same shifts,
-    same masks, so the recovered integers are bit-identical.
-    """
-    mask = jnp.uint32((1 << bits) - 1)
-    offs = (bit0.astype(jnp.uint32)
-            + jnp.arange(nv, dtype=jnp.uint32) * jnp.uint32(bits))
-    widx = (offs >> 5).astype(jnp.int32)
-    shift = offs & jnp.uint32(31)
-    lo = words[widx] >> shift
-    carry = shift > jnp.uint32(_WORD_BITS - bits)
-    hi_shift = jnp.where(carry, jnp.uint32(_WORD_BITS) - shift,
-                         jnp.uint32(31))
-    hi = jnp.where(carry, words[widx + 1] << hi_shift, jnp.uint32(0))
-    return (lo | hi) & mask
+def _cumsum(x: jax.Array, axis: int) -> jax.Array:
+    """Inclusive prefix sum by log-step shifted adds (Hillis-Steele):
+    exact in modular int32, so it equals ``jnp.cumsum`` bit for bit."""
+    idx = _iota(x, axis)
+    k = 1
+    while k < x.shape[axis]:
+        x = x + jnp.where(idx >= k, pltpu.roll(x, k, axis), 0)
+        k *= 2
+    return x
 
 
 def _unzigzag(u: jax.Array) -> jax.Array:
@@ -132,68 +164,94 @@ def _unzigzag(u: jax.Array) -> jax.Array:
     return (ui >> 1) ^ -(ui & 1)
 
 
-def band_payload(payload: jax.Array, nv: int, bits: int,
-                 nb: int) -> tuple[jax.Array, jax.Array]:
-    """Per-band payload word windows for in-kernel unpacking.
+# ---------------------------------------------------------------------------
+# payload words (payload-input kernel variants)
+# ---------------------------------------------------------------------------
 
-    Band ``b`` covers values ``[b*nv, (b+1)*nv)`` of the flat packed order;
-    its bits span at most ``nv*bits//32 + WPB_EXTRA`` words (+1 for the
-    in-word offset, +1 for the carry word — the width
-    ``repro.audit.kernelspec`` proves sufficient by exhaustive sweep).
-    Returns the ``(nb, wpb)`` word matrix and the ``(nb, 1)`` in-word bit
+def row_words(payload: jax.Array, n0: int, n1: int,
+              bits: int) -> tuple[jax.Array, jax.Array]:
+    """One payload word row per plane row, for in-kernel unpacking.
+
+    Row ``i``'s values start at bit ``i*n1*bits``: word ``w_i`` (split as
+    ``i*(A>>5) + (i*(A&31))>>5`` with ``A = n1*bits`` so no int32 product
+    overflows) and in-word offset ``s_i``.  Returns the ``(n0, W)`` int32
+    word matrix (``W`` = :func:`bitpack.window_words`) and the ``(n0, 1)``
     offsets — the only payload-sized transfer of the fused-decode path.
     """
-    from repro.kernels.specs import WPB_EXTRA
-    wpb = (nv * bits) // _WORD_BITS + WPB_EXTRA
-    bit0 = jnp.arange(nb, dtype=jnp.int32) * jnp.int32(nv * bits)
-    w0 = bit0 >> 5
-    s0 = bit0 & 31
-    pad = jnp.concatenate([payload, jnp.zeros((wpb,), jnp.uint32)])
-    words = pad[w0[:, None] + jnp.arange(wpb, dtype=jnp.int32)[None, :]]
-    return words, s0.reshape(nb, 1)
+    a = n1 * bits
+    i = jnp.arange(n0, dtype=jnp.int32)
+    frac = i * (a & 31)
+    w0 = i * (a >> 5) + (frac >> 5)
+    width = window_words(n1, bits)
+    idx = w0[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+    words = jax.lax.bitcast_convert_type(payload, jnp.int32).at[idx].get(
+        mode="fill", fill_value=0)
+    return words, (frac & 31).reshape(n0, 1)
+
+
+def _unpack_band(w_ref, s_ref, n1: int, bits: int) -> jax.Array:
+    return _unzigzag(unpack_lanes(w_ref[...], s_ref[...], n1, bits))
 
 
 def unpack_rows(payload: jax.Array, rows: jax.Array, n1: int,
                 bits: int) -> jax.Array:
     """Unpack whole rows of the padded plane (halo rows for the payload
-    kernels) — ``unpack_uniform``'s gather arithmetic restricted to the
-    requested rows, cost proportional to the rows, not the field."""
-    mask = jnp.uint32((1 << bits) - 1)
+    kernels) — ``unpack_uniform``'s arithmetic restricted to the requested
+    rows, cost proportional to the rows, not the field."""
+    from repro.core.encode import unpack_words  # core imports the kernels
+
     offs = ((rows[:, None].astype(jnp.uint32) * jnp.uint32(n1)
              + jnp.arange(n1, dtype=jnp.uint32)[None, :])
             * jnp.uint32(bits))
     widx = (offs >> 5).astype(jnp.int32)
-    shift = offs & jnp.uint32(31)
-    pad = jnp.concatenate([payload, jnp.zeros((1,), jnp.uint32)])
-    lo = pad[widx] >> shift
-    carry = shift > jnp.uint32(_WORD_BITS - bits)
-    hi_shift = jnp.where(carry, jnp.uint32(_WORD_BITS) - shift,
-                         jnp.uint32(31))
-    hi = jnp.where(carry, pad[widx + 1] << hi_shift, jnp.uint32(0))
-    return _unzigzag((lo | hi) & mask)
+    return _unzigzag(unpack_words(payload, widx, widx + 1,
+                                  offs & jnp.uint32(31), bits))
+
+
+def _specs(r: int, n1: int, width: int | None = None):
+    """BlockSpecs shared by the band kernels: the ``(r, n1)`` band, the
+    ``(8, n1)`` halo tile, and (payload variants) the ``(r, W)`` word rows
+    with their ``(r, 1)`` in-word offsets."""
+    band = pl.BlockSpec((r, n1), lambda b: (b, 0))
+    halo = pl.BlockSpec((SUBLANES, n1), lambda b: (b, 0))
+    if width is None:
+        return band, halo
+    words = pl.BlockSpec((r, width), lambda b: (b, 0))
+    offs = pl.BlockSpec((r, 1), lambda b: (b, 0))
+    return band, halo, words, offs
+
+
+def _outputs(band, n0: int, n1: int, dtype, what: str):
+    n_out = 2 if what == "grad" else 1
+    shape = jax.ShapeDtypeStruct((n0, n1), dtype)
+    if n_out == 1:
+        return band, shape
+    return [band] * n_out, [shape] * n_out
 
 
 # ---------------------------------------------------------------------------
-# Lorenzo family: residual band -> cumsum planes -> stencil, all in VMEM
+# Lorenzo family: residual band -> prefix-sum planes -> stencil, all in VMEM
 # ---------------------------------------------------------------------------
 
-def _lorenzo_core(p, ph_row, base_row, out_refs, what: str):
-    """Shared band body: D0 = cumsum(p, axis=1) (+1-row halo ``ph_row``),
-    D1 = base + cumsum(p, axis=0); emit the requested integer planes.
+def _lorenzo_core(p, h, out_refs, what: str):
+    """Shared band body: D0 = cumsum(p, axis=1), D1 = base + cumsum(p,
+    axis=0); emit the requested integer planes.  Halo tile ``h``: row 0 is
+    ``base`` (the exclusive column prefix of earlier bands), row 1 the
+    next band's first row of D0.
 
     Derivative planes are ``D[+1] + D[0]`` — identical integers at stages
     ②③④ (q[i+1]-q[i-1] telescopes to D[i+1]+D[i]); the laplacian plane is
     ``sum_a (D_a[+1] - D_a[0])``, Eq. V-B.3.
     """
+    r = p.shape[0]
     outs = iter(out_refs)
     if what in ("deriv0", "grad", "lap"):
-        da = jnp.cumsum(p, axis=1)
-        da_next = jnp.concatenate([da[1:], jnp.cumsum(ph_row, axis=1)],
-                                  axis=0)
+        da = _cumsum(p, 1)
+        da_next = jnp.where(_iota(da, 0) == r - 1, h[1:2],
+                            pltpu.roll(da, r - 1, 0))
     if what in ("deriv1", "grad", "lap"):
-        db = base_row + jnp.cumsum(p, axis=0)
-        db_next = jnp.concatenate(
-            [db[:, 1:], jnp.zeros((p.shape[0], 1), db.dtype)], axis=1)
+        db = h[0:1] + _cumsum(p, 0)
+        db_next = _shift_cols(db)[1]
     if what in ("deriv0", "grad"):
         next(outs)[...] = da_next + da
     if what in ("deriv1", "grad"):
@@ -202,26 +260,29 @@ def _lorenzo_core(p, ph_row, base_row, out_refs, what: str):
         next(outs)[...] = (da_next - da) + (db_next - db)
 
 
-def _lorenzo_kernel(p_ref, ph_ref, base_ref, *out_refs, what: str):
-    _lorenzo_core(p_ref[...], ph_ref[...], base_ref[...], out_refs, what)
+def _lorenzo_kernel(p_ref, h_ref, *out_refs, what: str):
+    _lorenzo_core(p_ref[...], h_ref[...], out_refs, what)
 
 
-def _lorenzo_enc_kernel(w_ref, s0_ref, ph_ref, base_ref, *out_refs,
-                        what: str, r: int, n1: int, bits: int):
-    """Payload-input variant: gathered band words -> in-kernel bitplane
-    unpack -> the same Lorenzo band body.  The residual plane exists only
-    in VMEM."""
-    p = _unzigzag(_unpack_span(w_ref[0], s0_ref[0, 0], r * n1,
-                               bits)).reshape(r, n1)
-    _lorenzo_core(p, ph_ref[...], base_ref[...], out_refs, what)
+def _lorenzo_enc_kernel(w_ref, s_ref, h_ref, *out_refs, what: str, n1: int,
+                        bits: int):
+    """Payload-input variant: band word rows -> in-kernel bitplane unpack
+    -> the same Lorenzo band body.  The residual plane exists only in
+    VMEM."""
+    _lorenzo_core(_unpack_band(w_ref, s_ref, n1, bits), h_ref[...],
+                  out_refs, what)
 
 
-def _colsum_enc_kernel(w_ref, s0_ref, o_ref, *, r: int, n1: int, bits: int):
+def _colsum_enc_kernel(w_ref, s_ref, o_ref, *, n1: int, bits: int):
     """Payload-input band column sums (the cross-band ``base`` prefix
     input) — int32 modular, so any summation order is exact."""
-    p = _unzigzag(_unpack_span(w_ref[0], s0_ref[0, 0], r * n1,
-                               bits)).reshape(r, n1)
-    o_ref[...] = jnp.sum(p, axis=0, keepdims=True)
+    p = _unpack_band(w_ref, s_ref, n1, bits)
+    o_ref[...] = jnp.broadcast_to(jnp.sum(p, axis=0, keepdims=True),
+                                  o_ref.shape)
+
+
+def _lorenzo_halo(base: jax.Array, nxt: jax.Array) -> jax.Array:
+    return _halo_tiles(base, jnp.cumsum(nxt, axis=1))
 
 
 @functools.partial(jax.jit, static_argnames=("what", "interpret"))
@@ -235,27 +296,19 @@ def lorenzo2d(p: jax.Array, *, what: str, interpret: bool = False):
     window the XLA lowering rules slice, then apply the float tail.
     """
     n0, n1 = p.shape
-    r = band_rows(n0)
-    nb = n0 // r
-    halo = _row_halo(p, r, "next")
-    band_sums = jnp.sum(p.reshape(nb, r, n1), axis=1)
-    base = jnp.concatenate(
-        [jnp.zeros((1, n1), p.dtype), jnp.cumsum(band_sums, axis=0)[:-1]],
-        axis=0)
-    band = pl.BlockSpec((r, n1), lambda b: (b, 0))
-    row = pl.BlockSpec((1, n1), lambda b: (b, 0))
-    n_out = 2 if what == "grad" else 1
-    out_spec = [band] * n_out
-    out_shape = [jax.ShapeDtypeStruct((n0, n1), p.dtype)] * n_out
-    out = pl.pallas_call(
+    r, nb = _bands(n0, n1)
+    base = _exclusive_band_prefix(jnp.sum(p.reshape(nb, r, n1), axis=1))
+    halo = _lorenzo_halo(base, _next_rows(p, r))
+    band, hspec = _specs(r, n1)
+    out_specs, out_shape = _outputs(band, n0, n1, p.dtype, what)
+    return pl.pallas_call(
         functools.partial(_lorenzo_kernel, what=what),
         grid=(nb,),
-        in_specs=[band, row, row],
-        out_specs=out_spec if n_out > 1 else out_spec[0],
-        out_shape=out_shape if n_out > 1 else out_shape[0],
+        in_specs=[band, hspec],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(p, halo, base)
-    return out
+    )(p, halo)
 
 
 @functools.partial(jax.jit,
@@ -267,58 +320,49 @@ def lorenzo_enc2d(payload: jax.Array, shape: tuple, bits: int, *,
     Two payload-input kernel passes, neither of which materializes the
     residual plane in HBM: a band column-sum pass (for the tiny cross-band
     ``base`` prefix), then the stencil pass — each unpacks its band's
-    gathered payload words in VMEM.  Halo rows are unpacked host-side at
-    row cost.  The recovered integers are bit-identical to
+    payload word rows in VMEM.  Halo rows are unpacked outside the kernel
+    at row cost.  The recovered integers are bit-identical to
     ``decode_device`` + :func:`lorenzo2d` (same unpack arithmetic), so the
     output planes are too.
     """
     n0, n1 = shape
-    r = band_rows(n0)
-    nb = n0 // r
-    words, s0 = band_payload(payload, r * n1, bits, nb)
-    wpb = words.shape[1]
-    halo = jnp.concatenate(
+    r, nb = _bands(n0, n1)
+    words, offs = row_words(payload, n0, n1, bits)
+    band, hspec, wspec, sspec = _specs(r, n1, words.shape[1])
+    colsums = pl.pallas_call(
+        functools.partial(_colsum_enc_kernel, n1=n1, bits=bits),
+        grid=(nb,),
+        in_specs=[wspec, sspec],
+        out_specs=hspec,
+        out_shape=jax.ShapeDtypeStruct((nb * SUBLANES, n1), jnp.int32),
+        interpret=interpret,
+    )(words, offs)
+    nxt = jnp.concatenate(
         [unpack_rows(payload, jnp.arange(1, nb, dtype=jnp.int32) * r,
                      n1, bits),
          jnp.zeros((1, n1), jnp.int32)], axis=0)
-    wband = pl.BlockSpec((1, wpb), lambda b: (b, 0))
-    srow = pl.BlockSpec((1, 1), lambda b: (b, 0))
-    row = pl.BlockSpec((1, n1), lambda b: (b, 0))
-    band = pl.BlockSpec((r, n1), lambda b: (b, 0))
-    colsums = pl.pallas_call(
-        functools.partial(_colsum_enc_kernel, r=r, n1=n1, bits=bits),
+    halo = _lorenzo_halo(_exclusive_band_prefix(colsums[::SUBLANES]), nxt)
+    out_specs, out_shape = _outputs(band, n0, n1, jnp.int32, what)
+    return pl.pallas_call(
+        functools.partial(_lorenzo_enc_kernel, what=what, n1=n1, bits=bits),
         grid=(nb,),
-        in_specs=[wband, srow],
-        out_specs=row,
-        out_shape=jax.ShapeDtypeStruct((nb, n1), jnp.int32),
+        in_specs=[wspec, sspec, hspec],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(words, s0)
-    base = jnp.concatenate(
-        [jnp.zeros((1, n1), jnp.int32), jnp.cumsum(colsums, axis=0)[:-1]],
-        axis=0)
-    n_out = 2 if what == "grad" else 1
-    out_spec = [band] * n_out
-    out_shape = [jax.ShapeDtypeStruct((n0, n1), jnp.int32)] * n_out
-    out = pl.pallas_call(
-        functools.partial(_lorenzo_enc_kernel, what=what, r=r, n1=n1,
-                          bits=bits),
-        grid=(nb,),
-        in_specs=[wband, srow, row, row],
-        out_specs=out_spec if n_out > 1 else out_spec[0],
-        out_shape=out_shape if n_out > 1 else out_shape[0],
-        interpret=interpret,
-    )(words, s0, halo, base)
-    return out
+    )(words, offs, halo)
 
 
 # ---------------------------------------------------------------------------
-# block-mean family: residual band + metadata grid band -> stencil
+# block-mean family: residual band + metadata band -> stencil
 # ---------------------------------------------------------------------------
 
-def _blockmean_core(p, pp_row, pn_row, mg, mp_row, mn_row, out_refs,
-                    what: str, block: tuple):
-    """Shared band body: upsample the metadata grid band in VMEM (never in
-    HBM) and emit the requested stencil planes.
+def _blockmean_core(p, mg, h, out_refs, what: str, b0: int):
+    """Shared band body: upsample the column-upsampled metadata band along
+    rows in VMEM (the full-resolution metadata plane never exists in HBM)
+    and emit the requested stencil planes.  Halo tile ``h``: rows 0-3 are
+    the residual rows above and below the band, then the metadata rows
+    above and below it.
 
     Derivative planes serve stages ②③④ alike: with q = p + m elementwise,
     q[+1]-q[-1] and (p[+1]-p[-1]) + (m[+1]-m[-1]) are the same int32 value.
@@ -326,12 +370,12 @@ def _blockmean_core(p, pp_row, pn_row, mg, mp_row, mn_row, out_refs,
     accumulation orders (②: stencil(p) + stencil(m); ③④: stencil(p + m)),
     minus the trailing eps multiply, which the lowering rule applies.
     """
-    b0, b1 = block
-    m = jnp.repeat(jnp.repeat(mg, b0, axis=0), b1, axis=1)
-    m_prev = jnp.repeat(mp_row, b1, axis=1)
-    m_next = jnp.repeat(mn_row, b1, axis=1)
-    p_up, p_dn = _shift_rows(p, pp_row, pn_row)
-    m_up, m_dn = _shift_rows(m, m_prev, m_next)
+    r, n1 = p.shape
+    rb = r // b0
+    m = jnp.broadcast_to(mg[:rb].reshape(rb, 1, n1), (rb, b0, n1))
+    m = m.reshape(r, n1)
+    p_up, p_dn = _shift_rows(p, h[0:1], h[1:2])
+    m_up, m_dn = _shift_rows(m, h[2:3], h[3:4])
     outs = iter(out_refs)
 
     def lap5(c, dn, up, right, left):
@@ -362,23 +406,32 @@ def _blockmean_core(p, pp_row, pn_row, mg, mp_row, mn_row, out_refs,
                                p_r + m_r, p_l + m_l)
 
 
-def _blockmean_kernel(p_ref, pp_ref, pn_ref, mg_ref, mp_ref, mn_ref,
-                      *out_refs, what: str, block: tuple):
-    _blockmean_core(p_ref[...], pp_ref[...], pn_ref[...], mg_ref[...],
-                    mp_ref[...], mn_ref[...], out_refs, what, block)
+def _blockmean_kernel(p_ref, m_ref, h_ref, *out_refs, what: str, b0: int):
+    _blockmean_core(p_ref[...], m_ref[...], h_ref[...], out_refs, what, b0)
 
 
-def _blockmean_enc_kernel(w_ref, s0_ref, pp_ref, pn_ref, mg_ref, mp_ref,
-                          mn_ref, *out_refs, what: str, block: tuple,
-                          r: int, n1: int, bits: int):
-    """Payload-input variant: gathered band words -> in-kernel bitplane
-    unpack -> the same block-mean band body.  Only the ±1 halo rows of the
-    residual plane are unpacked host-side; the band itself exists only in
-    VMEM."""
-    p = _unzigzag(_unpack_span(w_ref[0], s0_ref[0, 0], r * n1,
-                               bits)).reshape(r, n1)
-    _blockmean_core(p, pp_ref[...], pn_ref[...], mg_ref[...], mp_ref[...],
-                    mn_ref[...], out_refs, what, block)
+def _blockmean_enc_kernel(w_ref, s_ref, m_ref, h_ref, *out_refs, what: str,
+                          b0: int, n1: int, bits: int):
+    """Payload-input variant: band word rows -> in-kernel bitplane unpack
+    -> the same block-mean band body.  Only the ±1 halo rows of the
+    residual plane are unpacked outside the kernel; the band itself exists
+    only in VMEM."""
+    _blockmean_core(_unpack_band(w_ref, s_ref, n1, bits), m_ref[...],
+                    h_ref[...], out_refs, what, b0)
+
+
+def _meta_bands(meta: jax.Array, b1: int, nb: int, r: int, b0: int):
+    """Column-upsampled metadata in per-band tiles (``rb = r/b0`` rows,
+    padded to a multiple of 8), its BlockSpec, and the metadata rows
+    above and below each band."""
+    mcol = jnp.repeat(meta, b1, axis=1)
+    g0, n1 = mcol.shape
+    rb = r // b0
+    rb8 = -(-rb // SUBLANES) * SUBLANES
+    tiles = jnp.pad(mcol.reshape(nb, rb, n1),
+                    ((0, 0), (0, rb8 - rb), (0, 0))).reshape(nb * rb8, n1)
+    spec = pl.BlockSpec((rb8, n1), lambda b: (b, 0))
+    return tiles, spec, _prev_rows(mcol, rb), _next_rows(mcol, rb)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "what", "interpret"))
@@ -393,31 +446,20 @@ def blockmean2d(p: jax.Array, meta: jax.Array, block: tuple, *,
     """
     n0, n1 = p.shape
     b0, b1 = block
-    r = band_rows(n0, b0)
-    nb = n0 // r
-    rb = r // b0
-    p_prev = _row_halo(p, r, "prev")
-    p_next = _row_halo(p, r, "next")
-    m_prev = _row_halo(meta, rb, "prev")
-    m_next = _row_halo(meta, rb, "next")
-    ng1 = meta.shape[1]
-    band = pl.BlockSpec((r, n1), lambda b: (b, 0))
-    row = pl.BlockSpec((1, n1), lambda b: (b, 0))
-    gband = pl.BlockSpec((rb, ng1), lambda b: (b, 0))
-    grow = pl.BlockSpec((1, ng1), lambda b: (b, 0))
-    n_out = 2 if what == "grad" else 1
+    r, nb = _bands(n0, n1, b0)
+    mtiles, mspec, m_prev, m_next = _meta_bands(meta, b1, nb, r, b0)
+    halo = _halo_tiles(_prev_rows(p, r), _next_rows(p, r), m_prev, m_next)
+    band, hspec = _specs(r, n1)
     dtype = jnp.float32 if what in ("lap_p", "lap_q") else p.dtype
-    out_spec = [band] * n_out
-    out_shape = [jax.ShapeDtypeStruct((n0, n1), dtype)] * n_out
-    out = pl.pallas_call(
-        functools.partial(_blockmean_kernel, what=what, block=(b0, b1)),
+    out_specs, out_shape = _outputs(band, n0, n1, dtype, what)
+    return pl.pallas_call(
+        functools.partial(_blockmean_kernel, what=what, b0=b0),
         grid=(nb,),
-        in_specs=[band, row, row, gband, grow, grow],
-        out_specs=out_spec if n_out > 1 else out_spec[0],
-        out_shape=out_shape if n_out > 1 else out_shape[0],
+        in_specs=[band, mspec, hspec],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(p, p_prev, p_next, meta, m_prev, m_next)
-    return out
+    )(p, mtiles, halo)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "block", "bits",
@@ -428,46 +470,32 @@ def blockmean_enc2d(payload: jax.Array, meta: jax.Array, shape: tuple,
     """Single-pass decode + block-mean stencil from the packed payload.
 
     One payload-input kernel pass: each grid cell unpacks its band's
-    gathered payload words in VMEM, upsamples the metadata grid band, and
-    writes only the stencil plane — the residual plane never exists in
-    HBM.  Halo rows (±1 row per band) are unpacked host-side at row cost.
+    payload word rows in VMEM, upsamples the metadata band, and writes only
+    the stencil plane — the residual plane never exists in HBM.  Halo rows
+    (±1 row per band) are unpacked outside the kernel at row cost.
     Bit-identical to ``decode_device`` + :func:`blockmean2d`.
     """
     n0, n1 = shape
     b0, b1 = block
-    r = band_rows(n0, b0)
-    nb = n0 // r
-    rb = r // b0
-    words, s0 = band_payload(payload, r * n1, bits, nb)
-    wpb = words.shape[1]
-    p_prev = jnp.concatenate(
-        [jnp.zeros((1, n1), jnp.int32),
-         unpack_rows(payload, jnp.arange(1, nb, dtype=jnp.int32) * r - 1,
-                     n1, bits)], axis=0)
-    p_next = jnp.concatenate(
-        [unpack_rows(payload, jnp.arange(1, nb, dtype=jnp.int32) * r,
-                     n1, bits),
-         jnp.zeros((1, n1), jnp.int32)], axis=0)
-    m_prev = _row_halo(meta, rb, "prev")
-    m_next = _row_halo(meta, rb, "next")
-    ng1 = meta.shape[1]
-    wband = pl.BlockSpec((1, wpb), lambda b: (b, 0))
-    srow = pl.BlockSpec((1, 1), lambda b: (b, 0))
-    band = pl.BlockSpec((r, n1), lambda b: (b, 0))
-    row = pl.BlockSpec((1, n1), lambda b: (b, 0))
-    gband = pl.BlockSpec((rb, ng1), lambda b: (b, 0))
-    grow = pl.BlockSpec((1, ng1), lambda b: (b, 0))
-    n_out = 2 if what == "grad" else 1
+    r, nb = _bands(n0, n1, b0)
+    words, offs = row_words(payload, n0, n1, bits)
+    mtiles, mspec, m_prev, m_next = _meta_bands(meta, b1, nb, r, b0)
+    zero = jnp.zeros((1, n1), jnp.int32)
+    starts = jnp.arange(1, nb, dtype=jnp.int32) * r
+    p_prev = jnp.concatenate([zero, unpack_rows(payload, starts - 1, n1,
+                                                bits)], axis=0)
+    p_next = jnp.concatenate([unpack_rows(payload, starts, n1, bits), zero],
+                             axis=0)
+    halo = _halo_tiles(p_prev, p_next, m_prev, m_next)
+    band, hspec, wspec, sspec = _specs(r, n1, words.shape[1])
     dtype = jnp.float32 if what in ("lap_p", "lap_q") else jnp.int32
-    out_spec = [band] * n_out
-    out_shape = [jax.ShapeDtypeStruct((n0, n1), dtype)] * n_out
-    out = pl.pallas_call(
-        functools.partial(_blockmean_enc_kernel, what=what, block=(b0, b1),
-                          r=r, n1=n1, bits=bits),
+    out_specs, out_shape = _outputs(band, n0, n1, dtype, what)
+    return pl.pallas_call(
+        functools.partial(_blockmean_enc_kernel, what=what, b0=b0, n1=n1,
+                          bits=bits),
         grid=(nb,),
-        in_specs=[wband, srow, row, row, gband, grow, grow],
-        out_specs=out_spec if n_out > 1 else out_spec[0],
-        out_shape=out_shape if n_out > 1 else out_shape[0],
+        in_specs=[wspec, sspec, mspec, hspec],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(words, s0, p_prev, p_next, meta, m_prev, m_next)
-    return out
+    )(words, offs, mtiles, halo)

@@ -25,10 +25,13 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-#: payload-word window slack of :func:`repro.kernels.fused.band_payload`:
-#: +1 word for the in-word bit offset, +1 for the carry word.  The audit's
-#: bounded-exhaustive unpack lemma proves this is exactly enough for every
-#: (bits, offset) combination — see ``kernelspec.check_unpack_lemma``.
+#: payload-word slack of one lane tile of
+#: :func:`repro.kernels.bitpack.unpack_lanes`: a tile's 128 values span
+#: ``4*bits`` words from its window start; +1 word for the in-word bit
+#: offset, +1 for the carry word read.  The audit's bounded-exhaustive
+#: unpack lemma proves this is exactly enough for every (bits, offset)
+#: combination, and that the window still fits one 128-lane gather — see
+#: ``kernelspec.check_unpack_lemma``.
 WPB_EXTRA = 2
 
 
@@ -78,7 +81,7 @@ class KernelSpec:
     ``vmem_elems`` — worst-case 4-byte elements resident in VMEM per grid
     cell (inputs + outputs + temporaries), over the symbols plus ``F``.
     ``unpack_words`` — the kernel runs the in-VMEM bitplane unpack
-    (``_unpack_span``); the word-window carry lemma applies.
+    (``bitpack.unpack_lanes``); the word-window carry lemma applies.
     ``sequential_revisit`` — the output index map is deliberately
     constant across the grid (TPU sequential-grid accumulator pattern);
     exactly-once coverage is waived, and the kernel must never be
@@ -101,18 +104,22 @@ class KernelSpec:
 
 def _band_bounds(**extra) -> dict:
     """Common band-kernel symbol ranges: grid step ``b`` over ``nb``
-    bands of ``r`` rows (``r <= MAX_BAND``), ``n1`` columns."""
-    out = {"b": ("0", "nb - 1"), "nb": ("1", None), "r": ("1", "256"),
+    bands of ``r = 8*rq`` rows, ``n1`` columns."""
+    out = {"b": ("0", "nb - 1"), "nb": ("1", None), "rq": ("1", None),
            "n1": ("1", None)}
     out.update(extra)
     return out
 
 
+#: band rows are a multiple of 8 (``band_rows``); one 8-row halo tile per
+#: band; payload word rows are ``W`` wide (``bitpack.window_words``).
+_BAND_FACTS = ("n0 == nb*r", "r == 8*rq", "nh == 8*nb")
 _BAND = TileSpec("band", ("r", "n1"), ("b", "0"), ("n0", "n1"))
-_ROW = TileSpec("halo_row", ("1", "n1"), ("b", "0"), ("nb", "n1"))
-_BASE = TileSpec("base_row", ("1", "n1"), ("b", "0"), ("nb", "n1"))
-_WBAND = TileSpec("words", ("1", "wpb"), ("b", "0"), ("nb", "wpb"))
-_SROW = TileSpec("s0", ("1", "1"), ("b", "0"), ("nb", "1"))
+_HALO = TileSpec("halo", ("8", "n1"), ("b", "0"), ("nh", "n1"))
+_WORDS = TileSpec("words", ("r", "W"), ("b", "0"), ("n0", "W"))
+_OFFS = TileSpec("offs", ("r", "1"), ("b", "0"), ("n0", "1"))
+_META = TileSpec("meta", ("rb8", "n1"), ("b", "0"), ("nm", "n1"))
+_PLANE = TileSpec("plane", ("r", "n1"), ("b", "0"), ("n0", "n1"))
 
 
 KERNEL_SPECS: tuple[KernelSpec, ...] = (
@@ -122,40 +129,42 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         site=("fused", "lorenzo2d", 0),
         grid=("b",),
         bounds=_band_bounds(),
-        facts=("n0 == nb*r",),
-        inputs=(_BAND, _ROW, _BASE),
-        outputs=(TileSpec("plane", ("r", "n1"), ("b", "0"), ("n0", "n1")),),
+        facts=_BAND_FACTS,
+        inputs=(_BAND, _HALO),
+        outputs=(_PLANE,),
         halos=(
-            # _row_halo(p, r, "next"): next[b] = p[(b+1)*r], zero last band
+            # _next_rows(p, r): next[b] = p[(b+1)*r], zero last band
             HaloRead("p", "(b + 1)*r", "n0", guard="b <= nb - 2"),
         ),
-        # p + da/db (+next shifts) + base/halo rows + <=2 output planes
-        vmem_elems="9*F",
+        # band + halo tile (<= band) double-buffered, prefix planes and
+        # their shifts, <= 2 output planes double-buffered
+        vmem_elems="12*F",
         notes="grad emits two planes through the same output tile spec",
     ),
     KernelSpec(
         name="fused.lorenzo_enc2d.colsum",
         site=("fused", "lorenzo_enc2d", 0),
         grid=("b",),
-        bounds=_band_bounds(wpb=("2", None)),
-        inputs=(_WBAND, _SROW),
-        outputs=(TileSpec("colsums", ("1", "n1"), ("b", "0"), ("nb", "n1")),),
-        vmem_elems="3*F + 8",
+        bounds=_band_bounds(W=("128", None)),
+        facts=_BAND_FACTS,
+        inputs=(_WORDS, _OFFS),
+        outputs=(_HALO,),
+        vmem_elems="4*F + 8",
         unpack_words=True,
     ),
     KernelSpec(
         name="fused.lorenzo_enc2d.stencil",
         site=("fused", "lorenzo_enc2d", 1),
         grid=("b",),
-        bounds=_band_bounds(wpb=("2", None)),
-        facts=("n0 == nb*r",),
-        inputs=(_WBAND, _SROW, _ROW, _BASE),
-        outputs=(TileSpec("plane", ("r", "n1"), ("b", "0"), ("n0", "n1")),),
+        bounds=_band_bounds(W=("128", None)),
+        facts=_BAND_FACTS,
+        inputs=(_WORDS, _OFFS, _HALO),
+        outputs=(_PLANE,),
         halos=(
             # unpack_rows(payload, arange(1, nb)*r, ...): rows b*r, b >= 1
             HaloRead("plane", "b*r", "n0", guard="b >= 1"),
         ),
-        vmem_elems="10*F",
+        vmem_elems="13*F",
         unpack_words=True,
     ),
     # -- fused block-mean family ---------------------------------------------
@@ -163,43 +172,29 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         name="fused.blockmean2d",
         site=("fused", "blockmean2d", 0),
         grid=("b",),
-        bounds=_band_bounds(rb=("1", "256"), b0=("1", "4096"),
-                            ng1=("1", None)),
-        facts=("n0 == nb*r", "r == rb*b0", "g0 == nb*rb"),
-        inputs=(
-            _BAND,
-            TileSpec("p_prev", ("1", "n1"), ("b", "0"), ("nb", "n1")),
-            TileSpec("p_next", ("1", "n1"), ("b", "0"), ("nb", "n1")),
-            TileSpec("meta", ("rb", "ng1"), ("b", "0"), ("g0", "ng1")),
-            TileSpec("m_prev", ("1", "ng1"), ("b", "0"), ("nb", "ng1")),
-            TileSpec("m_next", ("1", "ng1"), ("b", "0"), ("nb", "ng1")),
-        ),
-        outputs=(TileSpec("plane", ("r", "n1"), ("b", "0"), ("n0", "n1")),),
+        bounds=_band_bounds(rb=("1", None), g0=("1", None),
+                            q8=("1", None)),
+        facts=_BAND_FACTS + ("g0 == nb*rb", "rb8 == 8*q8", "nm == nb*rb8"),
+        inputs=(_BAND, _META, _HALO),
+        outputs=(_PLANE,),
         halos=(
             HaloRead("p", "b*r - 1", "n0", guard="b >= 1"),
             HaloRead("p", "(b + 1)*r", "n0", guard="b <= nb - 2"),
             HaloRead("meta", "b*rb - 1", "g0", guard="b >= 1"),
             HaloRead("meta", "(b + 1)*rb", "g0", guard="b <= nb - 2"),
         ),
-        # p, upsampled m, 4 shifted planes, 2 col shifts, <=2 outputs, rows
-        vmem_elems="14*F",
+        # p, upsampled m, 4 shifted planes, 2 col shifts, <=2 outputs, tiles
+        vmem_elems="16*F",
     ),
     KernelSpec(
         name="fused.blockmean_enc2d",
         site=("fused", "blockmean_enc2d", 0),
         grid=("b",),
-        bounds=_band_bounds(rb=("1", "256"), b0=("1", "4096"),
-                            ng1=("1", None), wpb=("2", None)),
-        facts=("n0 == nb*r", "r == rb*b0", "g0 == nb*rb"),
-        inputs=(
-            _WBAND, _SROW,
-            TileSpec("p_prev", ("1", "n1"), ("b", "0"), ("nb", "n1")),
-            TileSpec("p_next", ("1", "n1"), ("b", "0"), ("nb", "n1")),
-            TileSpec("meta", ("rb", "ng1"), ("b", "0"), ("g0", "ng1")),
-            TileSpec("m_prev", ("1", "ng1"), ("b", "0"), ("nb", "ng1")),
-            TileSpec("m_next", ("1", "ng1"), ("b", "0"), ("nb", "ng1")),
-        ),
-        outputs=(TileSpec("plane", ("r", "n1"), ("b", "0"), ("n0", "n1")),),
+        bounds=_band_bounds(rb=("1", None), g0=("1", None),
+                            q8=("1", None), W=("128", None)),
+        facts=_BAND_FACTS + ("g0 == nb*rb", "rb8 == 8*q8", "nm == nb*rb8"),
+        inputs=(_WORDS, _OFFS, _META, _HALO),
+        outputs=(_PLANE,),
         halos=(
             # unpack_rows at arange(1, nb)*r - 1 and arange(1, nb)*r
             HaloRead("plane", "b*r - 1", "n0", guard="b >= 1"),
@@ -207,7 +202,7 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
             HaloRead("meta", "b*rb - 1", "g0", guard="b >= 1"),
             HaloRead("meta", "(b + 1)*rb", "g0", guard="b <= nb - 2"),
         ),
-        vmem_elems="15*F",
+        vmem_elems="17*F",
         unpack_words=True,
     ),
     # -- bitplane pack / unpack ----------------------------------------------
@@ -216,8 +211,8 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         site=("bitpack", "pack", 0),
         grid=("i",),
         bounds={"i": ("0", "g - 1"), "g": ("1", None),
-                "wp": ("1", "4096")},
-        facts=("npad == g*4096", "nw == g*wp"),
+                "bits": ("1", "31")},
+        facts=("npad == g*4096", "nw == g*wp", "wp == 128*bits"),
         inputs=(TileSpec("u", ("4096",), ("i",), ("npad",)),),
         outputs=(TileSpec("words", ("wp",), ("i",), ("nw",)),),
         # u + (V, bits<=32) bit matrix + word stream + powers
@@ -228,11 +223,14 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         site=("bitpack", "unpack", 0),
         grid=("i",),
         bounds={"i": ("0", "g - 1"), "g": ("1", None),
-                "wp": ("1", "4096")},
-        facts=("npad == g*4096", "nw == g*wp"),
-        inputs=(TileSpec("words", ("wp",), ("i",), ("nw",)),),
-        outputs=(TileSpec("u", ("4096",), ("i",), ("npad",)),),
-        vmem_elems="4096 + 4096*32 + 4096 + 64",
+                "kq": ("1", "8"), "W": ("128", None)},
+        facts=("rows == g*blk", "blk == 8*kq"),
+        inputs=(TileSpec("words", ("blk", "W"), ("i", "0"), ("rows", "W")),),
+        outputs=(TileSpec("u", ("blk", "1024"), ("i", "0"),
+                          ("rows", "1024")),),
+        # word rows (W <= 28*31 + 128 words) + lane-tile temporaries + out
+        vmem_elems="2*64*1024 + 6*64*1024",
+        unpack_words=True,
     ),
     # -- fused quantize + Lorenzo --------------------------------------------
     KernelSpec(
@@ -241,8 +239,8 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         grid=("i", "j"),
         bounds={"i": ("0", "g0 - 1"), "j": ("0", "g1 - 1"),
                 "g0": ("1", None), "g1": ("1", None),
-                "t0": ("1", "128"), "t1": ("1", "256")},
-        facts=("n0 == g0*t0", "n1 == g1*t1"),
+                "u0": ("1", "16"), "u1": ("1", "2")},
+        facts=("n0 == g0*t0", "n1 == g1*t1", "t0 == 8*u0", "t1 == 128*u1"),
         inputs=(
             TileSpec("x", ("t0", "t1"), ("i", "j"), ("n0", "n1")),
             TileSpec("xr", ("t0", "t1"), ("i", "j"), ("n0", "n1")),
@@ -261,8 +259,8 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         grid=("i", "j"),
         bounds={"i": ("0", "g0 - 1"), "j": ("0", "g1 - 1"),
                 "g0": ("1", None), "g1": ("1", None),
-                "t0": ("1", "128"), "t1": ("1", "256")},
-        facts=("m0 == g0*t0", "m1 == g1*t1"),
+                "u0": ("1", "16"), "u1": ("1", "2")},
+        facts=("m0 == g0*t0", "m1 == g1*t1", "t0 == 8*u0", "t1 == 128*u1"),
         inputs=(
             TileSpec("qn", ("t0", "t1"), ("i", "j"), ("m0", "m1")),
             TileSpec("qs", ("t0", "t1"), ("i", "j"), ("m0", "m1")),
@@ -281,8 +279,8 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         grid=("i", "j"),
         bounds={"i": ("0", "g0 - 1"), "j": ("0", "g1 - 1"),
                 "g0": ("1", None), "g1": ("1", None),
-                "t0": ("1", "128"), "t1": ("1", "256")},
-        facts=("m0 == g0*t0", "m1 == g1*t1"),
+                "u0": ("1", "16"), "u1": ("1", "2")},
+        facts=("m0 == g0*t0", "m1 == g1*t1", "t0 == 8*u0", "t1 == 128*u1"),
         inputs=(
             TileSpec("qc", ("t0", "t1"), ("i", "j"), ("m0", "m1")),
             TileSpec("qn", ("t0", "t1"), ("i", "j"), ("m0", "m1")),
@@ -299,8 +297,8 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         site=("block_stats", "block_stats", 0),
         grid=("i",),
         bounds={"i": ("0", "g - 1"), "g": ("1", None),
-                "rows": ("1", "256"), "s": ("1", "4096")},
-        facts=("nb == g*rows",),
+                "k": ("1", "2"), "s": ("1", "4096")},
+        facts=("nb == g*rows", "rows == 128*k"),
         inputs=(TileSpec("q", ("rows", "s"), ("i", "0"), ("nb", "s")),),
         outputs=(
             TileSpec("mean", ("rows",), ("i",), ("nb",)),
@@ -314,8 +312,8 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         site=("prefix_stats", "prefix_stats2d", 0),
         grid=("i",),
         bounds={"i": ("0", "g - 1"), "g": ("1", None),
-                "rows": ("1", "64"), "n1": ("1", None)},
-        facts=("n0 == g*rows",),
+                "k": ("1", "8"), "n1": ("1", None)},
+        facts=("n0 == g*rows", "rows == 8*k"),
         inputs=(TileSpec("p", ("rows", "n1"), ("i", "0"), ("n0", "n1")),),
         outputs=(TileSpec("s", ("2",), ("0",), ("2",)),),
         # band + rowcum + q + qf + colsum scratch

@@ -17,13 +17,8 @@ from repro.models import common as model_common
 
 
 def auto_axis_types(n_axes: int) -> dict[str, tuple]:
-    """``axis_types`` kwargs for ``jax.make_mesh``, portable across jax
-    versions (older releases predate ``jax.sharding.AxisType``; their meshes
-    are implicitly Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """``axis_types`` kwargs for ``jax.make_mesh``: every axis Auto."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -55,12 +50,8 @@ def make_analytics_mesh(n_shards: int | None = None):
         raise ValueError(
             f"n_shards must be in [1, {len(devices)}] "
             f"(addressable devices), got {n_shards}")
-    mesh_devices = np.asarray(devices[:n])
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.sharding.Mesh(mesh_devices, (SHARD_AXIS,))
-    return jax.sharding.Mesh(mesh_devices, (SHARD_AXIS,),
-                             axis_types=(axis_type.Auto,))
+    return jax.sharding.Mesh(np.asarray(devices[:n]), (SHARD_AXIS,),
+                             **auto_axis_types(1))
 
 
 def make_host_mesh(shape: tuple[int, ...] = (1, 1), axes=("data", "model")):

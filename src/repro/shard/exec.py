@@ -91,6 +91,62 @@ def gather_routing(n_shards: int, placement: BlockPlacement, bits: int,
     return src_arr, dst_arr
 
 
+def region_program(mesh, names: tuple[str, ...], stage: Stage, axis: int,
+                   norm, n_outs: tuple[int, ...], vector: bool):
+    """The jitted word-merge program of :meth:`ShardPrograms.region_compute`.
+
+    Called as ``fn(stripped, stripes, srcs, dsts)``: the payload-less
+    component fields (replicated), their ``[n_shards, w]`` payload stripes
+    and the per-shard routing from :func:`gather_routing` (both sharded
+    over the shard axis).  Each shard scatter-adds its owned words into the
+    gathered-word layout, a ``psum`` reassembles them, and the op set
+    lowers on the merged words; ``n_outs`` are the gathered word counts.
+    """
+    def body(ecs, strs, srcs, dsts):
+        merged = []
+        for st, sr, ds, n_out in zip(strs, srcs, dsts, n_outs):
+            vals = st[0][sr[0]]
+            buf = jnp.zeros((n_out + 1,), jnp.uint32).at[ds[0]].add(vals)
+            merged.append(jax.lax.psum(buf[:n_out], SHARD_AXIS))
+        if norm is None:
+            # full field: the merge reassembles the entire payload
+            # exactly, so the standard full decode runs unchanged
+            full = tuple(dataclasses.replace(ec, payload=m)
+                         for ec, m in zip(ecs, merged))
+            return oplib.compute(full if vector else full[0], names, stage,
+                                 axis=axis)
+        return oplib.compute(tuple(ecs) if vector else ecs[0], names, stage,
+                             axis=axis, region=norm,
+                             payload_words=merged if vector else merged[0])
+
+    return jax.jit(compat.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(), P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
+        out_specs=P(), check=False))
+
+
+def materialize_program(mesh, stage: Stage, norm, closure, n_out: int):
+    """The jitted word-merge program of :meth:`ShardPrograms.materialize`,
+    called as ``fn(stripped, stripes, src, dst)`` like
+    :func:`region_program`; returns the stage-② ``sub`` container or the
+    stage-③ ``q_spatial`` integers, replicated."""
+    def body(ec, st, sr, ds):
+        vals = st[0][sr[0]]
+        buf = jnp.zeros((n_out + 1,), jnp.uint32).at[ds[0]].add(vals)
+        merged = jax.lax.psum(buf[:n_out], SHARD_AXIS)
+        if norm is None:
+            full = dataclasses.replace(ec, payload=merged)
+            ctx = oplib.StageContext(full, stage, None, closure)
+        else:
+            ctx = oplib.StageContext(ec, stage, norm, closure, words=merged)
+        return ctx.sub if stage == Stage.P else ctx.q_spatial
+
+    return jax.jit(compat.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(), P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
+        out_specs=P(), check=False))
+
+
 class ShardPrograms:
     """Compiled ``shard_map`` programs for one analytics mesh.
 
@@ -202,31 +258,8 @@ class ShardPrograms:
                tuple(s.shape for s in stripes))
         fn = self._jitted.get(key)
         if fn is None:
-            n_outs = tuple(r[2] for r in routing)
-
-            def body(ecs, strs, srcs, dsts, _names=names, _stage=stage,
-                     _axis=axis, _norm=norm, _n=n_outs, _vec=vector):
-                merged = []
-                for ec, st, sr, ds, n_out in zip(ecs, strs, srcs, dsts, _n):
-                    vals = st[0][sr[0]]
-                    buf = jnp.zeros((n_out + 1,), jnp.uint32).at[ds[0]].add(vals)
-                    merged.append(jax.lax.psum(buf[:n_out], SHARD_AXIS))
-                if _norm is None:
-                    # full field: the merge reassembles the entire payload
-                    # exactly, so the standard full decode runs unchanged
-                    full = tuple(dataclasses.replace(ec, payload=m)
-                                 for ec, m in zip(ecs, merged))
-                    tgt = full if _vec else full[0]
-                    return oplib.compute(tgt, _names, _stage, axis=_axis)
-                tgt = tuple(ecs) if _vec else ecs[0]
-                words = merged if _vec else merged[0]
-                return oplib.compute(tgt, _names, _stage, axis=_axis,
-                                     region=_norm, payload_words=words)
-
-            fn = jax.jit(compat.shard_map(
-                body, mesh=self.mesh,
-                in_specs=(P(), P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
-                out_specs=P(), check=False))
+            fn = region_program(self.mesh, names, stage, axis, norm,
+                                tuple(r[2] for r in routing), vector)
             self._cache_put(key, fn)
         else:
             self._jitted.move_to_end(key)
@@ -289,23 +322,7 @@ class ShardPrograms:
                n_out, tuple(stripes.shape))
         fn = self._jitted.get(key)
         if fn is None:
-            def body(ec, st, sr, ds, _stage=stage, _norm=norm, _cl=closure,
-                     _n=n_out):
-                vals = st[0][sr[0]]
-                buf = jnp.zeros((_n + 1,), jnp.uint32).at[ds[0]].add(vals)
-                merged = jax.lax.psum(buf[:_n], SHARD_AXIS)
-                if _norm is None:
-                    full = dataclasses.replace(ec, payload=merged)
-                    ctx = oplib.StageContext(full, _stage, None, _cl)
-                else:
-                    ctx = oplib.StageContext(ec, _stage, _norm, _cl,
-                                             words=merged)
-                return ctx.sub if _stage == Stage.P else ctx.q_spatial
-
-            fn = jax.jit(compat.shard_map(
-                body, mesh=self.mesh,
-                in_specs=(P(), P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
-                out_specs=P(), check=False))
+            fn = materialize_program(self.mesh, stage, norm, closure, n_out)
             self._cache_put(key, fn)
         else:
             self._jitted.move_to_end(key)

@@ -672,13 +672,22 @@ class TestKernelSpecAnalyzer:
 
     def test_coverage_gap_one_finding(self):
         # one band more of rows than the grid writes
-        fs = kernelspec.check_spec(replace(_SPEC, facts=("n0 == nb*r + r",)))
+        facts = ("n0 == nb*r + r",) + _SPEC.facts[1:]
+        fs = kernelspec.check_spec(replace(_SPEC, facts=facts))
         assert [f.invariant for f in fs] == ["grid-write-gap"]
 
     def test_vmem_budget_one_finding(self):
         env = intwidth.Envelope(max_field_elems=2**23)  # 9F*4B >> 16 MiB
         fs = kernelspec.check_spec(_SPEC, env)
         assert [f.invariant for f in fs] == ["vmem-budget"]
+
+    def test_block_tiling_one_finding(self):
+        # a one-row halo block over an (nb, n1) array: in bounds and fully
+        # covered, but off the (8, 128) tiling — the block Mosaic refused
+        row = TileSpec("halo", ("1", "n1"), ("b", "0"), ("nb", "n1"))
+        fs = kernelspec.check_spec(replace(_SPEC, inputs=(_SPEC.inputs[0],
+                                                          row)))
+        assert [f.invariant for f in fs] == ["block-tiling"]
 
     def test_unpack_lemma_pins_word_window_slack(self):
         assert kernelspec.check_unpack_lemma(2) == []

@@ -70,6 +70,14 @@ def test_synth_field_deterministic():
     a = synth_field("NYX", 2, (16, 16, 16))
     b = synth_field("NYX", 2, (16, 16, 16))
     np.testing.assert_array_equal(a, b)
+    # pinned values: the seed must not depend on the process (str hashes
+    # are randomized per interpreter), so these hold in every run
+    np.testing.assert_allclose(
+        [a[0, 0, 0], a[3, 5, 7], a[15, 15, 15]],
+        [-0.821628, -0.5784376, -0.19868238], rtol=1e-6)
+    o = synth_field("Ocean", 0, (24, 36))
+    np.testing.assert_allclose([o[0, 0], o[23, 35]],
+                               [-0.12292598, 0.789031], rtol=1e-6)
 
 
 # -- serving engine --------------------------------------------------------------

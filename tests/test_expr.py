@@ -477,7 +477,7 @@ def _dags(draw):
     return n_leaves, node(0)
 
 
-@given(_dags())
+@given(spec=_dags())
 def test_random_dag_matches_composed_oracle(spec, field_2d):
     n_leaves, tree = spec
     comps = [hszp_nd.compress(

@@ -38,6 +38,42 @@ def _window_ref(comp, c):
 
 # -- the sparsity contract ----------------------------------------------------
 
+@pytest.mark.parametrize("bits", [1, 7, 12, 31, 32])
+@pytest.mark.parametrize("case", [
+    ((40, 48), (8, 8), ((5, 30), (9, 40)), "cover"),
+    ((40, 48), (8, 8), ((0, 40), (0, 48)), "cover"),
+    ((40, 48), (8, 8), ((16, 24), (3, 5)), (0, 1)),
+    ((24, 40, 33), (8, 8, 8), ((3, 17), (10, 30), (0, 33)), "cover"),
+    ((24, 40, 33), (8, 8, 8), ((3, 17), (10, 30), (5, 9)), "hull"),
+], ids=["2d", "2d-full", "2d-band", "3d", "3d-hull"])
+def test_device_gather_indices_match_host_plan(case, bits):
+    """nd region plans build their per-value gather indices on device; they
+    must equal the host plan's arrays exactly (global words for the
+    single-device decode, gathered-set positions for the sharded merge)."""
+    shape, block, region, closure = case
+    padded = tuple(-(-n // b) * b for n, b in zip(shape, block))
+    plan = R.RegionPlan(hszp_nd.scheme, shape, padded, block, region, closure)
+    # host oracle: every gathered value's words, sorted and deduplicated
+    axes = [np.arange(lo * b, hi * b)
+            for (lo, hi), b in zip(plan.grid_ranges, block)]
+    gflat = np.ravel_multi_index(np.meshgrid(*axes, indexing="ij"),
+                                 padded).reshape(-1).astype(np.int64)
+    total = encode.words_for(int(np.prod(padded)), bits)
+    w0 = (gflat * bits) >> 5
+    uniq = np.unique(np.concatenate([w0, w0 + 1]))
+    uniq = uniq[uniq < total]
+    np.testing.assert_array_equal(plan.payload_gather(bits).word_idx, uniq)
+    got_w0, shift = plan.value_words(bits)
+    np.testing.assert_array_equal(np.asarray(got_w0), w0)
+    np.testing.assert_array_equal(np.asarray(shift), (gflat * bits) & 31)
+    pos0, pos1, shift2 = plan.gathered_positions(bits)
+    np.testing.assert_array_equal(np.asarray(pos0), np.searchsorted(uniq, w0))
+    np.testing.assert_array_equal(
+        np.asarray(pos1), np.where(w0 + 1 < total,
+                                   np.searchsorted(uniq, w0 + 1), len(uniq)))
+    np.testing.assert_array_equal(np.asarray(shift2), np.asarray(shift))
+
+
 def test_region_decodes_only_covering_blocks():
     """A <=10% window gathers exactly its covering blocks and a proportional
     share of the payload words — never the whole field."""
