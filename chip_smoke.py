@@ -24,9 +24,8 @@ within its stage's bias bound (``repro.core.error_analysis``) plus the f32
 rounding the stage-4 values themselves carry; kernel cells equal the XLA
 lowering bit for bit.  A failed check raises, so the script exits non-zero.
 
-Timings printed are smoke timings (first call = compile + run, then one
-warm call), not benchmark numbers.  The last line of a successful run is
-``{"ok": true, "device": {...}}``.  Without a TPU the script exits non-zero
+The script prints no timings (``bench/run.py`` measures the service).  The
+last line of a successful run is ``{"ok": true, "device": {...}}``.  Without a TPU the script exits non-zero
 and prints no result.
 
     python chip_smoke.py [--seed 0] [--chips 4]
@@ -37,7 +36,6 @@ import argparse
 import json
 import os
 import sys
-import time
 
 import numpy as np
 
@@ -71,25 +69,6 @@ def check(ok, what: str) -> None:
 
 def log(*parts) -> None:
     print(*parts, flush=True)
-
-
-def smoke_timed(label: str, fn):
-    """Run ``fn`` twice to completion; print both wall times as smoke
-    timings (the first includes compilation)."""
-    def run():
-        out = fn()
-        jax.block_until_ready(getattr(out, "values", out))
-        return out
-
-    t0 = time.perf_counter()
-    run()
-    first = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    out = run()
-    warm = time.perf_counter() - t0
-    log(f"  smoke timing (not a benchmark) {label}: first call "
-        f"(compile + run) {first:.3f} s, warm call {warm:.3f} s")
-    return out
 
 
 def peak_bytes() -> str:
@@ -233,8 +212,7 @@ def phase_2d(dims=DATASETS["Ocean"][1], n_fields=DATASETS["Ocean"][0],
         # dashboard: one program, one prelude per field, auto stage
         roots = [r for i in ids for r in
                  (expr.mean(i), expr.std(i), expr.laplacian(i))]
-        res = smoke_timed(f"{scheme} mean+std+laplacian auto", lambda: query(
-            exprs=roots, stage="auto", store=store))
+        res = query(exprs=roots, stage="auto", store=store)
         stages = res.stages
         for f, f4 in enumerate(f4s):
             mean, std, lap = res.values[3 * f:3 * f + 3]
@@ -247,11 +225,9 @@ def phase_2d(dims=DATASETS["Ocean"][1], n_fields=DATASETS["Ocean"][0],
         q_roots = [r for i in ids for r in (expr.derivative(i, axis=0),
                                             expr.derivative(i, axis=1),
                                             expr.gradient(i))]
-        res_q = smoke_timed(f"{scheme} derivative+gradient @3", lambda: query(
-            exprs=q_roots, stage=Stage.Q, store=store).values)
-        res_p = smoke_timed(f"{scheme} laplacian @2", lambda: query(
-            exprs=[expr.laplacian(i) for i in ids], stage=Stage.P,
-            store=store).values)
+        res_q = query(exprs=q_roots, stage=Stage.Q, store=store).values
+        res_p = query(exprs=[expr.laplacian(i) for i in ids], stage=Stage.P,
+                      store=store).values
         sb = error_analysis.stencil_bias_bound(eb)
         for f, f4 in enumerate(f4s):
             d0, d1, (g0, g1) = res_q[3 * f:3 * f + 3]
@@ -344,8 +320,7 @@ def phase_3d(dims=DATASETS["Hurricane"][1], n_vars=DATASETS["Hurricane"][0],
     log(f"  {n_vars} variables: shape {encs[0].shape} bits {encs[0].bits}, "
         f"encoded {sum(encoded_bytes(e) for e in encs)} B in all")
     roots = [expr.mean(i) for i in ids] + [expr.std(i) for i in ids]
-    res = smoke_timed(f"{n_vars}-variable mean+std auto", lambda: query(
-        exprs=roots, stage="auto", store=store))
+    res = query(exprs=roots, stage="auto", store=store)
     for v, (mu, sd, amax) in enumerate(refs):
         e, s = encs[v], res.stages[v]
         close(f"{ids[v]} mean@{s.name}", res.values[v], mu,
@@ -354,9 +329,8 @@ def phase_3d(dims=DATASETS["Hurricane"][1], n_vars=DATASETS["Hurricane"][0],
         close(f"{ids[v]} std@{s.name}", res.values[n_vars + v], sd,
               error_analysis.std_bias_bound(e, s), 1.0, amax)
     uvw = tuple(ids[:3])
-    div, curl = smoke_timed("divergence+curl @3", lambda: query(
-        exprs=[expr.divergence(uvw), expr.curl(uvw)], stage=Stage.Q,
-        store=store).values)
+    div, curl = query(exprs=[expr.divergence(uvw), expr.curl(uvw)],
+                      stage=Stage.Q, store=store).values
     amax = max(r[2] for r in refs[:3])
     sb = error_analysis.stencil_bias_bound(encs[0])
     close("divergence@Q", div, sum(np_derivative(f, a)
@@ -388,10 +362,7 @@ def phase_stream(dims=DATASETS["Ocean"][1], n_slabs: int = 3, steps: int = 4,
         fe.add_request(AppendRequest(uid=2 * k, field_id=fid, data=slab))
         fe.add_request(AnalyticsRequest(
             uid=2 * k + 1, exprs=[expr.op(o, fid) for o in ops]))
-        t0 = time.perf_counter()
         done = {r.uid: r for r in fe.run_until_drained()}
-        log(f"  smoke timing (not a benchmark) append+query slab {k}: "
-            f"{time.perf_counter() - t0:.3f} s")
         for r in done.values():
             check(r.done and r.error is None,
                   f"stream request {r.uid} rejected: {r.error}")
@@ -450,10 +421,10 @@ def phase_shard(dims=DATASETS["NYX"][1], n_shards: int = 4,
              ("region divergence+curl @3",
               [expr.divergence(uvw), expr.curl(uvw)], Stage.Q, region))
     for label, roots, stage, reg in cases:
-        want = smoke_timed(f"single device {label}", lambda: query(
-            exprs=roots, stage=stage, region=reg, store=single).values)
-        got = smoke_timed(f"{n_shards} shards {label}", lambda: query(
-            exprs=roots, stage=stage, region=reg, store=sharded).values)
+        want = query(exprs=roots, stage=stage, region=reg,
+                     store=single).values
+        got = query(exprs=roots, stage=stage, region=reg,
+                    store=sharded).values
         for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             g, w = np.asarray(g), np.asarray(w)
             check(g.shape == w.shape and g.tobytes() == w.tobytes(),

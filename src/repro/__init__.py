@@ -11,6 +11,7 @@ Public entry points:
     repro.serve      — batched decode engine (int8 KV residency)
     repro.store      — materialized-stage field store (id-addressed serving)
     repro.stream     — streaming time-slab ingest + incremental temporal analytics
+    repro.obs        — spans and counters of the served path (ring + profiler)
     repro.data       — resumable token pipeline + compressed field store
     repro.configs    — assigned architectures x shapes registry
     repro.launch     — mesh rules, multi-pod dry-run, roofline, drivers

@@ -14,6 +14,16 @@ Store-seeded programs (``run(..., seeds=)``) take the fields' materialized
 intermediates as extra inputs and contain no stage reconstruction; they
 compile separately from their cold twins.
 
+Each engine program is a function named by its kind (``expr_dag``,
+``op_set``, ``temporal_summarize``, ``merge_summaries``,
+``temporal_postlude``), so a profiler trace says which one ran
+(``jit_expr_dag(...)``).  Every call runs in a :mod:`repro.obs` span:
+``repro.engine.dispatch`` covers building the cache key and, on a hit, the
+jitted call; a miss then opens ``repro.engine.build`` from ``jax.jit``
+through the first call, which traces and compiles.  Hits, misses and
+evictions are counted in :attr:`BatchedAnalytics.stats` and
+``repro.obs.counters``.
+
 Stage resolution is layered, not repeated: the engine plans only when given
 ``stage="auto"`` (or another directive string).  A resolved :class:`Stage`
 or :class:`StageSetPlan` — e.g. from :func:`repro.analytics.query.query`,
@@ -23,11 +33,13 @@ stages still raise at trace time from the ops themselves.
 from __future__ import annotations
 from collections.abc import Mapping, Sequence
 
+import dataclasses
 from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import (Compressed, Encoded, Stage, batch_stack, layout_key,
                         oplib)
 from repro.core import region as region_mod
@@ -64,6 +76,17 @@ def batch_key(first: Field, ops: str | Sequence[str], stage: Stage,
                                 batch, region, seed_sig, oplib.kernel_sig())
 
 
+@dataclasses.dataclass
+class JitStats:
+    """Cumulative jit-cache accounting of one engine (monotone counters):
+    ``misses`` counts programs built, ``evictions`` programs dropped (by the
+    LRU bound, or because their first call raised)."""
+
+    hits: int = 0
+    misses: int = 0
+    evictions: int = 0
+
+
 class BatchedAnalytics:
     """Executes one homomorphic op set over a batch of same-layout fields.
 
@@ -82,26 +105,64 @@ class BatchedAnalytics:
         self.bucket_batches = bucket_batches
         self.cache_limit = cache_limit
         self._jitted: OrderedDict[tuple, object] = OrderedDict()
+        self.stats = JitStats()
 
     @staticmethod
     def _bucket(n: int) -> int:
         return 1 << (n - 1).bit_length()
 
+    @staticmethod
+    def _unpad(out, b: int, n: int):
+        """Drop the results of the batch padding (``b`` of ``n`` are real)."""
+        return out if n == b else jax.tree.map(lambda x: x[:b], out)
+
     # -- compiled-program cache -------------------------------------------
-    def _compiled(self, key: tuple, ops: tuple[str, ...], stage: Stage,
-                  axis: int, n_components: int, batch: int, region=None,
-                  seeded: bool = False):
+    def _lookup(self, key: tuple):
+        """The program cached under ``key`` (a hit), or ``None``."""
         fn = self._jitted.get(key)
         if fn is not None:
             self._jitted.move_to_end(key)
-            return fn
+            self.stats.hits += 1
+            obs.counters["jit_hits"] += 1
+        return fn
+
+    def _cache_put(self, key: tuple, fn) -> None:
+        """Cache a program just built (a miss), evicting the least recently
+        used beyond ``cache_limit``."""
+        self.stats.misses += 1
+        obs.counters["jit_misses"] += 1
+        self._jitted[key] = fn
+        while len(self._jitted) > self.cache_limit:
+            self._jitted.popitem(last=False)
+            self._evicted()
+
+    def _evicted(self) -> None:
+        self.stats.evictions += 1
+        obs.counters["jit_evictions"] += 1
+
+    def _first_call(self, key: tuple, fn, *args):
+        """The first call of a program just cached.  An infeasible explicit
+        stage raises at its first trace: the program is dropped rather than
+        left permanently raising in the cache (warm entries stay through
+        transient runtime failures)."""
+        try:
+            return fn(*args)
+        except Exception:
+            if self._jitted.pop(key, None) is not None:
+                self._evicted()
+            raise
+
+    def _compiled(self, key: tuple, ops: tuple[str, ...], stage: Stage,
+                  axis: int, n_components: int, batch: int, region=None,
+                  seeded: bool = False):
+        """Build and cache the op-set program for ``key``."""
 
         def stack_seeds(seeds):
             return jax.tree.map(lambda *xs: jnp.stack(xs), *seeds)
 
         if oplib.is_vector_ops(ops):
-            def run(*flat, _ops=ops, _stage=stage, _b=batch,
-                    _nc=n_components, _r=region, _axis=axis):
+            def op_set(*flat, _ops=ops, _stage=stage, _b=batch,
+                       _nc=n_components, _r=region, _axis=axis):
                 comps = [batch_stack(flat[i * _b:(i + 1) * _b])
                          for i in range(_nc)]
                 if seeded:  # trailing args: seeds, component-major like fields
@@ -113,8 +174,8 @@ class BatchedAnalytics:
                 return jax.vmap(lambda *cs: oplib.compute(
                     list(cs), _ops, _stage, axis=_axis, region=_r))(*comps)
         else:
-            def run(*flat, _ops=ops, _stage=stage, _b=batch, _r=region,
-                    _axis=axis):
+            def op_set(*flat, _ops=ops, _stage=stage, _b=batch, _r=region,
+                       _axis=axis):
                 stacked = batch_stack(flat[:_b])
                 if seeded:
                     sstack = stack_seeds(flat[_b:])
@@ -124,20 +185,13 @@ class BatchedAnalytics:
                 return jax.vmap(lambda c: oplib.compute(
                     c, _ops, _stage, axis=_axis, region=_r))(stacked)
 
-        fn = jax.jit(run)
-        self._jitted[key] = fn
-        while len(self._jitted) > self.cache_limit:
-            self._jitted.popitem(last=False)
+        fn = jax.jit(op_set)
+        self._cache_put(key, fn)
         return fn
 
     @property
     def cache_size(self) -> int:
         return len(self._jitted)
-
-    def _cache_put(self, key: tuple, fn) -> None:
-        self._jitted[key] = fn
-        while len(self._jitted) > self.cache_limit:
-            self._jitted.popitem(last=False)
 
     # -- temporal (streaming) programs --------------------------------------
     def summarize(self, slabs: Sequence[Field], stage: Stage, *,
@@ -156,50 +210,45 @@ class BatchedAnalytics:
         """
         if not slabs:
             raise ValueError("empty slab batch")
-        first = slabs[0]
-        stage = Stage(stage)
-        norm = (region_mod.normalize_region(region, first.shape[1:])
-                if region is not None else None)
-        b = len(slabs)
-        padded = list(slabs)
-        if self.bucket_batches:
-            padded += [slabs[-1]] * (self._bucket(b) - b)
-        key = layout_key(first) + ("__temporal_summary__", stage, norm,
-                                   len(padded), oplib.kernel_sig())
-        fn = self._jitted.get(key)
-        fresh = fn is None
-        if fn is None:
-            def run(*flat, _stage=stage, _r=norm, _b=len(padded)):
+        with obs.span(obs.ENGINE_DISPATCH):
+            first = slabs[0]
+            stage = Stage(stage)
+            norm = (region_mod.normalize_region(region, first.shape[1:])
+                    if region is not None else None)
+            b = len(slabs)
+            padded = list(slabs)
+            if self.bucket_batches:
+                padded += [slabs[-1]] * (self._bucket(b) - b)
+            key = layout_key(first) + ("__temporal_summary__", stage, norm,
+                                       len(padded), oplib.kernel_sig())
+            fn = self._lookup(key)
+            if fn is not None:
+                return self._unpad(fn(*padded), b, len(padded))
+        with obs.span(obs.ENGINE_BUILD):
+            def temporal_summarize(*flat, _stage=stage, _r=norm,
+                                   _b=len(padded)):
                 stacked = batch_stack(flat[:_b])
                 return jax.vmap(lambda c: oplib.summarize_slab(
                     c, _stage, region=_r))(stacked)
 
-            fn = jax.jit(run)
+            fn = jax.jit(temporal_summarize)
             self._cache_put(key, fn)
-        else:
-            self._jitted.move_to_end(key)
-        try:
-            out = fn(*padded)
-        except Exception:
-            if fresh:  # infeasible stage raises at trace: don't cache it
-                self._jitted.pop(key, None)
-            raise
-        if len(padded) != b:
-            out = jax.tree.map(lambda x: x[:b], out)
-        return out
+            return self._unpad(self._first_call(key, fn, *padded), b,
+                               len(padded))
 
     def merge_summaries(self, a, b):
         """Jitted pairwise summary merge — ONE program per summary
         signature, reused for every append and every fold step, so merging
         a K-slab stream never retraces as K grows."""
-        key = ("__temporal_merge__", a.sig(), b.sig())
-        fn = self._jitted.get(key)
-        if fn is None:
+        with obs.span(obs.ENGINE_DISPATCH):
+            key = ("__temporal_merge__", a.sig(), b.sig())
+            fn = self._lookup(key)
+            if fn is not None:
+                return fn(a, b)
+        with obs.span(obs.ENGINE_BUILD):
             fn = jax.jit(oplib.merge_summaries)
             self._cache_put(key, fn)
-        else:
-            self._jitted.move_to_end(key)
-        return fn(a, b)
+            return self._first_call(key, fn, a, b)
 
     def run_temporal(self, ops: str | Sequence[str], summary, eps):
         """Temporal op postludes on one merged summary: one compiled
@@ -209,15 +258,18 @@ class BatchedAnalytics:
         names = oplib.canonical_ops(ops)
         if not oplib.is_temporal_ops(names):
             raise ValueError(f"{names} is not a temporal op set")
-        key = ("__temporal_post__", names, summary.sig())
-        fn = self._jitted.get(key)
-        if fn is None:
-            fn = jax.jit(lambda s, e, _names=names:
-                         oplib.temporal_postlude(_names, s, e))
+        with obs.span(obs.ENGINE_DISPATCH):
+            key = ("__temporal_post__", names, summary.sig())
+            fn = self._lookup(key)
+            if fn is not None:
+                return fn(summary, eps)
+        with obs.span(obs.ENGINE_BUILD):
+            def temporal_postlude(s, e, _names=names):
+                return oplib.temporal_postlude(_names, s, e)
+
+            fn = jax.jit(temporal_postlude)
             self._cache_put(key, fn)
-        else:
-            self._jitted.move_to_end(key)
-        return fn(summary, eps)
+            return self._first_call(key, fn, summary, eps)
 
     # -- expression DAGs ----------------------------------------------------
     def run_expr(self, program, bindings: Sequence, stages: Sequence[Stage],
@@ -263,36 +315,32 @@ class BatchedAnalytics:
                 return tuple(x.sig() for x in s)
             return s.sig()
 
-        pre_keys = tuple(sorted(precomputed))
-        pre_sig = tuple((k, jnp.shape(precomputed[k]),
-                         str(jnp.result_type(precomputed[k])))
-                        for k in pre_keys)
-        key = ("__expr__", program.key,
-               tuple(slot_layout(b) for b in bindings),
-               tuple(Stage(s) for s in stages),
-               tuple(slot_region(b) for b in bindings),
-               tuple(slot_seed_sig(s) for s in seeds), pre_sig,
-               oplib.kernel_sig())
-        fn = self._jitted.get(key)
-        fresh = fn is None
-        if fn is None:
-            def run(binds, sds, pre_vals, _stages=tuple(stages), _r=region):
+        with obs.span(obs.ENGINE_DISPATCH):
+            pre_keys = tuple(sorted(precomputed))
+            pre_sig = tuple((k, jnp.shape(precomputed[k]),
+                             str(jnp.result_type(precomputed[k])))
+                            for k in pre_keys)
+            key = ("__expr__", program.key,
+                   tuple(slot_layout(b) for b in bindings),
+                   tuple(Stage(s) for s in stages),
+                   tuple(slot_region(b) for b in bindings),
+                   tuple(slot_seed_sig(s) for s in seeds), pre_sig,
+                   oplib.kernel_sig())
+            args = (list(bindings), seeds, [precomputed[k] for k in pre_keys])
+            fn = self._lookup(key)
+            if fn is not None:
+                return fn(*args)
+        with obs.span(obs.ENGINE_BUILD):
+            def expr_dag(binds, sds, pre_vals, _stages=tuple(stages),
+                         _r=region):
                 return expr_mod.lower(program, binds, _stages, region=_r,
                                       seeds=sds,
                                       precomputed=dict(zip(pre_keys,
                                                            pre_vals)))
 
-            fn = jax.jit(run)
+            fn = jax.jit(expr_dag)
             self._cache_put(key, fn)
-        else:
-            self._jitted.move_to_end(key)
-        try:
-            return fn(list(bindings), seeds,
-                      [precomputed[k] for k in pre_keys])
-        except Exception:
-            if fresh:  # infeasible stage raises at trace: don't cache it
-                self._jitted.pop(key, None)
-            raise
+            return self._first_call(key, fn, *args)
 
     # -- stage resolution ---------------------------------------------------
     def _resolve(self, scheme, names: tuple[str, ...], stage: StageLike,
@@ -361,65 +409,64 @@ class BatchedAnalytics:
                    for op in names}
             return out[names[0]] if single else out
 
-        seed_sig = None
-        if seeds is not None:
-            if len(seeds) != len(fields):
-                raise ValueError(
-                    f"{len(seeds)} seeds for {len(fields)} fields")
-            # per-component signatures may differ (per-axis band closures);
-            # across the batch each component's seeds must agree to stack
-            per_comp = (tuple(zip(*seeds)) if vector else (tuple(seeds),))
-            comp_sigs = []
-            for comp_seeds in per_comp:
-                sigs = {s.sig() for s in comp_seeds}
-                if len(sigs) != 1:
+        with obs.span(obs.ENGINE_DISPATCH):
+            seed_sig = None
+            if seeds is not None:
+                if len(seeds) != len(fields):
                     raise ValueError(
-                        f"seeds must share one layout signature per "
-                        f"component, got {sigs}")
-                comp_sigs.append(sigs.pop())
-                # the seed owns the stage-serving rule (③ serves ④, ...)
-                if not comp_seeds[0].serves(plan.fused):
-                    raise ValueError(
-                        f"seeds materialized at stage "
-                        f"{Stage(comp_seeds[0].stage).name} cannot seed a "
-                        f"stage-{plan.fused.name} plan")
-            seed_sig = tuple(comp_sigs)
+                        f"{len(seeds)} seeds for {len(fields)} fields")
+                # per-component signatures may differ (per-axis band
+                # closures); across the batch each component's seeds must
+                # agree to stack
+                per_comp = (tuple(zip(*seeds)) if vector
+                            else (tuple(seeds),))
+                comp_sigs = []
+                for comp_seeds in per_comp:
+                    sigs = {s.sig() for s in comp_seeds}
+                    if len(sigs) != 1:
+                        raise ValueError(
+                            f"seeds must share one layout signature per "
+                            f"component, got {sigs}")
+                    comp_sigs.append(sigs.pop())
+                    # the seed owns the stage-serving rule (③ serves ④, ...)
+                    if not comp_seeds[0].serves(plan.fused):
+                        raise ValueError(
+                            f"seeds materialized at stage "
+                            f"{Stage(comp_seeds[0].stage).name} cannot seed "
+                            f"a stage-{plan.fused.name} plan")
+                seed_sig = tuple(comp_sigs)
 
-        b = len(fields)
-        padded = list(fields)
-        padded_seeds = list(seeds) if seeds is not None else None
-        if self.bucket_batches:
-            pad = self._bucket(b) - b
-            padded += [fields[-1]] * pad
-            if padded_seeds is not None:
-                padded_seeds += [padded_seeds[-1]] * pad
-        key = batch_key(first, names, plan.fused, d_axis, n_comp,
-                        len(padded), region, seed_sig)
-        fresh = key not in self._jitted
-        fn = self._compiled(key, names, plan.fused, d_axis, n_comp,
-                            len(padded), region, seeded=seeds is not None)
-        if vector:
-            # component-major flat args: (f0[c], f1[c], ...) for each c
-            flat = tuple(f[i] for i in range(n_comp) for f in padded)
-            if padded_seeds is not None:
-                flat += tuple(s[i] for i in range(n_comp)
-                              for s in padded_seeds)
-        else:
-            flat = tuple(padded)
-            if padded_seeds is not None:
-                flat += tuple(padded_seeds)
-        try:
-            out = fn(*flat)
-        except Exception:
-            # an infeasible explicit stage raises at first trace; don't leave
-            # a permanently-raising program in the cache (but keep warm
-            # entries through transient runtime failures)
-            if fresh:
-                self._jitted.pop(key, None)
-            raise
-        if len(padded) != b:
-            out = jax.tree.map(lambda x: x[:b], out)
-        return out[names[0]] if single else out
+            b = len(fields)
+            padded = list(fields)
+            padded_seeds = list(seeds) if seeds is not None else None
+            if self.bucket_batches:
+                pad = self._bucket(b) - b
+                padded += [fields[-1]] * pad
+                if padded_seeds is not None:
+                    padded_seeds += [padded_seeds[-1]] * pad
+            key = batch_key(first, names, plan.fused, d_axis, n_comp,
+                            len(padded), region, seed_sig)
+            if vector:
+                # component-major flat args: (f0[c], f1[c], ...) for each c
+                flat = tuple(f[i] for i in range(n_comp) for f in padded)
+                if padded_seeds is not None:
+                    flat += tuple(s[i] for i in range(n_comp)
+                                  for s in padded_seeds)
+            else:
+                flat = tuple(padded)
+                if padded_seeds is not None:
+                    flat += tuple(padded_seeds)
+            fn = self._lookup(key)
+            if fn is not None:
+                out = self._unpad(fn(*flat), b, len(padded))
+                return out[names[0]] if single else out
+        with obs.span(obs.ENGINE_BUILD):
+            fn = self._compiled(key, names, plan.fused, d_axis, n_comp,
+                                len(padded), region,
+                                seeded=seeds is not None)
+            out = self._unpad(self._first_call(key, fn, *flat), b,
+                              len(padded))
+            return out[names[0]] if single else out
 
 
 #: process-wide engine (shared jit cache) used by the query front-end.

@@ -18,6 +18,9 @@ with the resident intermediates — a cache hit pays only the op postludes.
 A miss materializes through the store (one reconstruction per field
 lifetime, LRU/byte-budget permitting).  Results are bit-identical to the
 storeless path at the same stage.
+
+Planning runs in a ``repro.query.plan`` span and the store's ``seed`` calls
+in a ``repro.store.seed`` span (:mod:`repro.obs`); the engine opens its own.
 """
 from __future__ import annotations
 from collections.abc import Sequence
@@ -25,6 +28,7 @@ from collections.abc import Sequence
 import dataclasses
 import warnings
 
+from repro import obs
 from repro.core import Compressed, Encoded, Stage, layout_key, oplib
 from repro.core import expr as expr_mod
 
@@ -230,28 +234,30 @@ def _query_opset(fields: Sequence[FieldOrVector],
         engine = default_engine
     d_axis = axis if any(oplib.OPS[n].needs_axis for n in names) else 0
 
-    resolved: list = []
-    ids: list = []
-    for item in fields:
-        r, fid = _resolve_item(item, store, vector)
-        for c in (r if vector else (r,)):
-            if hasattr(c, "layout_sig"):  # TemporalField (repro.stream)
-                raise TypeError(
-                    f"spatial op set {names} takes Compressed/Encoded "
-                    "fields; a temporal field answers temporal ops "
-                    f"({', '.join(oplib.TEMPORAL_OPS)}) instead")
-        resolved.append(r)
-        ids.append(fid)
+    with obs.span(obs.QUERY_PLAN):
+        resolved: list = []
+        ids: list = []
+        for item in fields:
+            r, fid = _resolve_item(item, store, vector)
+            for c in (r if vector else (r,)):
+                if hasattr(c, "layout_sig"):  # TemporalField (repro.stream)
+                    raise TypeError(
+                        f"spatial op set {names} takes Compressed/Encoded "
+                        "fields; a temporal field answers temporal ops "
+                        f"({', '.join(oplib.TEMPORAL_OPS)}) instead")
+            resolved.append(r)
+            ids.append(fid)
+
+        # group by static layout signature (store-backed items separately:
+        # only they carry the cache identity seeding needs), preserving
+        # input order
+        groups: dict[tuple, list[int]] = {}
+        for i, item in enumerate(resolved):
+            sig = (_group_signature(item, vector), ids[i] is not None)
+            groups.setdefault(sig, []).append(i)
 
     hits0, misses0 = ((store.stats.hits, store.stats.misses)
                       if store is not None else (0, 0))
-
-    # group by static layout signature (store-backed items separately: only
-    # they carry the cache identity seeding needs), preserving input order
-    groups: dict[tuple, list[int]] = {}
-    for i, item in enumerate(resolved):
-        sig = (_group_signature(item, vector), ids[i] is not None)
-        groups.setdefault(sig, []).append(i)
 
     values: list = [None] * len(fields)
     stages: list = [None] * len(fields)
@@ -259,40 +265,43 @@ def _query_opset(fields: Sequence[FieldOrVector],
     for (_, store_backed), indices in groups.items():
         group = [resolved[i] for i in indices]
         first = group[0][0] if vector else group[0]
-        cached = None
-        placement = None
-        if store_backed:
-            sets = [store.cached_stages(ids[i], names, region=region,
-                                        axis=d_axis) for i in indices]
-            cached = frozenset.intersection(*sets)
-            # a sharded store prices reconstruction as the max over
-            # participating shards (repro.shard); single-device stores
-            # don't expose placement_of and keep the spatial fraction
-            placement_of = getattr(store, "placement_of", None)
-            if placement_of is not None:
-                fid0 = ids[indices[0]]
-                placement = placement_of(fid0 if isinstance(fid0, str)
-                                         else fid0[0])
-        plan = plan_stages(first.scheme, names, stage,
-                           cost_model or engine.cost_model,
-                           region=region, field=first, axis=d_axis,
-                           cached=cached, placement=placement)
+        with obs.span(obs.QUERY_PLAN):
+            cached = None
+            placement = None
+            if store_backed:
+                sets = [store.cached_stages(ids[i], names, region=region,
+                                            axis=d_axis) for i in indices]
+                cached = frozenset.intersection(*sets)
+                # a sharded store prices reconstruction as the max over
+                # participating shards (repro.shard); single-device stores
+                # don't expose placement_of and keep the spatial fraction
+                placement_of = getattr(store, "placement_of", None)
+                if placement_of is not None:
+                    fid0 = ids[indices[0]]
+                    placement = placement_of(fid0 if isinstance(fid0, str)
+                                             else fid0[0])
+            plan = plan_stages(first.scheme, names, stage,
+                               cost_model or engine.cost_model,
+                               region=region, field=first, axis=d_axis,
+                               cached=cached, placement=placement)
         seeds = None
         if (store_backed and plan.fused is not None
                 and plan.fused != Stage.M):
             s = plan.fused
-            if vector:
-                closures = oplib.component_closures(
-                    names, [c.scheme for c in group[0]], s)
-                seeds = [tuple(store.seed(fid, s, region=region, closure=cl)
-                               for fid, cl in zip(ids[i], closures))
-                         for i in indices]
-                flat = [m for item in seeds for m in item]
-            else:
-                cl = oplib.set_closure(names, first.scheme, s, d_axis)
-                seeds = [store.seed(ids[i], s, region=region, closure=cl)
-                         for i in indices]
-                flat = seeds
+            with obs.span(obs.STORE_SEED):
+                if vector:
+                    closures = oplib.component_closures(
+                        names, [c.scheme for c in group[0]], s)
+                    seeds = [tuple(store.seed(fid, s, region=region,
+                                              closure=cl)
+                                   for fid, cl in zip(ids[i], closures))
+                             for i in indices]
+                    flat = [m for item in seeds for m in item]
+                else:
+                    cl = oplib.set_closure(names, first.scheme, s, d_axis)
+                    seeds = [store.seed(ids[i], s, region=region, closure=cl)
+                             for i in indices]
+                    flat = seeds
             if any(m is None for m in flat):
                 # some cell can never be retained under the byte budget:
                 # re-materializing it every call would make the store a
@@ -355,59 +364,61 @@ def _query_exprs(exprs, stage="auto", *, region=None,
     if engine is None:
         engine = default_engine
     single = isinstance(exprs, expr_mod.Expr)
-    program = expr_mod.analyze([exprs] if single else list(exprs))
-
     stats = getattr(store, "stats", None) if store is not None else None
     hits0, misses0 = (stats.hits, stats.misses) if stats else (0, 0)
+    with obs.span(obs.QUERY_PLAN):
+        program = expr_mod.analyze([exprs] if single else list(exprs))
 
-    bindings: list = []
-    slot_ids: list = []
-    for slot, lf in enumerate(program.leaves):
-        b, fid = _resolve_leaf(lf, store)
-        temporal = program.leaf_is_temporal(slot)
-        for c in (b if isinstance(b, tuple) else (b,)):
-            if hasattr(c, "layout_sig") != temporal:
-                consumers = ", ".join(n for n, _ in
-                                      program.leaf_consumers(slot))
-                raise TypeError(
-                    f"leaf {lf.key} binds a {type(c).__name__} but its "
-                    f"consumers ({consumers}) are "
-                    f"{'temporal' if temporal else 'spatial'} ops")
-        if temporal and not b.slabs:
-            raise ValueError("temporal field has no appended slabs"
-                             + (f" (id {fid!r})" if fid else ""))
-        bindings.append(b)
-        slot_ids.append(fid)
-    expr_mod.validate_bound(program, bindings, region=region)
+        bindings: list = []
+        slot_ids: list = []
+        for slot, lf in enumerate(program.leaves):
+            b, fid = _resolve_leaf(lf, store)
+            temporal = program.leaf_is_temporal(slot)
+            for c in (b if isinstance(b, tuple) else (b,)):
+                if hasattr(c, "layout_sig") != temporal:
+                    consumers = ", ".join(n for n, _ in
+                                          program.leaf_consumers(slot))
+                    raise TypeError(
+                        f"leaf {lf.key} binds a {type(c).__name__} but its "
+                        f"consumers ({consumers}) are "
+                        f"{'temporal' if temporal else 'spatial'} ops")
+            if temporal and not b.slabs:
+                raise ValueError("temporal field has no appended slabs"
+                                 + (f" (id {fid!r})" if fid else ""))
+            bindings.append(b)
+            slot_ids.append(fid)
+        expr_mod.validate_bound(program, bindings, region=region)
 
-    def slot_cached(slot: int) -> frozenset:
-        fid = slot_ids[slot]
-        if (fid is None or program.leaf_is_temporal(slot)
-                or not hasattr(store, "is_resident")):
-            return frozenset()
-        b = bindings[slot]
-        out = set()
-        for s in (Stage.P, Stage.Q, Stage.F):
-            try:
-                if isinstance(b, tuple):
-                    cls = expr_mod.vector_closures(
-                        program, slot, [c.scheme for c in b], s)
-                    ok = all(store.is_resident(f, s, region=region,
+        def slot_cached(slot: int) -> frozenset:
+            fid = slot_ids[slot]
+            if (fid is None or program.leaf_is_temporal(slot)
+                    or not hasattr(store, "is_resident")):
+                return frozenset()
+            b = bindings[slot]
+            out = set()
+            for s in (Stage.P, Stage.Q, Stage.F):
+                try:
+                    if isinstance(b, tuple):
+                        cls = expr_mod.vector_closures(
+                            program, slot, [c.scheme for c in b], s)
+                        ok = all(store.is_resident(f, s, region=region,
+                                                   closure=cl)
+                                 for f, cl in zip(fid, cls))
+                    else:
+                        cl = expr_mod.leaf_closure(program, slot, b.scheme,
+                                                   s)
+                        ok = store.is_resident(fid, s, region=region,
                                                closure=cl)
-                             for f, cl in zip(fid, cls))
-                else:
-                    cl = expr_mod.leaf_closure(program, slot, b.scheme, s)
-                    ok = store.is_resident(fid, s, region=region, closure=cl)
-            except Exception:  # closure undefined at an infeasible stage
-                continue
-            if ok:
-                out.add(s)
-        return frozenset(out)
+                except Exception:  # closure undefined at an infeasible stage
+                    continue
+                if ok:
+                    out.add(s)
+            return frozenset(out)
 
-    cached = [slot_cached(s) for s in range(len(program.leaves))]
-    plan = plan_expr(program, bindings, stage,
-                     cost_model or engine.cost_model,
-                     region=region, cached=cached)
+        cached = [slot_cached(s) for s in range(len(program.leaves))]
+        plan = plan_expr(program, bindings, stage,
+                         cost_model or engine.cost_model,
+                         region=region, cached=cached)
 
     # temporal op nodes: summaries reduce outside the spatial trace (one
     # shared summary per stream slot), values join the DAG via `precomputed`
@@ -438,23 +449,25 @@ def _query_exprs(exprs, stage="auto", *, region=None,
 
     seeds: list = [None] * len(bindings)
     if store is not None and hasattr(store, "seed"):
-        for slot in range(len(program.leaves)):
-            fid = slot_ids[slot]
-            if fid is None or program.leaf_is_temporal(slot):
-                continue
-            s = plan.stages[program.leaf_component[slot]]
-            if s == Stage.M:
-                continue  # metadata is always resident in the container
-            b = bindings[slot]
-            if isinstance(b, tuple):
-                cls = expr_mod.vector_closures(
-                    program, slot, [c.scheme for c in b], s)
-                ms = tuple(store.seed(f, s, region=region, closure=cl)
-                           for f, cl in zip(fid, cls))
-                seeds[slot] = ms if all(m is not None for m in ms) else None
-            else:
-                cl = expr_mod.leaf_closure(program, slot, b.scheme, s)
-                seeds[slot] = store.seed(fid, s, region=region, closure=cl)
+        with obs.span(obs.STORE_SEED):
+            for slot in range(len(program.leaves)):
+                fid = slot_ids[slot]
+                if fid is None or program.leaf_is_temporal(slot):
+                    continue
+                s = plan.stages[program.leaf_component[slot]]
+                if s == Stage.M:
+                    continue  # metadata is always resident in the container
+                b = bindings[slot]
+                if isinstance(b, tuple):
+                    cls = expr_mod.vector_closures(
+                        program, slot, [c.scheme for c in b], s)
+                    ms = tuple(store.seed(f, s, region=region, closure=cl)
+                               for f, cl in zip(fid, cls))
+                    seeds[slot] = (ms if all(m is not None for m in ms)
+                                   else None)
+                else:
+                    cl = expr_mod.leaf_closure(program, slot, b.scheme, s)
+                    seeds[slot] = store.seed(fid, s, region=region, closure=cl)
 
     if all(program.serial(r) in precomputed for r in program.roots):
         out = tuple(precomputed[program.serial(r)] for r in program.roots)

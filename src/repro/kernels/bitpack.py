@@ -117,6 +117,7 @@ def pack(u: jax.Array, bits: int, *, interpret: bool = False) -> jax.Array:
         out_specs=pl.BlockSpec((words_per,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad * bits // 32,), jnp.uint32),
         interpret=interpret,
+        name="bitpack_pack",
     )(u.astype(jnp.uint32))
     return out[:_words_for(n, bits)]
 
@@ -148,5 +149,6 @@ def unpack(words: jax.Array, n: int, bits: int, *, interpret: bool = False) -> j
         out_specs=pl.BlockSpec((blk, ROW_VALS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, ROW_VALS), jnp.int32),
         interpret=interpret,
+        name="bitpack_unpack",
     )(w)
     return out.reshape(-1)[:n].astype(jnp.uint32)

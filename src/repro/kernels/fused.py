@@ -308,6 +308,7 @@ def lorenzo2d(p: jax.Array, *, what: str, interpret: bool = False):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=f"lorenzo2d_{what}",
     )(p, halo)
 
 
@@ -336,6 +337,7 @@ def lorenzo_enc2d(payload: jax.Array, shape: tuple, bits: int, *,
         out_specs=hspec,
         out_shape=jax.ShapeDtypeStruct((nb * SUBLANES, n1), jnp.int32),
         interpret=interpret,
+        name="lorenzo_enc2d_colsum",
     )(words, offs)
     nxt = jnp.concatenate(
         [unpack_rows(payload, jnp.arange(1, nb, dtype=jnp.int32) * r,
@@ -350,6 +352,7 @@ def lorenzo_enc2d(payload: jax.Array, shape: tuple, bits: int, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=f"lorenzo_enc2d_{what}",
     )(words, offs, halo)
 
 
@@ -459,6 +462,7 @@ def blockmean2d(p: jax.Array, meta: jax.Array, block: tuple, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=f"blockmean2d_{what}",
     )(p, mtiles, halo)
 
 
@@ -498,4 +502,5 @@ def blockmean_enc2d(payload: jax.Array, meta: jax.Array, shape: tuple,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=f"blockmean_enc2d_{what}",
     )(words, offs, mtiles, halo)

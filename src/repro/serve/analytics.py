@@ -33,9 +33,11 @@ from __future__ import annotations
 from collections.abc import Sequence
 
 import dataclasses
+import itertools
 import warnings
 from typing import Any
 
+from repro import obs
 from repro.analytics import CostModel, query
 from repro.analytics.engine import BatchedAnalytics
 from repro.analytics.query import _group_signature, _query_opset, _resolve_item
@@ -127,6 +129,7 @@ class AnalyticsFrontend:
         self.max_batch = max_batch
         self.store = store
         self._queue: list[AnalyticsRequest] = []
+        self._serials = itertools.count(1)
 
     def _resolve_fields(self, req: AnalyticsRequest, vector: bool):
         """Id-free view of a request's fields (for grouping signatures);
@@ -170,7 +173,16 @@ class AnalyticsFrontend:
         everything servable in the step is served, and a rejected request
         never leaves a poisoned entry in the engine's jit cache (fresh
         failing programs are evicted by the engine itself).
+
+        The step runs in a ``repro.frontend.step`` span (:mod:`repro.obs`)
+        that carries its serial and counts the requests it finished.
         """
+        with obs.span(obs.FRONTEND_STEP, step=next(self._serials)) as sp:
+            finished = self._serve()
+            sp.count = len(finished)
+        return finished
+
+    def _serve(self) -> list[AnalyticsRequest | AppendRequest]:
         batch, self._queue = self._queue[:self.max_batch], self._queue[self.max_batch:]
         finished: list[AnalyticsRequest | AppendRequest] = []
         analytics_batch: list[AnalyticsRequest] = []
@@ -210,7 +222,7 @@ class AnalyticsFrontend:
                 warnings.warn(
                     "the AnalyticsRequest.op op-set form is deprecated; "
                     "send AnalyticsRequest(exprs=[...]) expressions instead "
-                    "(repro.core.expr)", DeprecationWarning, stacklevel=2)
+                    "(repro.core.expr)", DeprecationWarning, stacklevel=3)
             try:
                 ops = oplib.canonical_ops(req.op)
                 vector = oplib.is_vector_ops(ops)
