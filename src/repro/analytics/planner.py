@@ -16,6 +16,15 @@ which matches the paper's measurements); a :class:`CostModel` calibrated from
 ``benchmarks/run.py`` CSV output refines the choice with measured
 microseconds per call.
 
+Store-backed planning (``servable`` given: the stages whose
+materialization the store holds or can keep and from which every planned
+op's rule runs straight, ``oplib.reads_seed``) ranks stages by what a
+request costs once that materialization is resident: those stages come
+first, and stages whose rules would still recorrelate or decode (Lorenzo
+prefix sums over a stage-② seed in XLA, a fused stage-③ kernel that
+decodes the payload) after; residency, ① and stage order break ties
+(:func:`_auto_stage`).  Storeless planning keeps Table I's cold order.
+
 ``plan_stages`` plans an *op set* jointly: it picks one shared stage
 minimizing the **total** cost over the feasible intersection, so a fused
 query pays a single stage reconstruction for every op (DESIGN.md §6).  When
@@ -39,6 +48,7 @@ import dataclasses
 import json
 import os
 
+from repro import obs
 from repro.core import Scheme, Stage, UnsupportedStageError, oplib
 from repro.core import region as region_mod
 
@@ -112,6 +122,31 @@ def _resident_rank(cached: AbstractSet[Stage]):
     to stage order."""
     resident = set(cached) | {Stage.M}
     return lambda s: (0 if s in resident else 1, int(s))
+
+
+def _auto_stage(stages: Sequence[Stage], resident: AbstractSet[Stage],
+                servable: AbstractSet[Stage] | None) -> Stage:
+    """Uncalibrated ``stage="auto"`` choice among feasible ``stages``.
+
+    ``servable`` (``None`` unless every planned input is store-backed)
+    names the stages whose materialization the store holds or can keep
+    and from which every planned op's rule runs straight: those, and ①
+    (metadata is always resident), rank before every other stage.  Within
+    each class :func:`_resident_rank` breaks ties (``resident``: the
+    stages resident for every input).  With nothing resident and nothing
+    store-backed this is stage order.
+    """
+    first = set(servable or ()) | {Stage.M}
+    rank = _resident_rank(resident)
+    return min(stages, key=lambda s: (s not in first, rank(s)))
+
+
+def _count_promotion(servable: AbstractSet[Stage] | None,
+                     promoted: bool) -> None:
+    """Count one planned store-backed component (or flat op-set group) put
+    above the stage storeless planning picks."""
+    if servable is not None and promoted:
+        obs.counters["plan_resident_promotions"] += 1
 
 
 class CostModel:
@@ -331,7 +366,8 @@ def plan_stage(scheme: Scheme, op: str,
                stage: Stage | str | int = "auto",
                cost_model: CostModel | None = None, *,
                region=None, field=None, axis: int = 0,
-               cached: AbstractSet[Stage] | None = None) -> Stage:
+               cached: AbstractSet[Stage] | None = None,
+               servable: AbstractSet[Stage] | None = None) -> Stage:
     """Resolve the execution stage for ``op`` on ``scheme``.
 
     ``stage="auto"`` picks the cheapest feasible stage (never one that would
@@ -342,6 +378,9 @@ def plan_stage(scheme: Scheme, op: str,
     region-closure size.  ``cached`` names the stages whose materialized
     intermediates are store-resident: their reconstruction term is dropped,
     so auto planning can pick a *higher* stage than it would cold.
+    ``servable`` (store-backed inputs) names the stages the store can
+    serve straight from a materialization; uncalibrated, the choice is
+    :func:`_auto_stage`'s.
     """
     cached = frozenset(cached or ())
     if stage != "auto":
@@ -364,11 +403,7 @@ def plan_stage(scheme: Scheme, op: str,
                                                         axis=axis)
                          for s in stages}
         return cost_model.cheapest(scheme, op, stages, fractions, cached)
-    if cached:
-        # no measured costs, but residency is hard knowledge: a resident
-        # stage pays no reconstruction, which is the dominant term (§V)
-        return min(stages, key=_resident_rank(cached))
-    return stages[0]
+    return _auto_stage(stages, cached, servable)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -424,6 +459,7 @@ def plan_stages(scheme: Scheme, ops: str | Sequence[str],
                 cost_model: CostModel | None = None, *,
                 region=None, field=None, axis: int = 0,
                 cached: AbstractSet[Stage] | None = None,
+                servable: AbstractSet[Stage] | None = None,
                 placement=None) -> StageSetPlan:
     """Jointly resolve the execution stage(s) for an op *set*.
 
@@ -437,7 +473,11 @@ def plan_stages(scheme: Scheme, ops: str | Sequence[str],
     their own decode, so this comparison understates the fusion saving).
     ``cached`` stages (store-resident materializations) are priced without
     their reconstruction term, which can flip the shared stage to a higher
-    one that is already resident.
+    one that is already resident.  Uncalibrated, the shared stage is
+    :func:`_auto_stage`'s choice; ``servable`` (store-backed sets) names
+    the stages the store can serve straight from a materialization, and a
+    store-backed group planned above the storeless stage counts in
+    ``obs.counters["plan_resident_promotions"]``.
 
     ``placement`` (a :class:`repro.shard.BlockPlacement`, duck-typed) turns
     on the sharded cost rule: each op's reconstruction cost scales by the
@@ -475,12 +515,16 @@ def plan_stages(scheme: Scheme, ops: str | Sequence[str],
         return tuple(
             (op, plan_stage(scheme, op, "auto", cost_model,
                             region=region, field=field, axis=axis,
-                            cached=cached))
+                            cached=cached, servable=servable))
             for op in names)
 
     inter = tuple(s for s in Stage if all(s in f for f in feas.values()))
     if not inter:
-        return StageSetPlan(names, per_op_plan(), None)
+        per_op = per_op_plan()
+        if cost_model is None:
+            _count_promotion(servable,
+                             any(s > feas[op][0] for op, s in per_op))
+        return StageSetPlan(names, per_op, None)
 
     # residency only ever discounts stages the candidate can actually run
     # at: for the *shared* choice that is the feasible intersection, so a
@@ -517,14 +561,9 @@ def plan_stages(scheme: Scheme, ops: str | Sequence[str],
         per_total = sum(cost(op, s) for op, s in per_op)
         if per_total < totals[shared]:
             return StageSetPlan(names, per_op, None)
-    elif shared_cached:
-        # uncalibrated but residency is known: a resident shared stage pays
-        # no reconstruction at all — prefer it over any cold stage
-        shared = min(inter, key=_resident_rank(shared_cached))
     else:
-        # stage order is monotone in decompression work (paper §V): the
-        # lowest shared stage is the cheapest joint reconstruction
-        shared = inter[0]
+        shared = _auto_stage(inter, shared_cached, servable)
+        _count_promotion(servable, shared > inter[0])
     return StageSetPlan(names, tuple((op, shared) for op in names), shared)
 
 
@@ -545,7 +584,9 @@ class ExprPlan:
 
 def plan_expr(program, bindings: Sequence, stage="auto",
               cost_model: CostModel | None = None, *, region=None,
-              cached: Sequence[AbstractSet[Stage]] | None = None) -> ExprPlan:
+              cached: Sequence[AbstractSet[Stage]] | None = None,
+              servable: Sequence[AbstractSet[Stage] | None] | None = None
+              ) -> ExprPlan:
     """Jointly plan the execution stage of each DAG component.
 
     Every ``(op application, leaf scheme)`` pair in a component contributes
@@ -554,13 +595,20 @@ def plan_expr(program, bindings: Sequence, stage="auto",
     preludes a combinator joins are stage-compatible.  An explicit ``stage``
     is validated against every pair (op error semantics preserved).  With
     ``stage="auto"``: a fully calibrated cost model minimizes the total
-    (region-closure-scaled, residency-discounted) cost; otherwise stages at
-    which *every* leaf of the component is store-resident (``cached``, per
-    leaf slot) rank first, falling back to stage order.  An unaligned
-    ``region`` drops stage ① exactly as in :func:`plan_stages`.
+    (region-closure-scaled, residency-discounted) cost; otherwise
+    :func:`_auto_stage` chooses, with the stages at which *every* leaf of
+    the component is store-resident (``cached``, per leaf slot) and, for a
+    component whose every leaf is store-backed, the stages the store can
+    serve straight from a materialization for all of them (``servable``,
+    per leaf slot; ``None`` for a slot that is not a store id).  Such a
+    component planned above the storeless stage counts in
+    ``obs.counters["plan_resident_promotions"]``.  An unaligned ``region``
+    drops stage ① exactly as in :func:`plan_stages`.
     """
     cached = (list(cached) if cached is not None
               else [frozenset()] * len(bindings))
+    servable = (list(servable) if servable is not None
+                else [None] * len(bindings))
 
     def slot_field(slot: int):
         b = bindings[slot]
@@ -614,10 +662,12 @@ def plan_expr(program, bindings: Sequence, stage="auto",
 
             totals = {s: sum(pair_cost(*p, s) for p in pairs) for s in inter}
             out.append(min(inter, key=lambda s: (totals[s], int(s))))
-        elif resident:
-            out.append(min(inter, key=_resident_rank(resident)))
         else:
-            out.append(inter[0])
+            keep = [servable[sl] for sl in comp_slots]
+            keep = (None if any(k is None for k in keep)
+                    else frozenset.intersection(*map(frozenset, keep)))
+            out.append(_auto_stage(inter, resident, keep))
+            _count_promotion(keep, out[-1] > inter[0])
     return ExprPlan(tuple(out))
 
 

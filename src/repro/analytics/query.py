@@ -17,7 +17,12 @@ can flip to a resident stage), and the group's compiled program is seeded
 with the resident intermediates — a cache hit pays only the op postludes.
 A miss materializes through the store (one reconstruction per field
 lifetime, LRU/byte-budget permitting).  Results are bit-identical to the
-storeless path at the same stage.
+storeless path at the same stage.  A store-backed ``stage="auto"`` also
+learns which stages the store can serve straight from a materialization
+(:func:`_slot_stages`: resident or within budget, ``can_retain``, and read
+by every op's rule, ``oplib.reads_seed``); uncalibrated, it goes to such a
+stage rather than one whose rules recorrelate or decode again
+(:func:`repro.analytics.planner._auto_stage`).
 
 Planning runs in a ``repro.query.plan`` span and the store's ``seed`` calls
 in a ``repro.store.seed`` span (:mod:`repro.obs`); the engine opens its own.
@@ -31,6 +36,7 @@ import warnings
 from repro import obs
 from repro.core import Compressed, Encoded, Stage, layout_key, oplib
 from repro.core import expr as expr_mod
+from repro.store import MATERIALIZABLE
 
 from .engine import BatchedAnalytics, default_engine
 from .planner import CostModel, plan_expr, plan_stages
@@ -88,6 +94,32 @@ def _store_get(store, fid: str) -> Field:
         raise ValueError(
             f"field id {fid!r} given but no store= attached to the query")
     return store.get(fid)
+
+
+def _slot_stages(store, fids: Sequence[str], fields: Sequence, ops,
+                 closures_at, region) -> tuple[frozenset, frozenset]:
+    """Residency probe of one store-backed slot (a field id, or a vector's
+    component ids, with their ``fields``) under the ``ops`` consuming it:
+    the materializable stages resident for every id, and the servable ones
+    — resident or within the store's budget (``can_retain``) for every id,
+    and read straight by every op's rule (``oplib.reads_seed``).
+    ``closures_at(stage)`` gives each id's closure.  Pure peeks: no LRU
+    order or counter moves."""
+    can_retain = getattr(store, "can_retain", None)
+    resident, servable = set(), set()
+    for s in MATERIALIZABLE:
+        cells = list(zip(fids, fields, closures_at(s)))
+        here = all(store.is_resident(f, s, region=region, closure=cl)
+                   for f, _, cl in cells)
+        if here:
+            resident.add(s)
+        if ((here or (can_retain is not None and all(
+                can_retain(f, s, region=region, closure=cl)
+                for f, _, cl in cells)))
+                and all(oplib.reads_seed(op, c, s, region=region, closure=cl)
+                        for _, c, cl in cells for op in ops)):
+            servable.add(s)
+    return frozenset(resident), frozenset(servable)
 
 
 def _resolve_item(item, store, vector):
@@ -266,12 +298,25 @@ def _query_opset(fields: Sequence[FieldOrVector],
         group = [resolved[i] for i in indices]
         first = group[0][0] if vector else group[0]
         with obs.span(obs.QUERY_PLAN):
-            cached = None
+            cached = servable = None
             placement = None
-            if store_backed:
-                sets = [store.cached_stages(ids[i], names, region=region,
-                                            axis=d_axis) for i in indices]
-                cached = frozenset.intersection(*sets)
+            if store_backed and stage == "auto":
+                if vector:
+                    schemes = [c.scheme for c in group[0]]
+
+                    def closures_at(s):
+                        return oplib.component_closures(names, schemes, s)
+                else:
+                    def closures_at(s):
+                        return (oplib.set_closure(names, first.scheme, s,
+                                                  d_axis),)
+                probes = [_slot_stages(store, ids[i] if vector else (ids[i],),
+                                       resolved[i] if vector
+                                       else (resolved[i],),
+                                       names, closures_at, region)
+                          for i in indices]
+                cached = frozenset.intersection(*(r for r, _ in probes))
+                servable = frozenset.intersection(*(k for _, k in probes))
                 # a sharded store prices reconstruction as the max over
                 # participating shards (repro.shard); single-device stores
                 # don't expose placement_of and keep the spatial fraction
@@ -283,7 +328,8 @@ def _query_opset(fields: Sequence[FieldOrVector],
             plan = plan_stages(first.scheme, names, stage,
                                cost_model or engine.cost_model,
                                region=region, field=first, axis=d_axis,
-                               cached=cached, placement=placement)
+                               cached=cached, servable=servable,
+                               placement=placement)
         seeds = None
         if (store_backed and plan.fused is not None
                 and plan.fused != Stage.M):
@@ -389,36 +435,29 @@ def _query_exprs(exprs, stage="auto", *, region=None,
             slot_ids.append(fid)
         expr_mod.validate_bound(program, bindings, region=region)
 
-        def slot_cached(slot: int) -> frozenset:
+        def slot_stages(slot: int) -> tuple[frozenset, frozenset | None]:
             fid = slot_ids[slot]
             if (fid is None or program.leaf_is_temporal(slot)
                     or not hasattr(store, "is_resident")):
-                return frozenset()
+                return frozenset(), None
             b = bindings[slot]
-            out = set()
-            for s in (Stage.P, Stage.Q, Stage.F):
-                try:
-                    if isinstance(b, tuple):
-                        cls = expr_mod.vector_closures(
-                            program, slot, [c.scheme for c in b], s)
-                        ok = all(store.is_resident(f, s, region=region,
-                                                   closure=cl)
-                                 for f, cl in zip(fid, cls))
-                    else:
-                        cl = expr_mod.leaf_closure(program, slot, b.scheme,
-                                                   s)
-                        ok = store.is_resident(fid, s, region=region,
-                                               closure=cl)
-                except Exception:  # closure undefined at an infeasible stage
-                    continue
-                if ok:
-                    out.add(s)
-            return frozenset(out)
+            ops = {n for n, _ in program.leaf_consumers(slot)}
+            if isinstance(b, tuple):
+                schemes = [c.scheme for c in b]
+                return _slot_stages(
+                    store, fid, b, ops, lambda s: expr_mod.vector_closures(
+                        program, slot, schemes, s), region)
+            return _slot_stages(
+                store, (fid,), (b,), ops, lambda s: (expr_mod.leaf_closure(
+                    program, slot, b.scheme, s),), region)
 
-        cached = [slot_cached(s) for s in range(len(program.leaves))]
+        # an explicit stage is planned without residency
+        probes = ([slot_stages(s) for s in range(len(program.leaves))]
+                  if stage == "auto" else [(frozenset(), None)] * len(bindings))
         plan = plan_expr(program, bindings, stage,
-                         cost_model or engine.cost_model,
-                         region=region, cached=cached)
+                         cost_model or engine.cost_model, region=region,
+                         cached=[r for r, _ in probes],
+                         servable=[k for _, k in probes])
 
     # temporal op nodes: summaries reduce outside the spatial trace (one
     # shared summary per stream slot), values join the DAG via `precomputed`

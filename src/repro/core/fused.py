@@ -74,10 +74,17 @@ from .stages import Encoded, Stage
 
 @dataclass(frozen=True)
 class FusedRule:
-    """A Pallas-backed lowering rule with a static coverage predicate."""
+    """A Pallas-backed lowering rule with a static coverage predicate.
+
+    ``reads_seed`` says whether the rule runs off a resident
+    materialization of its stage: the stage-② rules run their kernels on
+    ``ctx.sub``, which a stage-② seed holds; the stage-③④ rules need the
+    residual plane (or the payload) too, which a stage-③ seed does not
+    hold, so they decode whatever is resident."""
 
     fn: Callable          # (ctx, axis) -> result, same signature as XLA rules
     covers: Callable      # (ctx) -> bool: can this rule serve the context?
+    reads_seed: bool = False
 
     def __call__(self, ctx, axis: int):
         return self.fn(ctx, axis)
@@ -189,35 +196,35 @@ def _lap_blockmean_q(ctx, axis: int) -> jax.Array:
 
 # -- registries wired onto the OpSpecs (oplib imports these) ----------------
 
-def _rule(fn) -> FusedRule:
-    return FusedRule(fn, _covers_2d)
+def _rule(fn, stage: Stage) -> FusedRule:
+    return FusedRule(fn, _covers_2d, reads_seed=stage == Stage.P)
 
 
 #: derivative cells — also dispatched by ``oplib._derivative_at``, which
 #: hands the kernels to gradient/divergence/curl compositions for free.
 DERIVATIVE: dict[tuple[Stage, str], FusedRule] = {
-    (Stage.P, "lorenzo"): _rule(_deriv_lorenzo),
-    (Stage.Q, "lorenzo"): _rule(_deriv_lorenzo),
-    (Stage.F, "lorenzo"): _rule(_deriv_lorenzo),
-    (Stage.P, "blockmean"): _rule(_deriv_blockmean),
-    (Stage.Q, "blockmean"): _rule(_deriv_blockmean),
-    (Stage.F, "blockmean"): _rule(_deriv_blockmean),
+    (Stage.P, "lorenzo"): _rule(_deriv_lorenzo, Stage.P),
+    (Stage.Q, "lorenzo"): _rule(_deriv_lorenzo, Stage.Q),
+    (Stage.F, "lorenzo"): _rule(_deriv_lorenzo, Stage.F),
+    (Stage.P, "blockmean"): _rule(_deriv_blockmean, Stage.P),
+    (Stage.Q, "blockmean"): _rule(_deriv_blockmean, Stage.Q),
+    (Stage.F, "blockmean"): _rule(_deriv_blockmean, Stage.F),
 }
 
 #: gradient gets its own cells: one dual-output kernel pass instead of two.
 GRADIENT: dict[tuple[Stage, str], FusedRule] = {
-    (Stage.P, "lorenzo"): _rule(_grad_lorenzo),
-    (Stage.Q, "lorenzo"): _rule(_grad_lorenzo),
-    (Stage.F, "lorenzo"): _rule(_grad_lorenzo),
-    (Stage.P, "blockmean"): _rule(_grad_blockmean),
-    (Stage.Q, "blockmean"): _rule(_grad_blockmean),
-    (Stage.F, "blockmean"): _rule(_grad_blockmean),
+    (Stage.P, "lorenzo"): _rule(_grad_lorenzo, Stage.P),
+    (Stage.Q, "lorenzo"): _rule(_grad_lorenzo, Stage.Q),
+    (Stage.F, "lorenzo"): _rule(_grad_lorenzo, Stage.F),
+    (Stage.P, "blockmean"): _rule(_grad_blockmean, Stage.P),
+    (Stage.Q, "blockmean"): _rule(_grad_blockmean, Stage.Q),
+    (Stage.F, "blockmean"): _rule(_grad_blockmean, Stage.F),
 }
 
 #: laplacian: lorenzo ③④ deliberately absent (see module docstring).
 LAPLACIAN: dict[tuple[Stage, str], FusedRule] = {
-    (Stage.P, "lorenzo"): _rule(_lap_lorenzo),
-    (Stage.P, "blockmean"): _rule(_lap_blockmean_p),
-    (Stage.Q, "blockmean"): _rule(_lap_blockmean_q),
-    (Stage.F, "blockmean"): _rule(_lap_blockmean_q),
+    (Stage.P, "lorenzo"): _rule(_lap_lorenzo, Stage.P),
+    (Stage.P, "blockmean"): _rule(_lap_blockmean_p, Stage.P),
+    (Stage.Q, "blockmean"): _rule(_lap_blockmean_q, Stage.Q),
+    (Stage.F, "blockmean"): _rule(_lap_blockmean_q, Stage.F),
 }

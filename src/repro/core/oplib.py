@@ -309,11 +309,16 @@ class StageContext:
         stage-③ seed holds it resident)."""
         if self._seed is not None and self._seed.q_spatial is not None:
             return self._seed.q_spatial
-        q = self.compressor.decompress(self.sub, Stage.Q,
-                                       crop=self.plan is None)
+        return self.extent(self.compressor.decompress(self.sub, Stage.Q,
+                                                      crop=False))
+
+    def extent(self, arr: jax.Array) -> jax.Array:
+        """A padded-layout plane cut to the queried extent exactly as
+        :attr:`q_spatial` is: the region window, or the field's own shape
+        (padding dropped, 1-D layouts unflattened)."""
         if self.plan is not None:
-            return self.plan.window_of(q)
-        return q
+            return self.plan.window_of(arr)
+        return self.compressor._restore(arr, self.sub)
 
     @cached_property
     def f_spatial(self) -> jax.Array:
@@ -471,20 +476,23 @@ def _std_p_blockmean(ctx: StageContext, axis: int) -> jax.Array:
     return jnp.sqrt(jnp.maximum(ss, 0.0) / (n - 1)) * ctx.eps * 2.0
 
 
-def _std_p_lorenzo(ctx: StageContext, axis: int) -> jax.Array:
-    qf = ctx.stat_values(ctx.lorenzo_q)
+def _std_moments(ctx: StageContext, q: jax.Array) -> jax.Array:
+    """Single-pass moments of the integers ``q`` on the queried extent."""
+    qf = q.astype(jnp.float32).reshape(-1)
     n = ctx.n
     s1, s2 = jnp.sum(qf), jnp.sum(qf * qf)
     var = (s2 - s1 * s1 / n) / (n - 1)
     return jnp.sqrt(jnp.maximum(var, 0.0)) * ctx.eps * 2.0
+
+
+def _std_p_lorenzo(ctx: StageContext, axis: int) -> jax.Array:
+    # the stage-③ reduction over the same integers in the same layout, so
+    # stages ② and ③ agree bit for bit (the planner may route either way)
+    return _std_moments(ctx, ctx.extent(ctx.lorenzo_q))
 
 
 def _std_q(ctx: StageContext, axis: int) -> jax.Array:
-    qf = ctx.q_spatial.astype(jnp.float32).reshape(-1)
-    n = ctx.n
-    s1, s2 = jnp.sum(qf), jnp.sum(qf * qf)
-    var = (s2 - s1 * s1 / n) / (n - 1)
-    return jnp.sqrt(jnp.maximum(var, 0.0)) * ctx.eps * 2.0
+    return _std_moments(ctx, ctx.q_spatial)
 
 
 def _std_f(ctx: StageContext, axis: int) -> jax.Array:
@@ -568,6 +576,14 @@ class OpSpec:
     ``closure`` gives the region dependency closure of the op's prelude;
     vector ops instead declare ``component_axes`` (which derivative axes
     each component feeds) from which per-component closures derive.
+    ``recorrelates`` names the ``(stage, family)`` cells whose XLA rule
+    still recorrelates the stage's resident intermediate (Lorenzo prefix
+    sums over a stage-② seed): there a store-resident materialization
+    leaves the expensive part of the work in the postlude.  With the fused
+    cells' :attr:`~repro.core.fused.FusedRule.reads_seed` it gives
+    :func:`reads_seed`, which the store-backed planner ranks by
+    (``repro.analytics.query``).  ``tests/test_store.py`` pins every cell
+    against the rule's jaxpr.
     """
 
     name: str
@@ -582,6 +598,7 @@ class OpSpec:
         default_factory=dict)
     lower_vector: Callable | None = None
     lower_temporal: Callable | None = None  # (TemporalSummary, eps) -> result
+    recorrelates: frozenset[tuple[Stage, str]] = frozenset()
 
 
 def _mean_stages(scheme: Scheme) -> tuple[Stage, ...]:
@@ -694,6 +711,10 @@ def _curl_axes(n_components: int) -> tuple[tuple[int, ...], ...]:
     raise ValueError(f"curl needs 2 or 3 components, got {n_components}")
 
 
+#: the one recorrelating cell: stage-② Lorenzo postludes rebuild q (or an
+#: axis difference D_a) from the residuals by prefix sums
+_LORENZO_P = frozenset({(Stage.P, "lorenzo")})
+
 #: the registry: declaration order is the canonical op-set order (used for
 #: order-insensitive fused cache keys).
 OPS: dict[str, OpSpec] = {
@@ -710,27 +731,30 @@ OPS: dict[str, OpSpec] = {
                lower={(Stage.P, "blockmean"): _std_p_blockmean,
                       (Stage.P, "lorenzo"): _std_p_lorenzo,
                       (Stage.Q, "any"): _std_q,
-                      (Stage.F, "any"): _std_f}),
+                      (Stage.F, "any"): _std_f},
+               recorrelates=_LORENZO_P),
         OpSpec("derivative", "field", "differentiation", _stencil_stages,
                needs_axis=True, closure=_deriv_closure, lower=_DERIV_RULES,
-               fused=fused_mod.DERIVATIVE),
+               fused=fused_mod.DERIVATIVE, recorrelates=_LORENZO_P),
         OpSpec("gradient", "field", "differentiation", _stencil_stages,
                closure=_gradient_closure,
                lower={(Stage.P, "any"): _gradient_rule,
                       (Stage.Q, "any"): _gradient_rule,
                       (Stage.F, "any"): _gradient_rule},
-               fused=fused_mod.GRADIENT),
+               fused=fused_mod.GRADIENT, recorrelates=_LORENZO_P),
         OpSpec("laplacian", "field", "differentiation", _stencil_stages,
                closure=_stat_closure,  # hull / cover: all axes' diffs
                lower={(Stage.P, "lorenzo"): _lap_p_lorenzo,
                       (Stage.P, "blockmean"): _lap_p_blockmean,
                       (Stage.Q, "any"): _lap_q,
                       (Stage.F, "any"): _lap_f},
-               fused=fused_mod.LAPLACIAN),
+               fused=fused_mod.LAPLACIAN, recorrelates=_LORENZO_P),
         OpSpec("divergence", "vector", "multivariate", _stencil_stages,
-               component_axes=_div_axes, lower_vector=_divergence_vector),
+               component_axes=_div_axes, lower_vector=_divergence_vector,
+               recorrelates=_LORENZO_P),
         OpSpec("curl", "vector", "multivariate", _stencil_stages,
-               component_axes=_curl_axes, lower_vector=_curl_vector),
+               component_axes=_curl_axes, lower_vector=_curl_vector,
+               recorrelates=_LORENZO_P),
     )
 }
 
@@ -947,6 +971,24 @@ def family_of(scheme: Scheme) -> str:
     """The lowering-rule family key of a scheme (``compute`` dispatches on
     this): ``"lorenzo"`` for the HSZp pair, ``"blockmean"`` for HSZx."""
     return "lorenzo" if Scheme(scheme).is_lorenzo else "blockmean"
+
+
+def reads_seed(op: str, c: Field, stage: Stage, *, region=None,
+               closure: R.Closure = "cover") -> bool:
+    """Does the rule :func:`compute` selects for ``op`` at ``stage`` on
+    ``c`` run straight off a resident materialization of that stage?  Not
+    where the XLA rule still recorrelates it (:attr:`OpSpec.recorrelates`),
+    nor where the fused rule covering the context decodes the payload
+    instead (:attr:`~repro.core.fused.FusedRule.reads_seed`).  Vector ops
+    dispatch the derivative cells per component (:func:`_derivative_at`)."""
+    spec = _ALL_OPS[op]
+    stage, fam = Stage(stage), family_of(c.scheme)
+    cells = spec.fused if spec.arity == "field" else fused_mod.DERIVATIVE
+    fr = cells.get((stage, fam))
+    if (fr is not None and kernel_ops.kernels_enabled()
+            and fr.covers(StageContext(c, stage, region, closure))):
+        return fr.reads_seed
+    return (stage, fam) not in spec.recorrelates
 
 
 def resolve_rules(spec: OpSpec, scheme: Scheme, stage: Stage) -> tuple[Rule, ...]:

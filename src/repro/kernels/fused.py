@@ -79,6 +79,7 @@ BAND_BYTES = 256 << 10  # target int32 bytes of one band plane in VMEM
 SUBLANES = 8
 
 
+@functools.lru_cache(maxsize=256)
 def band_rows(n0: int, n1: int, mult: int = 1) -> int | None:
     """Rows per grid step: the largest multiple of ``lcm(8, mult)`` that
     divides ``n0`` and keeps an int32 band within ``BAND_BYTES`` (at least

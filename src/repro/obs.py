@@ -26,9 +26,11 @@ The span names are fixed; the spans of one serving step are siblings inside
   call, which traces and compiles (or loads) the program.
 
 A span opened inside a step records that step's serial.  :data:`counters`
-holds the process-wide jit-cache totals; each engine keeps its own in
-``BatchedAnalytics.stats``.  Spans sit on the host path only: none is opened
-inside traced or jitted code.
+holds the process-wide jit-cache totals (each engine keeps its own in
+``BatchedAnalytics.stats``) and ``plan_resident_promotions``: planned
+store-backed components that ``stage="auto"`` put above the stage
+storeless planning picks (``repro.analytics.planner``).  Spans sit on the
+host path only: none is opened inside traced or jitted code.
 """
 from __future__ import annotations
 
@@ -50,8 +52,10 @@ NAMES = (FRONTEND_STEP, QUERY_PLAN, STORE_SEED, ENGINE_DISPATCH,
 #: (about 19k steps of 4 spans) with room to spare
 RING_SIZE = 1 << 18
 
-#: process-wide jit-cache totals over every engine (monotone)
-counters = {"jit_hits": 0, "jit_misses": 0, "jit_evictions": 0}
+#: process-wide totals (monotone): jit-cache events over every engine, and
+#: store-backed stage promotions of the planner
+counters = {"jit_hits": 0, "jit_misses": 0, "jit_evictions": 0,
+            "plan_resident_promotions": 0}
 
 _ID = {name: i for i, name in enumerate(NAMES)}
 _NONE = -1                  # step / count not given

@@ -87,20 +87,15 @@ class StoreRouter:
         return self._of(field_id).lookup(field_id, stage, region=region,
                                          closure=closure)
 
+    def can_retain(self, field_id: str, stage, *, region=None,
+                   closure="cover") -> bool:
+        return self._of(field_id).can_retain(field_id, stage, region=region,
+                                             closure=closure)
+
     def is_resident(self, field_id: str, stage, *, region=None,
                     closure="cover") -> bool:
         return self._of(field_id).is_resident(field_id, stage, region=region,
                                               closure=closure)
-
-    def cached_stages(self, field_ids, ops, *, region=None, axis: int = 0):
-        fids = [field_ids] if isinstance(field_ids, str) else list(field_ids)
-        stores = {id(self._of(f)) for f in fids}
-        if len(stores) > 1:
-            raise ValueError(
-                "vector components must live in one store (sharded or "
-                f"local), got a mix for {fids}")
-        return self._of(fids[0]).cached_stages(field_ids, ops, region=region,
-                                               axis=axis)
 
     def placement_of(self, field_id: str):
         store = self._of(field_id)

@@ -33,7 +33,7 @@ back to the ordinary unseeded path.
 from __future__ import annotations
 
 import dataclasses
-from collections.abc import Iterable, Sequence
+from collections.abc import Sequence
 from functools import reduce
 
 import numpy as np
@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from repro.core import Encoded, Stage, oplib
 from repro.core import region as region_mod
 from repro.core.oplib import TemporalSummary
-from repro.store import FieldStore, MATERIALIZABLE, StoreStats
+from repro.store import FieldStore, StoreStats
 from repro.store.materialized import (MaterializedStage, materialized_nbytes,
                                       storage_stage)
 from repro.stream import StreamFieldStore, TemporalField
@@ -57,7 +57,7 @@ class ShardedFieldStore:
     """Block-sharded analytics store over a ``("shard",)`` mesh.
 
     Duck-types the query/serve store surface (``get`` / ``seed`` /
-    ``cached_stages`` / ``is_resident`` / ``stats`` / ``temporal_summary``
+    ``can_retain`` / ``is_resident`` / ``stats`` / ``temporal_summary``
     / ``append`` / ...), so ``repro.analytics.query`` and the serve
     frontend use it unchanged.  ``cache_bytes_per_shard`` budgets each
     shard's LRU independently; ``mesh`` comes from
@@ -310,8 +310,8 @@ class ShardedFieldStore:
         m = child._peek_hit(key)
         if m is not None:
             return m
-        if materialized_nbytes(field, stage, region=region,
-                               closure=cl) > child.cache_bytes:
+        if not self.can_retain(field_id, stage, region=region,
+                               closure=closure):
             child.stats.rejected += 1
             if self.retain_payload:
                 return None
@@ -322,40 +322,19 @@ class ShardedFieldStore:
         return m
 
     # -- planner input --------------------------------------------------------
+    def can_retain(self, field_id: str, stage: Stage, *, region=None,
+                   closure="cover") -> bool:
+        """:meth:`FieldStore.can_retain` against the home shard's budget."""
+        field, _, cl, _, child = self._cell(field_id, Stage(stage), region,
+                                            closure)
+        return materialized_nbytes(field, stage, region=region,
+                                   closure=cl) <= child.cache_bytes
+
     def is_resident(self, field_id: str, stage: Stage, *, region=None,
                     closure="cover") -> bool:
         field, norm, cl, key, child = self._cell(field_id, Stage(stage),
                                                  region, closure)
         return key in child._cache
-
-    def cached_stages(self, field_ids, ops, *, region=None,
-                      axis: int = 0) -> frozenset[Stage]:
-        """:meth:`FieldStore.cached_stages`, with each cell checked in its
-        home shard's cache (pure peek)."""
-        names = oplib.canonical_ops(ops)
-        vector = oplib.is_vector_ops(names)
-        fids = list(field_ids) if vector else [field_ids]
-        if isinstance(field_ids, str) and vector:
-            raise ValueError("vector op sets need one field id per component")
-        fields = [self.get(f) for f in fids]
-        out = set()
-        for stage in MATERIALIZABLE:
-            if vector:
-                closures = oplib.component_closures(
-                    names, [f.scheme for f in fields], stage)
-            else:
-                closures = (oplib.set_closure(names, fields[0].scheme, stage,
-                                              axis),)
-            resident = True
-            for fid, field, cl in zip(fids, fields, closures):
-                norm, cl = self._canonical(field, stage, region, cl)
-                key = FieldStore._key(fid, stage, norm, cl)
-                if key not in self._shards[self._home(field, norm, cl)]._cache:
-                    resident = False
-                    break
-            if resident:
-                out.add(stage)
-        return frozenset(out)
 
     # -- temporal serving ------------------------------------------------------
     def _temporal_home(self, field_id: str, tf: TemporalField, norm) -> int:

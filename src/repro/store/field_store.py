@@ -10,10 +10,11 @@ reconstruction per call" into "one reconstruction per field lifetime":
   intermediates keyed by ``(field id, stage, region, closure)``, bounded by
   a device-byte budget, with hit / miss / eviction accounting
   (:class:`StoreStats`);
-* **planner input** — :meth:`cached_stages` reports which stages of a
-  field are resident for a given op set, so the cache-aware cost model
+* **planner input** — :meth:`is_resident` reports whether a stage of a
+  field is resident, so the cache-aware cost model
   (``repro.analytics.planner``) can drop the reconstruction term and route
-  ``stage="auto"`` to an already-materialized stage.
+  ``stage="auto"`` to an already-materialized stage; :meth:`can_retain`
+  says which stages the budget could keep at all.
 
 Invalidation rules (DESIGN.md §7): re-registering or removing a field id
 drops every materialization derived from it; materializations are immutable
@@ -21,12 +22,11 @@ otherwise (fields are, too — compression is content-addressed by the
 caller's id discipline).
 """
 from __future__ import annotations
-from collections.abc import Iterable, Sequence
 
 import dataclasses
 from collections import OrderedDict
 
-from repro.core import Compressed, Encoded, Stage, oplib
+from repro.core import Compressed, Encoded, Stage
 from repro.core import region as region_mod
 from repro.core.region import Closure
 
@@ -189,8 +189,8 @@ class FieldStore:
         m = self._peek_hit(key)
         if m is not None:
             return m
-        if materialized_nbytes(field, stage, region=region,
-                               closure=closure) > self.cache_bytes:
+        if not self.can_retain(field_id, stage, region=region,
+                               closure=closure):
             self.stats.rejected += 1
             return None
         self.stats.misses += 1
@@ -242,49 +242,25 @@ class FieldStore:
         return len(victims)
 
     # -- planner input ------------------------------------------------------
+    def can_retain(self, field_id: str, stage: Stage, *, region=None,
+                   closure: Closure = "cover") -> bool:
+        """Could the budget keep this cell's materialization (its exact
+        predicted size, :func:`materialized_nbytes`, fits the whole
+        budget)?  Static geometry only: no device work, no counter moves.
+        :meth:`seed` declines the cells that fail it; the planner ranks
+        only stages that pass it as servable from the store."""
+        field = self.get(field_id)
+        _, closure = self._canonical(field, stage, region, closure)
+        return materialized_nbytes(field, stage, region=region,
+                                   closure=closure) <= self.cache_bytes
+
     def is_resident(self, field_id: str, stage: Stage, *, region=None,
                     closure: Closure = "cover") -> bool:
         """Pure residency peek for one exact ``(stage, region, closure)``
-        cell — the expression planner's cache-awareness probe (expression
-        closures join over a DAG's consumer set, so they don't reduce to an
-        op-set's :meth:`cached_stages` row).  Neither the LRU order nor the
-        hit/miss counters move."""
+        cell — the planner's cache-awareness probe
+        (``repro.analytics.query._slot_stages``).  Neither the LRU order nor
+        the hit/miss counters move (planning must not distort serving
+        statistics)."""
         field = self.get(field_id)
         norm, closure = self._canonical(field, stage, region, closure)
         return self._key(field_id, stage, norm, closure) in self._cache
-
-    def cached_stages(self, field_ids: str | Sequence[str],
-                      ops: str | Iterable[str], *, region=None,
-                      axis: int = 0) -> frozenset[Stage]:
-        """Stages at which ``ops`` over ``field_ids`` would be served from
-        resident materializations.
-
-        For a field-arity op set pass one id; for a vector-arity set
-        (``divergence``/``curl``) pass the component ids — a stage counts
-        only when *every* component's cell is resident.  Pure peek: neither
-        the LRU order nor the hit/miss counters move (planning must not
-        distort serving statistics).
-        """
-        names = oplib.canonical_ops(ops)
-        vector = oplib.is_vector_ops(names)
-        fids = list(field_ids) if vector else [field_ids]
-        if isinstance(field_ids, str) and vector:
-            raise ValueError("vector op sets need one field id per component")
-        fields = [self.get(f) for f in fids]
-        out = set()
-        for stage in MATERIALIZABLE:
-            if vector:
-                closures = oplib.component_closures(
-                    names, [f.scheme for f in fields], stage)
-            else:
-                closures = (oplib.set_closure(names, fields[0].scheme, stage,
-                                              axis),)
-            resident = True
-            for fid, field, cl in zip(fids, fields, closures):
-                norm, cl = self._canonical(field, stage, region, cl)
-                if self._key(fid, stage, norm, cl) not in self._cache:
-                    resident = False
-                    break
-            if resident:
-                out.add(stage)
-        return frozenset(out)

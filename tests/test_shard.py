@@ -308,6 +308,17 @@ for scheme in ("hszp", "hszx", "hszp_nd", "hszx_nd"):
                   and eq_tree(r2.values[0], r3.values[0]))
     st = sh_store.stats
     check(f"store/{scheme}/hits", st.hits > 0)
+    # store-backed auto planning: the sharded store plans what the
+    # single-device store plans (Lorenzo std at stage ② would run prefix
+    # sums over the resident residuals, so both go to ③)
+    want = Stage.Q if comp.scheme.is_lorenzo else Stage.P
+    cold = (StreamFieldStore(), ShardedFieldStore(mesh))
+    for s in cold:
+        s.put("f", e)
+    plans = [query(["f"], ["mean", "std"], "auto", store=s).stages[0]
+             for s in cold + cold]
+    check(f"plan/{scheme}", all(p == {"mean": want, "std": want}
+                                for p in plans))
 
 # --- per-shard byte budgets: eviction on one shard leaves siblings ----------
 comp = by_name("hszx_nd")
@@ -444,6 +455,11 @@ def test_materialize_bit_identity(shard_results):
 
 def test_store_query_bit_identity(shard_results):
     assert not _failing(shard_results, "store/"), shard_results["failures"]
+
+
+def test_sharded_auto_plan_matches_single_device(shard_results):
+    assert _failing(shard_results, "plan/") == []
+    assert sum(k.startswith("plan/") for k in shard_results) == 4
 
 
 def test_eviction_is_per_shard(shard_results):

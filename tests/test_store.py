@@ -7,7 +7,10 @@ The contract under test:
   sets, Compressed and Encoded containers;
 * ``stage="auto"`` provably flips to a cached stage when the cache-aware
   cost model says so — both uncalibrated (residency beats reconstruction)
-  and calibrated (measured cost minus fig34 reconstruction term);
+  and calibrated (measured cost minus fig34 reconstruction term) — and,
+  store-backed and uncalibrated, plans the retainable stage from whose
+  materialization the selected rules run straight (``oplib.reads_seed``,
+  pinned per XLA and fused lowering rule against its jaxpr);
 * the ``FieldStore`` is a byte-budgeted LRU with exact hit / miss /
   eviction accounting and id-invalidation rules;
 * serve resolves string field ids end to end with one dispatch per group;
@@ -15,14 +18,20 @@ The contract under test:
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
+from jax.extend.core import Var
 
-from repro import analytics
+from repro import analytics, obs
 from repro.analytics import BatchedAnalytics, CostModel, query
+from repro.analytics.query import _slot_stages
 from repro.core import (Scheme, Stage, homomorphic as H, hszp, hszp_nd, hszx,
                         hszx_nd, oplib)
 from repro.serve import AnalyticsFrontend, AnalyticsRequest
-from repro.store import FieldStore, MaterializedStage, materialize
+from repro.core import expr
+from repro.kernels import ops as kops
+from repro.store import (FieldStore, MaterializedStage, materialize,
+                         materialized_nbytes)
 
 ALL = [hszp, hszx, hszp_nd, hszx_nd]
 REGION = ((30, 75), (10, 52))  # unaligned window of the 181x97 field_2d
@@ -265,28 +274,40 @@ def test_plan_stage_cached_preference_keeps_metadata_fast_path():
                                 cached=frozenset({Stage.Q})) == Stage.Q
 
 
+def _cached_stages(store, fid, ops, *, region=None):
+    """The planner's residency probe (``query._slot_stages``) for one
+    store-backed field under a flat op set."""
+    names = oplib.canonical_ops(ops)
+    c = store.get(fid)
+    resident, _ = _slot_stages(
+        store, (fid,), (c,), names,
+        lambda s: (oplib.set_closure(names, c.scheme, s),), region)
+    return resident
+
+
 def test_cached_stages_requires_matching_region_and_closure(field_2d):
     c = _c(hszp_nd, field_2d)
     store = FieldStore()
     store.put("f", c)
     store.ensure("f", Stage.Q)
     # the stage-③ integers serve stage ④ too (dequantize is postlude)
-    assert store.cached_stages("f", ["mean", "std"]) == {Stage.Q, Stage.F}
+    assert _cached_stages(store, "f", ["mean", "std"]) == {Stage.Q, Stage.F}
     # the full-field entry does not serve a region query (different key) ...
-    assert store.cached_stages("f", ["mean", "std"], region=REGION) == frozenset()
+    assert _cached_stages(store, "f", ["mean", "std"],
+                          region=REGION) == frozenset()
     cl = oplib.set_closure(["mean", "std"], c.scheme, Stage.Q)
     store.ensure("f", Stage.Q, region=REGION, closure=cl)
-    assert store.cached_stages("f", ["mean", "std"],
-                               region=REGION) == {Stage.Q, Stage.F}
+    assert _cached_stages(store, "f", ["mean", "std"],
+                          region=REGION) == {Stage.Q, Stage.F}
     # ... and closures are part of the key: a stage-② derivative band entry
     # is not the hull the {mean, std} set needs
     band = oplib.set_closure("derivative", c.scheme, Stage.P, axis=0)
     hull = oplib.set_closure(["mean", "std"], c.scheme, Stage.P)
     assert band != hull
     store.ensure("f", Stage.P, region=REGION, closure=band)
-    assert Stage.P not in store.cached_stages("f", ["mean", "std"],
-                                              region=REGION)
-    assert Stage.P in store.cached_stages("f", "derivative", region=REGION)
+    assert Stage.P not in _cached_stages(store, "f", ["mean", "std"],
+                                         region=REGION)
+    assert Stage.P in _cached_stages(store, "f", "derivative", region=REGION)
 
 
 # -- FieldStore semantics -----------------------------------------------------
@@ -666,3 +687,223 @@ def test_query_rejects_duplicate_vector_ids_but_allows_raw_duplicates(field_2d):
     c = _c(hszp_nd, field_2d)
     res = query([(c, c)], "curl", stage=Stage.Q)
     assert np.isfinite(np.asarray(res.values[0])).all()
+
+
+# -- store-backed auto planning: no recorrelation over a resident stage -------
+
+STATS_LAP = ("mean", "std", "laplacian")
+
+
+def _auto_stages(path, store, ids, ops):
+    """Planned stage of every (field, op) answer of one store-backed
+    ``stage="auto"`` request, through the expression query, the flat
+    (deprecated) query, or the serving frontend."""
+    roots = [expr.op(op, f) for f in ids for op in ops]
+    if path == "exprs":
+        res = query(exprs=roots, store=store)
+        return res.stages, res.values
+    if path == "flat":
+        with pytest.warns(DeprecationWarning):
+            res = query(list(ids), list(ops), store=store)
+        return ([st[op] for st in res.stages for op in ops],
+                [v[op] for v in res.values for op in ops])
+    fe = AnalyticsFrontend(store=store)
+    req = AnalyticsRequest(uid=0, exprs=roots, stage="auto")
+    fe.add_request(req)
+    fe.run_until_drained()
+    assert req.error is None, req.error
+    return list(req.result_stage), list(req.result)
+
+
+@pytest.mark.parametrize("path", ["exprs", "flat", "frontend"])
+def test_auto_plans_stage_without_recorrelation(path, field_3d):
+    """3-D Lorenzo {mean, std, laplacian}: cold, the store-backed plan is
+    stage ③ (stage-② std and laplacian would run prefix sums over the
+    resident residuals every query), and it stays ③ once ③ is resident.
+    std and laplacian are bit-identical to an explicit stage-② query; the
+    mean differs from ②'s weighted residual sum by f32 summation order."""
+    c = _c(hszp_nd, field_3d)
+    store = FieldStore()
+    store.put("f", c)
+    ref = query(exprs=[expr.op(op, c) for op in STATS_LAP], stage=Stage.P)
+    for _ in range(3):
+        stages, values = _auto_stages(path, store, ["f"], STATS_LAP)
+        assert stages == [Stage.Q] * 3
+        got = dict(zip(STATS_LAP, values))
+        _assert_same(got["std"], ref.values[1])
+        _assert_same(got["laplacian"], ref.values[2])
+        np.testing.assert_allclose(np.asarray(got["mean"]),
+                                   np.asarray(ref.values[0]), rtol=1e-6)
+    assert store.stats.misses == 1 and store.stats.hits == 2
+    assert store.is_resident("f", Stage.Q)
+    assert not store.is_resident("f", Stage.P)
+
+
+@pytest.mark.parametrize("comp,ops,want", [
+    (hszx_nd, ("mean", "std"), Stage.P),   # blockmean ② needs no prefix sum
+    (hszx_nd, ("mean",), Stage.M),         # metadata stays the fast path
+    (hszp_nd, ("mean",), Stage.P),         # ② and ③ tie; stage order keeps ②
+], ids=["hszx_nd-mean+std", "hszx_nd-mean", "hszp_nd-mean"])
+@pytest.mark.parametrize("path", ["exprs", "flat"])
+def test_auto_keeps_cold_stage_without_recorrelation(comp, ops, want, path,
+                                                     field_3d):
+    store = FieldStore()
+    store.put("f", _c(comp, field_3d))
+    p0 = obs.counters["plan_resident_promotions"]
+    for _ in range(2):
+        stages, _ = _auto_stages(path, store, ["f"], ops)
+        assert stages == [want] * len(ops)
+    assert obs.counters["plan_resident_promotions"] == p0
+
+
+@pytest.mark.parametrize("path", ["exprs", "flat"])
+def test_auto_keeps_cold_stage_when_budget_cannot_retain(path, field_3d):
+    """A budget that cannot hold the stage-③ plane (so not the larger
+    stage-② plane either) keeps today's plan: stage ②, run unseeded."""
+    c = _c(hszp_nd, field_3d)
+    assert materialized_nbytes(c, Stage.P) > materialized_nbytes(c, Stage.Q)
+    store = FieldStore(cache_bytes=materialized_nbytes(c, Stage.Q) - 1)
+    store.put("f", c)
+    assert not store.can_retain("f", Stage.Q)
+    p0 = obs.counters["plan_resident_promotions"]
+    for _ in range(2):
+        stages, _ = _auto_stages(path, store, ["f"], STATS_LAP)
+        assert stages == [Stage.P] * 3
+    assert obs.counters["plan_resident_promotions"] == p0
+    assert store.cache_entries == 0 and store.stats.rejected == 2
+
+
+@pytest.mark.parametrize("ops", [("derivative",), ("gradient",),
+                                 ("laplacian",), ("derivative", "laplacian")])
+@pytest.mark.parametrize("mode,want", [("interpret", Stage.P),
+                                       ("off", Stage.Q)])
+def test_auto_2d_stencil_follows_the_selected_rule(ops, mode, want,
+                                                   field_2d):
+    """2-D Lorenzo stencils: with the kernels on, the fused stage-② rules
+    recorrelate in VMEM over the resident residuals while the fused
+    stage-③ derivative and gradient rules would decode the payload past a
+    resident ③ plane, so the plan stays at ② (the storeless stage) and
+    nothing is promoted; with the kernels off the XLA ② rules run prefix
+    sums, and the plan goes to ③."""
+    store = FieldStore()
+    store.put("f", _c(hszp_nd, field_2d))
+    with kops.override_mode(mode):
+        p0 = obs.counters["plan_resident_promotions"]
+        for _ in range(2):
+            stages, _ = _auto_stages("exprs", store, ["f"], ops)
+            assert stages == [want] * len(ops)
+        assert (obs.counters["plan_resident_promotions"] - p0
+                == (2 if want == Stage.Q else 0))
+    assert store.is_resident(
+        "f", want, closure=oplib.set_closure(ops, hszp_nd.scheme, want))
+
+
+def test_plan_resident_promotions_counts_promoted_components(field_3d):
+    """One count per planned component above the storeless stage: two
+    store-backed Lorenzo stat+stencil components are promoted (cold and
+    warm); a blockmean component, a Lorenzo mean, a raw (storeless) leaf
+    and an explicit-stage query are not."""
+    rng = np.random.default_rng(5)
+    store = FieldStore()
+    for i in range(2):
+        store.put(f"p{i}", _c(hszp_nd, field_3d + rng.normal(
+            0, 0.1, field_3d.shape).astype(np.float32)))
+    store.put("x", _c(hszx_nd, field_3d))
+    store.put("m", _c(hszp_nd, field_3d))
+    raw = _c(hszp_nd, field_3d)
+    roots = ([expr.op(op, f"p{i}") for i in range(2) for op in STATS_LAP]
+             + [expr.mean("x"), expr.std("x"), expr.mean("m"),
+                expr.std(raw), expr.laplacian(raw)])
+    for _ in range(2):
+        p0 = obs.counters["plan_resident_promotions"]
+        res = query(exprs=roots, store=store)
+        assert obs.counters["plan_resident_promotions"] == p0 + 2
+        assert res.stages == [Stage.Q] * 6 + [Stage.P] * 5
+    p0 = obs.counters["plan_resident_promotions"]
+    query(exprs=roots, store=store, stage=Stage.Q)
+    assert obs.counters["plan_resident_promotions"] == p0
+
+
+def _plane_cumsums(jaxpr, n: int):
+    """Prefix sums over a whole plane (an operand of ``n`` elements or
+    more) anywhere in a jaxpr, sub-jaxprs included; the fused kernels'
+    band-boundary prefix sums are far smaller."""
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "cumsum"
+                and eqn.invars[0].aval.size >= n):
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _plane_cumsums(sub, n)
+
+
+def _check_reads_seed(name, fam, stage, x):
+    """Trace the rule ``compute`` selects for each ``fam`` scheme over the
+    stage's resident materialization and hold ``oplib.reads_seed`` to it:
+    the rule reads the seed (its arrays are live in the jaxpr) and runs no
+    prefix sum over a whole plane exactly where declared."""
+    spec = oplib.OPS[name]
+    for comp in _FAMILY_SCHEMES[fam]:
+        if stage not in spec.feasible(comp.scheme):
+            continue
+        scheme = comp.scheme
+        if spec.arity == "vector":
+            comps = [_c(comp, x * (k + 1)) for k in range(x.ndim)]
+            closures = oplib.component_closures(name, [scheme] * x.ndim,
+                                                stage)
+
+            def run(seeds, comps=comps, closures=closures):
+                return spec.lower_vector(
+                    [oplib.StageContext(c, stage, None, cl, seed=m)
+                     for c, cl, m in zip(comps, closures, seeds)], 0)
+            seeds = [materialize(c, stage) for c in comps]
+            declared = {oplib.reads_seed(name, c, stage, closure=cl)
+                        for c, cl in zip(comps, closures)}
+            (declared,) = declared
+        else:
+            c = _c(comp, x)
+            closure = oplib.set_closure(name, scheme, stage, axis=1)
+
+            def run(seed, c=c, closure=closure):
+                ctx = oplib.StageContext(c, stage, None, closure, seed=seed)
+                return oplib.select_rule(spec, stage, oplib.family_of(scheme),
+                                         ctx)(ctx, 1)
+            # stage ① has nothing to materialize: metadata is resident
+            seeds = None if stage == Stage.M else materialize(c, stage)
+            declared = oplib.reads_seed(name, c, stage, closure=closure)
+        jaxpr = jax.make_jaxpr(run)(seeds).jaxpr
+        used = {v for v in (*(v for e in jaxpr.eqns for v in e.invars),
+                            *jaxpr.outvars) if isinstance(v, Var)}
+        reads = not jaxpr.invars or any(v in used for v in jaxpr.invars)
+        plane = x.size
+        assert declared == (reads and not any(_plane_cumsums(jaxpr, plane))
+                            ), scheme
+
+
+_FAMILY_SCHEMES = {"lorenzo": (hszp, hszp_nd), "blockmean": (hszx, hszx_nd)}
+_RULE_CELLS = [(name, fam, stage) for name in oplib.OPS
+               for fam in _FAMILY_SCHEMES for stage in Stage
+               if any(stage in oplib.OPS[name].feasible(comp.scheme)
+                      for comp in _FAMILY_SCHEMES[fam])]
+_RULE_IDS = [f"{n}-{f}-{s.name}" for n, f, s in _RULE_CELLS]
+
+
+@pytest.mark.parametrize("name,fam,stage", _RULE_CELLS, ids=_RULE_IDS)
+def test_recorrelates_matches_rule_jaxpr(name, fam, stage, field_3d):
+    """The registry's seed fact, which the store-backed planner ranks by,
+    is what each XLA lowering rule does (a 3-D field: no fused kernel
+    covers it, and every axis difference needs prefix sums): a rule in
+    ``OpSpec.recorrelates`` runs prefix sums over the resident plane."""
+    _check_reads_seed(name, fam, stage, field_3d)
+
+
+@pytest.mark.parametrize("name,fam,stage", _RULE_CELLS, ids=_RULE_IDS)
+def test_reads_seed_matches_fused_rule_jaxpr(name, fam, stage, field_2d):
+    """The same fact on a 2-D field with the kernels on, where the fused
+    Pallas rules run: stage-② kernels recorrelate in VMEM over the
+    resident residuals (no plane prefix sum), and the stage-③④ derivative
+    and gradient kernels ignore a stage-③ seed (``FusedRule.reads_seed``)."""
+    with kops.override_mode("interpret"):
+        _check_reads_seed(name, fam, stage, field_2d)
