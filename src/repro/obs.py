@@ -25,12 +25,21 @@ The span names are fixed; the spans of one serving step are siblings inside
 * :data:`ENGINE_BUILD` -- a jit-cache miss: ``jax.jit`` through the first
   call, which traces and compiles (or loads) the program.
 
-A span opened inside a step records that step's serial.  :data:`counters`
-holds the process-wide jit-cache totals (each engine keeps its own in
-``BatchedAnalytics.stats``) and ``plan_resident_promotions``: planned
-store-backed components that ``stage="auto"`` put above the stage
-storeless planning picks (``repro.analytics.planner``).  Spans sit on the
-host path only: none is opened inside traced or jitted code.
+A span opened inside a step records that step's serial, except
+:data:`STORE_MATERIALIZE` -- a store miss that builds a materialization
+(``FieldStore.seed``/``ensure``), nested inside :data:`STORE_SEED` when a
+step seeds.  It records no step serial, so the spans that carry one still
+partition the step and the seed span keeps the materializations it
+contains; a reader finds the materializations of a step by time.
+
+:data:`counters` holds the process-wide jit-cache totals (each engine
+keeps its own in ``BatchedAnalytics.stats``); ``plan_resident_promotions``:
+planned store-backed components that ``stage="auto"`` put above the stage
+storeless planning picks (``repro.analytics.planner``); and the store's
+``store_materializations`` (materializations built) and
+``store_evictions`` (cache entries dropped, as ``StoreStats.evictions``
+counts them), over every store.  Spans sit on the host path only: none is
+opened inside traced or jitted code.
 """
 from __future__ import annotations
 
@@ -45,19 +54,22 @@ QUERY_PLAN = "repro.query.plan"
 STORE_SEED = "repro.store.seed"
 ENGINE_DISPATCH = "repro.engine.dispatch"
 ENGINE_BUILD = "repro.engine.build"
+STORE_MATERIALIZE = "repro.store.materialize"
 NAMES = (FRONTEND_STEP, QUERY_PLAN, STORE_SEED, ENGINE_DISPATCH,
-         ENGINE_BUILD)
+         ENGINE_BUILD, STORE_MATERIALIZE)
 
 #: spans the ring holds: one 40 s window of the fastest benchmark cell
 #: (about 19k steps of 4 spans) with room to spare
 RING_SIZE = 1 << 18
 
-#: process-wide totals (monotone): jit-cache events over every engine, and
-#: store-backed stage promotions of the planner
+#: process-wide totals (monotone): jit-cache events over every engine,
+#: store-backed stage promotions of the planner, and store cache churn
 counters = {"jit_hits": 0, "jit_misses": 0, "jit_evictions": 0,
-            "plan_resident_promotions": 0}
+            "plan_resident_promotions": 0, "store_materializations": 0,
+            "store_evictions": 0}
 
 _ID = {name: i for i, name in enumerate(NAMES)}
+_UNSTEPPED = frozenset({STORE_MATERIALIZE})   # nested: no step serial
 _NONE = -1                  # step / count not given
 _ROW = struct.Struct("qqqqq")   # name index, start_ns, end_ns, step, count
 _TraceMe = jax.profiler.TraceAnnotation
@@ -140,7 +152,7 @@ class span:
             if self.count is not None:
                 self._tm.set_metadata(count=self.count)
             self._tm.__exit__(*exc)
-        step = _current_step
+        step = _NONE if self.name in _UNSTEPPED else _current_step
         _current_step = self._outer
         _ring.append(self.name, self._t0, t1, step,
                      _NONE if self.count is None else self.count)

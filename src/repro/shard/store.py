@@ -40,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import Encoded, Stage, oplib
 from repro.core import region as region_mod
 from repro.core.oplib import TemporalSummary
@@ -265,10 +266,12 @@ class ShardedFieldStore:
     def _materialize(self, field_id: str, field: Encoded, stage: Stage,
                      norm, closure) -> MaterializedStage:
         st = storage_stage(stage)
-        inter = self.progs.materialize(
-            field, st, region=norm, closure=closure,
-            placement=self._placements[field_id],
-            stripes=self._stripes[field_id])
+        with obs.span(obs.STORE_MATERIALIZE):
+            obs.counters["store_materializations"] += 1
+            inter = self.progs.materialize(
+                field, st, region=norm, closure=closure,
+                placement=self._placements[field_id],
+                stripes=self._stripes[field_id])
         return MaterializedStage(
             sub=inter if st == Stage.P else None,
             q_spatial=None if st == Stage.P else inter,

@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 from collections import OrderedDict
 
+from repro import obs
 from repro.core import Compressed, Encoded, Stage
 from repro.core import region as region_mod
 from repro.core.region import Closure
@@ -169,7 +170,7 @@ class FieldStore:
             return m
         field = self.get(field_id)
         norm, closure = self._canonical(field, stage, region, closure)
-        m = materialize(field, stage, region=region, closure=closure)
+        m = self._build(field, stage, region, closure)
         self._insert(self._key(field_id, stage, norm, closure), m)
         return m
 
@@ -194,9 +195,21 @@ class FieldStore:
             self.stats.rejected += 1
             return None
         self.stats.misses += 1
-        m = materialize(field, stage, region=region, closure=closure)
+        m = self._build(field, stage, region, closure)
         self._insert(key, m)
         return m
+
+    @staticmethod
+    def _build(field: Field, stage: Stage, region,
+               closure: Closure) -> MaterializedStage:
+        """One materialization, inside span ``repro.store.materialize``."""
+        with obs.span(obs.STORE_MATERIALIZE):
+            obs.counters["store_materializations"] += 1
+            return materialize(field, stage, region=region, closure=closure)
+
+    def _evicted(self, n: int = 1) -> None:
+        self.stats.evictions += n
+        obs.counters["store_evictions"] += n
 
     def _insert(self, key: tuple, m) -> None:
         """Insert (or replace) one cache entry, keeping ``_bytes`` equal to
@@ -220,7 +233,7 @@ class FieldStore:
             # summaries, which are replaced on every append).
             self.stats.rejected += 1
             if old is not None:
-                self.stats.evictions += 1
+                self._evicted()
             return
         self._cache[key] = m
         self._bytes += nb
@@ -229,7 +242,7 @@ class FieldStore:
             if victim_key == key:  # never evict the entry just inserted
                 break
             self._bytes -= self._cache.pop(victim_key).nbytes
-            self.stats.evictions += 1
+            self._evicted()
 
     def invalidate(self, field_id: str) -> int:
         """Drop every materialization of ``field_id`` (counted as
@@ -238,7 +251,7 @@ class FieldStore:
         victims = [k for k in self._cache if k[0] == field_id]
         for k in victims:
             self._bytes -= self._cache.pop(k).nbytes
-        self.stats.evictions += len(victims)
+        self._evicted(len(victims))
         return len(victims)
 
     # -- planner input ------------------------------------------------------

@@ -2,8 +2,10 @@
 
 One frontend step opens ``repro.frontend.step`` and, inside it, sibling
 spans for planning, store seeding and the engine's dispatch (and build, on
-a jit-cache miss); the siblings partition the step.  The spans reach both
-sinks: the in-memory ring and, under ``jax.profiler``, the trace.
+a jit-cache miss); the siblings partition the step.  A store miss opens
+``repro.store.materialize`` inside the seed span, with no step serial, so
+the partition holds.  The spans reach both sinks: the in-memory ring and,
+under ``jax.profiler``, the trace.
 """
 import glob
 import time
@@ -59,14 +61,21 @@ def _step(fe, *reqs):
 
 def _partition(spans):
     """Check that the step's children nest inside it, carry its serial and
-    do not overlap; returns ``(step span, children)``."""
+    do not overlap, and that each materialization lies inside a seed span
+    with no serial; returns ``(step span, children)``."""
     (step,) = [s for s in spans if s[0] == obs.FRONTEND_STEP]
-    children = [s for s in spans if s[0] != obs.FRONTEND_STEP]
+    nested = [s for s in spans if s[0] == obs.STORE_MATERIALIZE]
+    children = [s for s in spans
+                if s[0] not in (obs.FRONTEND_STEP, obs.STORE_MATERIALIZE)]
     _, t0, t1, serial, _ = step
     assert serial is not None
     for name, c0, c1, c_step, count in children:
         assert t0 <= c0 <= c1 <= t1, name
         assert c_step == serial and count is None
+    seeds = [s for s in children if s[0] == obs.STORE_SEED]
+    for _, m0, m1, m_step, count in nested:
+        assert m_step is None and count is None
+        assert any(s0 <= m0 <= m1 <= s1 for _, s0, s1, _, _ in seeds)
     for a, b in zip(children, children[1:]):   # ordered by start
         assert a[2] <= b[1], (a[0], b[0])
     busy = sum(c1 - c0 for _, c0, c1, _, _ in children)
@@ -95,11 +104,46 @@ def test_second_step_hits_the_jit_cache(fe, make):
     misses0 = obs.counters["jit_misses"]
     done, spans = _step(fe, make(1))
     assert [r.error for r in done] == [None]
-    assert {s[0] for s in spans} == ALL_SPANS - {obs.ENGINE_BUILD}
+    assert {s[0] for s in spans} == ALL_SPANS - {obs.ENGINE_BUILD,
+                                                 obs.STORE_MATERIALIZE}
     _partition(spans)
     assert fe.engine.stats.hits == 1 and fe.engine.stats.misses == 1
     assert obs.counters["jit_hits"] == hits0 + 1
     assert obs.counters["jit_misses"] == misses0
+
+
+def test_step_with_a_store_miss_still_partitions():
+    """A cache of one stage-③ plane and requests alternating between two
+    fields: from the third step on, each step misses the store and hits the
+    jit cache.  Its materialization nests in the seed span, and the step's
+    self time is the step less plan, seed and dispatch."""
+    plane = 4 * 32 * 48
+    store = FieldStore(cache_bytes=plane)
+    for i in range(2):
+        store.put(f"f/{i}", _field(i))
+    fe = AnalyticsFrontend(store=store)
+
+    def request(uid):
+        return AnalyticsRequest(uid=uid, exprs=[expr.laplacian(f"f/{uid % 2}")],
+                                stage=Stage.Q)
+
+    for uid in range(2):
+        _step(fe, request(uid))
+    made0 = obs.counters["store_materializations"]
+    evicted0 = obs.counters["store_evictions"]
+    done, spans = _step(fe, request(2))
+    assert [r.error for r in done] == [None]
+    assert {s[0] for s in spans} == ALL_SPANS - {obs.ENGINE_BUILD}
+    step, children = _partition(spans)
+    assert sorted(c[0] for c in children) == sorted(
+        [obs.QUERY_PLAN, obs.STORE_SEED, obs.ENGINE_DISPATCH])
+    (made,) = [s for s in spans if s[0] == obs.STORE_MATERIALIZE]
+    assert made[2] - made[1] > 0
+    self_ns = (step[2] - step[1]) - sum(c[2] - c[1] for c in children)
+    assert self_ns >= 0
+    assert obs.counters["store_materializations"] == made0 + 1
+    assert obs.counters["store_evictions"] == evicted0 + 1
+    assert store.stats.evictions == 2 and store.stats.misses == 3
 
 
 def test_steps_carry_their_serials(fe):
@@ -184,6 +228,7 @@ def test_spans_reach_the_profiler_trace(fe, tmp_path):
             for e in line.events:
                 if e.name in ALL_SPANS:
                     found[e.name] = dict(e.stats)
-    assert set(found) == ALL_SPANS - {obs.ENGINE_BUILD}
+    assert set(found) == ALL_SPANS - {obs.ENGINE_BUILD,
+                                      obs.STORE_MATERIALIZE}
     (step,) = [s for s in spans if s[0] == obs.FRONTEND_STEP]
     assert found[obs.FRONTEND_STEP] == {"step": step[3], "count": 1}
