@@ -61,8 +61,14 @@ def unpack_lanes(w: jax.Array, s, nv: int, bits: int) -> jax.Array:
     fetches its low and carry words with a gather inside that window.  The
     shifts and masks are ``encode.unpack_uniform``'s, so the integers are
     the same bits.  Returns the ``(rows, nv)`` zigzag values.
+
+    ``w`` may instead hold just the row's own words (at least ``LANES``
+    columns): a window that would run past them starts ``LANES`` words
+    before their end, and its gather indices, shifted by as much, are
+    clamped into the window (lanes past the row's last word read a word
+    that is then masked off or dropped).
     """
-    rows = w.shape[0]
+    rows, width = w.shape
     off = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1) * bits + s
     lo_idx = off >> 5
     hi_idx = lo_idx + 1
@@ -72,10 +78,15 @@ def unpack_lanes(w: jax.Array, s, nv: int, bits: int) -> jax.Array:
     mask = (1 << bits) - 1
     tiles = []
     for c in range(pl.cdiv(nv, LANES)):
-        win = w[:, 4 * bits * c:4 * bits * c + LANES]
-        lo = jnp.take_along_axis(win, lo_idx, axis=1,
+        start = min(4 * bits * c, width - LANES)
+        win = w[:, start:start + LANES]
+        lo_c, hi_c = lo_idx, hi_idx
+        if start < 4 * bits * c:
+            lo_c = jnp.minimum(lo_idx + (4 * bits * c - start), LANES - 1)
+            hi_c = jnp.minimum(hi_idx + (4 * bits * c - start), LANES - 1)
+        lo = jnp.take_along_axis(win, lo_c, axis=1,
                                  mode="promise_in_bounds")
-        hi = jnp.take_along_axis(win, hi_idx, axis=1,
+        hi = jnp.take_along_axis(win, hi_c, axis=1,
                                  mode="promise_in_bounds")
         v = (jax.lax.shift_right_logical(lo, shift)
              | jnp.where(carry, jax.lax.shift_left(hi, hi_shift), 0))

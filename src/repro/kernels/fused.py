@@ -73,7 +73,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .bitpack import unpack_lanes, window_words
+from .bitpack import LANES, unpack_lanes, window_words
 
 BAND_BYTES = 256 << 10  # target int32 bytes of one band plane in VMEM
 SUBLANES = 8
@@ -505,3 +505,177 @@ def blockmean_enc2d(payload: jax.Array, meta: jax.Array, shape: tuple,
         interpret=interpret,
         name=f"blockmean_enc2d_{what}",
     )(words, offs, mtiles, halo)
+
+
+# ---------------------------------------------------------------------------
+# 3-D Lorenzo: payload words -> stage-③ plane (the store's materialization)
+# ---------------------------------------------------------------------------
+
+PLANE_BYTES = 1 << 20  # largest int32 plane (or its word rows) one step holds
+SLAB_BYTES = 2 << 20   # target bytes of one grid step's output (or word) slab
+MXU_BITS = 17          # widest payload whose lane prefix runs on the MXU
+
+
+def _word_layout(n2: int, bits: int) -> tuple[int, int, int]:
+    """``(P, SW, Wn)`` of rows of ``n2`` values at ``bits``: rows repeat
+    their in-word bit offset every ``P`` rows, ``P`` rows fill ``SW``
+    whole words, and ``Wn`` words hold any one row."""
+    a = n2 * bits
+    period = 32 // math.gcd(a, 32)
+    offs = [(ph * a) & 31 for ph in range(period)]
+    return period, period * a // 32, max(((s + a - 1) >> 5) + 1 for s in offs)
+
+
+def _slab_planes(shape: tuple, bits: int) -> int | None:
+    """Planes per grid step: as many as keep the output slab and its word
+    slab within :data:`SLAB_BYTES`, or ``None`` when a plane
+    exceeds :data:`PLANE_BYTES` or its rows are not whole sublane tiles
+    — the kernel does not cover it."""
+    n0, n1, n2 = shape
+    width = n2 if bits == 32 else max(_word_layout(n2, bits)[2], LANES)
+    plane = 4 * n1 * max(n2, width)
+    if n1 % SUBLANES or plane > PLANE_BYTES:
+        return None
+    return min(n0, SLAB_BYTES // plane)
+
+
+def lorenzo3d_covers(shape: tuple, bits: int) -> bool:
+    """Does :func:`lorenzo3d_q` cover a padded ``shape`` at ``bits``?"""
+    return len(shape) == 3 and (bits == 0 or _slab_planes(
+        tuple(shape), bits) is not None)
+
+
+def plane_words(payload: jax.Array, rows: int, n2: int,
+                bits: int) -> jax.Array:
+    """``(rows, W)`` payload word rows, row ``g`` starting at the word that
+    holds its first value (in-word offset ``((g % P) * n2 * bits) & 31``,
+    :func:`_word_layout`).  Rows of whole words (``P == 1``) are a reshape
+    of the payload; otherwise each phase of the ``P``-row period is a
+    strided view of it.  Narrow rows are padded to one lane tile."""
+    period, sw, wn = _word_layout(n2, bits)
+    w = payload
+    m = -(-rows // period)
+    need = ((period - 1) * n2 * bits >> 5) + m * sw
+    if w.shape[0] < need:
+        w = jnp.pad(w, (0, need - w.shape[0]))
+    # a row's words never outrun its period's (wn <= sw), so each phase is
+    # a reshape of the payload from the phase's first word
+    phases = [w[base:base + m * sw].reshape(m, sw)[:, :wn]
+              for base in ((ph * n2 * bits) >> 5 for ph in range(period))]
+    words = (phases[0] if period == 1
+             else jnp.stack(phases, axis=1).reshape(m * period, wn))[:rows]
+    if bits < 32 and wn < LANES:
+        words = jnp.pad(words, ((0, 0), (0, LANES - wn)))
+    return words
+
+
+def _lane_prefix_mxu(p: jax.Array, uj: jax.Array) -> jax.Array:
+    """Inclusive prefix along lanes on the MXU.  Each 128-lane block of
+    ``p``, split into its low byte and the rest (both exact in bf16 while
+    ``|p| <= 2**16``), times ``[U | J]`` (the upper triangle of ones, all
+    ones) gives the block's prefix and, in every lane, its total: sums of
+    at most 128 such values, exact in f32.  The int32 recombination adds
+    the totals of the blocks before."""
+    r, n = p.shape
+    nb = n // LANES
+    lo = (p & 255).astype(jnp.float32).astype(jnp.bfloat16)
+    hi = (p >> 8).astype(jnp.float32).astype(jnp.bfloat16)
+    x = jnp.concatenate([v[:, LANES * c:LANES * (c + 1)]
+                         for v in (lo, hi) for c in range(nb)], axis=0)
+    y = jnp.dot(x, uj, preferred_element_type=jnp.float32).astype(jnp.int32)
+    y = y[:nb * r] + (y[nb * r:] << 8)
+    out, carry = [], None
+    for c in range(nb):
+        pre, tot = y[c * r:(c + 1) * r, :LANES], y[c * r:(c + 1) * r, LANES:]
+        out.append(pre if carry is None else pre + carry)
+        carry = tot if carry is None else carry + tot
+    return out[0] if nb == 1 else jnp.concatenate(out, axis=1)
+
+
+def _lorenzo3d_q_kernel(w_ref, *refs, n2: int, bits: int, period: int,
+                        mxu: bool):
+    """One slab of planes, plane by plane: unpack the plane's word rows,
+    unzigzag, take the prefix along lanes (on the MXU where ``mxu``) and
+    along rows, and add the carry plane ``acc_ref`` — the previous
+    plane's stage-③ integers, kept in VMEM from one grid step to the next
+    (so the grid runs in order, never under ``vmap``).  Whole planes keep
+    every vector operation long enough to hide its latency."""
+    uj_ref, o_ref, acc_ref = refs if mxu else (None,) + refs
+    slab, n1 = o_ref.shape[:2]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    first = pl.program_id(0) * slab * n1
+
+    def plane(t, _):
+        w = jax.lax.bitcast_convert_type(w_ref[t], jnp.int32)
+        if bits == 32:
+            u = w
+        else:
+            s = 0
+            if period > 1:
+                g = first + t * n1 + jax.lax.broadcasted_iota(
+                    jnp.int32, (n1, 1), 0)
+                s = ((g & (period - 1)) * ((n2 * bits) & 31)) & 31
+            u = unpack_lanes(w, s, n2, bits)
+        p = _unzigzag(u)
+        p = _lane_prefix_mxu(p, uj_ref[...]) if mxu else _cumsum(p, 1)
+        q = acc_ref[...] + _cumsum(p, 0)
+        acc_ref[...] = q
+        o_ref[t] = q
+        return 0
+
+    jax.lax.fori_loop(0, slab, plane, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "bits", "interpret"))
+def lorenzo3d_q(payload: jax.Array, shape: tuple, bits: int, *,
+                interpret: bool = False):
+    """Stage-③ integers of a 3-D Lorenzo field (padded ``shape``) straight
+    from its packed payload: ``unlorenzo(unzigzag(unpack(payload)))``, bit
+    for bit (int32 prefix sums are modular, so any order is exact).
+
+    One sequential pass over slabs of planes: each grid step reads its
+    planes' payload words and writes their int32 planes, and the unpack,
+    unzigzag and all three prefix sums run in VMEM.  The axis-0 prefix is
+    a carry plane held in VMEM across grid steps; the slab count need not
+    divide the plane count (the words gain zero planes, the output is cut
+    back).  Rows of whole 128-lane blocks at up to :data:`MXU_BITS` bits
+    take their lane prefix on the MXU, others by log-step shifted adds.
+    """
+    n0, n1, n2 = shape
+    if bits == 0:
+        return jnp.zeros(shape, jnp.int32)
+    slab = _slab_planes(shape, bits)
+    if slab is None:
+        raise ValueError(f"lorenzo3d_q does not cover {shape} at {bits} bits")
+    nb = -(-n0 // slab)
+    words = plane_words(payload, n0 * n1, n2, bits).reshape(n0, n1, -1)
+    if nb * slab > n0:
+        words = jnp.pad(words, ((0, nb * slab - n0), (0, 0), (0, 0)))
+    width = words.shape[2]
+    mxu = bits <= MXU_BITS and n2 % LANES == 0
+    args = [words]
+    in_specs = [pl.BlockSpec((slab, n1, width), lambda b: (b, 0, 0))]
+    if mxu:
+        i = jnp.arange(LANES)
+        tri = i[:, None] <= i[None, :]
+        args.append(jnp.concatenate([tri, jnp.ones_like(tri)],
+                                    axis=1).astype(jnp.bfloat16))
+        in_specs.append(pl.BlockSpec((LANES, 2 * LANES), lambda b: (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_lorenzo3d_q_kernel, n2=n2, bits=bits,
+                          period=_word_layout(n2, bits)[0], mxu=mxu),
+        grid=(nb,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((slab, n1, n2), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb * slab, n1, n2), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((n1, n2), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="lorenzo3d_q",
+    )(*args)
+    return out[:n0]

@@ -27,6 +27,7 @@ import jax
 
 from . import bitpack as _bitpack
 from . import block_stats as _block_stats
+from . import fused as _fused
 from . import prefix_stats as _prefix_stats
 from . import quant_lorenzo as _quant_lorenzo
 from . import stencil_dq as _stencil_dq
@@ -87,6 +88,12 @@ def pack(u: jax.Array, bits: int) -> jax.Array:
 
 def unpack(words: jax.Array, n: int, bits: int) -> jax.Array:
     return _bitpack.unpack(words, n, bits, interpret=_interpret())
+
+
+def lorenzo3d_q(payload: jax.Array, shape: tuple, bits: int) -> jax.Array:
+    """Stage-③ integers of a 3-D Lorenzo field (padded ``shape``) from its
+    packed payload in one pass (the store's materialization)."""
+    return _fused.lorenzo3d_q(payload, shape, bits, interpret=_interpret())
 
 
 def grad2d(q: jax.Array, eps):

@@ -205,6 +205,28 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
         vmem_elems="17*F",
         unpack_words=True,
     ),
+    # -- 3-D Lorenzo stage-③ plane ------------------------------------------
+    KernelSpec(
+        name="fused.lorenzo3d_q",
+        site=("fused", "lorenzo3d_q", 0),
+        grid=("b",),
+        bounds={"b": ("0", "nb - 1"), "nb": ("1", None), "S": ("1", None),
+                "rq": ("1", None), "n2": ("1", None), "W": ("1", None)},
+        facts=("n0 == nb*S", "n1 == 8*rq"),
+        inputs=(TileSpec("words", ("S", "n1", "W"), ("b", "0", "0"),
+                         ("n0", "n1", "W")),),
+        outputs=(TileSpec("plane", ("S", "n1", "n2"), ("b", "0", "0"),
+                          ("n0", "n1", "n2")),),
+        # word and output slabs (each <= SLAB_BYTES = 2 MiB) double-
+        # buffered, the carry plane and two planes of spilled temporaries
+        # (each <= PLANE_BYTES = 1 MiB); the [U | J] block is 64 KiB
+        vmem_elems="4*524288 + 3*262144 + 16384",
+        unpack_words=True,
+        notes="the carry plane persists in VMEM scratch across the "
+              "sequential grid (axis-0 prefix), so the kernel must never "
+              "run under vmap: only the store's materialization calls it; "
+              "the MXU variant adds the constant (128, 256) [U | J] block",
+    ),
     # -- bitplane pack / unpack ----------------------------------------------
     KernelSpec(
         name="bitpack.pack",

@@ -38,10 +38,13 @@ from functools import partial
 
 import jax
 
-from repro.core import Compressed, Encoded, Stage, layout_key, oplib
+from repro import obs
+from repro.core import Compressed, Encoded, Stage, blocking, layout_key, oplib
 from repro.core import region as region_mod
 from repro.core.region import Closure
 from repro.core.stages import _dataclass_pytree
+from repro.kernels import ops as kernel_ops
+from repro.kernels.fused import lorenzo3d_covers
 
 Field = Compressed | Encoded
 
@@ -137,6 +140,17 @@ def materialized_nbytes(field: Field, stage: Stage, *, region=None,
     return int32 * field.n
 
 
+@partial(jax.jit, static_argnames=("padded", "shape", "bits", "mode"))
+def _lorenzo3d_q_program(payload: jax.Array, *, padded: tuple, shape: tuple,
+                         bits: int, mode: str) -> jax.Array:
+    """The stage-③ plane of a full 3-D Lorenzo field as one program: the
+    payload-to-``q`` kernel, then the crop of the padding (what
+    ``StageContext.q_spatial`` computes op by op).  ``mode`` keys the
+    kernel backend."""
+    del mode
+    return blocking.crop(kernel_ops.lorenzo3d_q(payload, padded, bits), shape)
+
+
 def materialize(field: Field, stage: Stage, *,
                 region=None, closure: Closure = "cover") -> MaterializedStage:
     """Build the intermediate representation of one cache cell.
@@ -148,6 +162,13 @@ def materialize(field: Field, stage: Stage, *,
     ``region`` (it decides the gathered block set); full-field
     materializations share the canonical ``"cover"`` key regardless of the
     op set that asked.
+
+    The full-field stage-③ plane of an encoded 3-D Lorenzo field is one
+    dispatch instead, when the kernels are on and cover its shape
+    (:func:`repro.kernels.fused.lorenzo3d_covers`): the Pallas kernel that
+    takes the payload words to ``q`` and the crop, as one jitted program
+    (counted in ``store_materializations_fused``).  Its integers are the
+    prelude's, bit for bit.
     """
     stage = storage_stage(stage)
     if stage == Stage.M:
@@ -157,6 +178,15 @@ def materialize(field: Field, stage: Stage, *,
     norm = (region_mod.normalize_region(region, field.shape)
             if region is not None else None)
     closure = region_mod.canonical_closure(field.scheme, closure, norm)
+    if (stage == Stage.Q and norm is None and isinstance(field, Encoded)
+            and field.scheme.is_lorenzo and kernel_ops.kernels_enabled()
+            and lorenzo3d_covers(field.padded_shape, field.bits)):
+        obs.counters["store_materializations_fused"] += 1
+        q = _lorenzo3d_q_program(field.payload, padded=field.padded_shape,
+                                 shape=field.shape, bits=field.bits,
+                                 mode=kernel_ops.kernel_mode())
+        return MaterializedStage(sub=None, q_spatial=q, stage=stage,
+                                 closure=closure, region=None)
     ctx = oplib.StageContext(field, stage, region, closure)
     sub = q = None
     if stage == Stage.P:

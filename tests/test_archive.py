@@ -20,6 +20,7 @@ import pytest
 from repro import obs
 from repro.analytics import query
 from repro.core import Stage, expr, hszp_nd
+from repro.kernels import ops as kernel_ops
 from repro.serve import AnalyticsFrontend, AnalyticsRequest
 from repro.store import FieldStore
 
@@ -69,8 +70,8 @@ def _flat(values) -> list[np.ndarray]:
 def _serve(encs, cache_planes: int):
     """Templates A and B in turn through a frontend over a store of
     ``cache_planes`` stage-③ planes: per request, its flat answers and
-    what it added to the materialization and eviction counters and to the
-    materialization spans."""
+    what it added to the materialization and eviction counters, to the
+    materialization spans and to the fused materializations."""
     store = FieldStore(cache_bytes=cache_planes * PLANE)
     ids = [store.put(f"nyx/{i}", e) for i, e in enumerate(encs)]
     fe = AnalyticsFrontend(store=store)
@@ -78,6 +79,7 @@ def _serve(encs, cache_planes: int):
     out = []
     for uid in range(N_REQUESTS):
         made0 = obs.counters["store_materializations"]
+        fused0 = obs.counters["store_materializations_fused"]
         evicted0 = obs.counters["store_evictions"]
         t0 = time.perf_counter_ns()
         fe.add_request(AnalyticsRequest(uid=uid, exprs=tpls[uid % 2],
@@ -87,7 +89,8 @@ def _serve(encs, cache_planes: int):
         spans = sum(s[0] == obs.STORE_MATERIALIZE for s in obs.spans(t0))
         out.append((_flat(r.result),
                     obs.counters["store_materializations"] - made0,
-                    obs.counters["store_evictions"] - evicted0, spans))
+                    obs.counters["store_evictions"] - evicted0, spans,
+                    obs.counters["store_materializations_fused"] - fused0))
     return out, store
 
 
@@ -120,12 +123,45 @@ def test_thrashing_answers_match_storeless_query(archive, thrashing, stage):
 
 def test_each_request_materializes_and_evicts_three(thrashing):
     served, store = thrashing
-    for uid, (_, made, evicted, spans) in enumerate(served):
+    for uid, (_, made, evicted, spans, _) in enumerate(served):
         if uid >= 2:   # after the first cycle
             assert (made, evicted, spans) == (3, 3, 3), uid
-    assert served[0][1:] == (3, 0, 3)
+    assert served[0][1:4] == (3, 0, 3)
     assert store.stats.misses == 3 * N_REQUESTS
     assert store.cache_entries == 4
+
+
+def test_every_miss_is_fused(thrashing):
+    """Each store miss of these encoded 3-D Lorenzo fields is built by the
+    one-dispatch kernel branch: ``store_materializations_fused`` moves with
+    ``store_materializations``."""
+    served, _ = thrashing
+    assert [r[4] for r in served] == [r[1] for r in served]
+
+
+@pytest.mark.parametrize("op", ["divergence", "curl", "mean", "std"])
+def test_kernel_seeded_answers_match_storeless_query(archive, op):
+    """A stage-③ answer seeded from planes the kernel branch built equals
+    the storeless query, bit for bit, with the kernels on and off."""
+    encs = archive[1]
+    store = FieldStore(cache_bytes=6 * PLANE)
+    ids = [store.put(f"nyx/{i}", e) for i, e in enumerate(encs)]
+    vec = op in ("divergence", "curl")
+
+    def roots(fields):
+        if vec:
+            return [getattr(expr, op)(tuple(fields[3:]))]
+        return [getattr(expr, op)(f) for f in fields[:3]]
+
+    fused0 = obs.counters["store_materializations_fused"]
+    got = _flat(query(exprs=roots(ids), stage=Stage.Q, store=store).values)
+    assert obs.counters["store_materializations_fused"] - fused0 == 3
+    for mode in ("interpret", "off"):
+        with kernel_ops.override_mode(mode):
+            want = _flat(query(exprs=roots(encs), stage=Stage.Q).values)
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
 
 
 # -- the plain reference ------------------------------------------------------

@@ -133,3 +133,45 @@ def test_kernel_pipeline_consistency(field_2d):
     c = hszp_nd.compress(x, eps=eps)
     p_pipeline = blocking.crop(c.residuals, x.shape)
     np.testing.assert_array_equal(np.asarray(p_kernel), np.asarray(p_pipeline))
+
+
+# shape, width: rows whose bits fill whole words and rows that do not (a
+# 21x19x37 field pads to 24x24x40, so a 10-bit row is 12.5 words), rows of
+# several lane tiles, rows of whole 128-lane blocks at widths the MXU lane
+# prefix takes (13x7x250 pads to 16x8x256) and one it does not, and planes
+# of 640 KiB, three to a slab, so the slab count does not divide the 8
+# planes (7x250x637 pads to 8x256x640 on the MXU path, 8x509x317 to
+# 8x512x320 on the shifted-add one)
+LORENZO3D_CASES = [
+    ((24, 20, 28), 10), ((21, 19, 37), 10), ((13, 7, 250), 1),
+    ((13, 7, 250), 10), ((13, 7, 250), 17), ((13, 7, 250), 32),
+    ((24, 20, 28), 0), ((24, 20, 28), 1), ((21, 19, 37), 1),
+    ((24, 20, 28), 17), ((21, 19, 37), 17), ((24, 20, 28), 32),
+    ((21, 19, 37), 32), ((16, 8, 300), 10), ((16, 8, 300), 17),
+    ((8, 8, 500), 10), ((7, 250, 637), 17), ((8, 509, 317), 10),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,bits", LORENZO3D_CASES,
+    ids=[f"{'x'.join(map(str, s))}-b{b}" for s, b in LORENZO3D_CASES])
+def test_lorenzo3d_q_matches_xla_chain(shape, bits):
+    """The payload-to-q kernel equals the XLA chain (unpack, unzigzag,
+    three prefix sums, crop) bit for bit, on payloads of random words (so
+    every bit of every value is exercised)."""
+    import dataclasses
+    from repro.core import Stage, blocking, hszp_nd, oplib
+    from repro.kernels import fused
+    rng = np.random.default_rng(sum(shape) * 40 + bits)
+    c = hszp_nd.compress(jnp.asarray(rng.normal(0, 1, shape), jnp.float32),
+                         rel_eb=1e-3)
+    e = hszp_nd.encode(c, bits=bits)
+    e = dataclasses.replace(e, payload=jnp.asarray(rng.integers(
+        0, 2**32, e.payload.shape, dtype=np.uint64).astype(np.uint32)))
+    assert fused.lorenzo3d_covers(e.padded_shape, bits)
+    got = blocking.crop(fused.lorenzo3d_q(e.payload, e.padded_shape, bits,
+                                          interpret=True), shape)
+    with ops.override_mode("off"):
+        want = oplib.StageContext(e, Stage.Q, None, "cover").q_spatial
+    assert got.dtype == want.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -79,6 +79,44 @@ def test_materialized_stage_is_pytree(comp, field_2d):
     assert stacked.q_spatial.shape == (2,) + m.q_spatial.shape
 
 
+# the one-dispatch 3-D Lorenzo branch: (compressor, field, stage, region,
+# kernel mode, built by the kernel?)
+_FUSED_CASES = [
+    ("hszp_nd", "3d", Stage.Q, None, "interpret", True),
+    ("hszp_nd", "3d", Stage.F, None, "interpret", True),
+    ("hszp_nd", "3d", Stage.Q, ((2, 20), (5, 31), (0, 33)), "interpret",
+     False),
+    ("hszp_nd", "3d", Stage.P, None, "interpret", False),
+    ("hszp_nd", "3d", Stage.Q, None, "off", False),
+    ("hszx_nd", "3d", Stage.Q, None, "interpret", False),
+    ("hszp", "3d", Stage.Q, None, "interpret", False),
+    ("hszp_nd", "2d", Stage.Q, None, "interpret", False),
+]
+
+
+@pytest.mark.parametrize(
+    "comp,dim,stage,region,mode,fused", _FUSED_CASES,
+    ids=["q", "f", "region", "p", "off", "blockmean", "1d", "2d"])
+def test_materialize_fused_branch_engages_by_shape(comp, dim, stage, region,
+                                                   mode, fused, field_2d,
+                                                   field_3d):
+    """Only a full-field stage-③ (or ④) materialization of an encoded 3-D
+    Lorenzo field takes the kernel branch, counted in
+    ``store_materializations_fused``; its plane is the XLA prelude's."""
+    comp = {"hszp_nd": hszp_nd, "hszx_nd": hszx_nd, "hszp": hszp}[comp]
+    e = comp.encode(_c(comp, field_3d if dim == "3d" else field_2d))
+    fused0 = obs.counters["store_materializations_fused"]
+    with kops.override_mode(mode):
+        m = materialize(e, stage, region=region)
+    assert obs.counters["store_materializations_fused"] - fused0 == fused
+    if m.q_spatial is not None:
+        with kops.override_mode("off"):
+            want = oplib.StageContext(e, Stage.Q, region,
+                                      m.closure).q_spatial
+        np.testing.assert_array_equal(np.asarray(m.q_spatial),
+                                      np.asarray(want))
+
+
 def test_materialize_rejects_stage_m(field_2d):
     e = hszx_nd.encode(_c(hszx_nd, field_2d))
     with pytest.raises(ValueError, match="already resident"):

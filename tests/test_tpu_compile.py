@@ -144,3 +144,20 @@ def test_sharded_word_merge_compiles_for_v5e_2x2(topo, program):
         lowered = fn.lower(stripped, stripes, srcs, dsts)
     text = lowered.compile().as_text()
     assert "all-reduce" in text
+
+
+@pytest.mark.parametrize("padded,shape", [
+    ((512, 512, 512), (512, 512, 512)), ((104, 504, 504), (100, 500, 500))],
+    ids=["nyx", "hurricane"])
+def test_lorenzo3d_q_program_compiles_for_v5e(one_chip, padded, shape):
+    """The store's one-dispatch stage-③ program (payload-to-q kernel and
+    crop) at NYX and Hurricane widths: rows of whole words with the MXU
+    lane prefix, and 12.5-word rows with the shifted-add prefix.  Never
+    under ``vmap``: its grid carries a plane."""
+    from repro.core import blocking
+    n_words = -(-padded[0] * padded[1] * padded[2] * 10 // 32)
+    words = jax.ShapeDtypeStruct((n_words,), jnp.uint32, sharding=one_chip)
+    fn = jax.jit(lambda w: blocking.crop(fk.lorenzo3d_q(w, padded, 10),
+                                         shape))
+    compiled = fn.lower(words).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
