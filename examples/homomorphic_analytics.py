@@ -134,8 +134,7 @@ def main():
           f"{t_full*1e3:.2f} ms full-field ({words}/{e.payload.size} payload "
           f"words gathered)")
 
-    print("\nServing front-end (second request type next to token "
-          "generation):")
+    print("\nServing front-end:")
     fe = AnalyticsFrontend()
     for i, c in enumerate(fields):
         fe.add_request(AnalyticsRequest(uid=i, fields=c, op="std"))

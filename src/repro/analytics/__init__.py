@@ -6,7 +6,7 @@ live.  This package turns that into an engine:
 
 * :mod:`repro.analytics.planner` — the feasibility matrix as data (derived
   from the declarative op registry in :mod:`repro.core.oplib`), plus a cost
-  model (optionally calibrated from ``benchmarks/run.py`` CSV) that picks
+  model (optionally calibrated from measured CSV rows) that picks
   the cheapest feasible stage automatically — jointly over an op *set* via
   ``plan_stages`` (one shared stage minimizing total cost);
 * :mod:`repro.analytics.engine` — stacks same-layout compressed fields into

@@ -13,7 +13,7 @@ raise/no-raise behavior of :mod:`repro.core.homomorphic`:
 ``plan_stage`` resolves ``stage="auto"`` to the cheapest feasible stage.  By
 default "cheapest" is stage order (①<②<③<④ — monotone in decompression work,
 which matches the paper's measurements); a :class:`CostModel` calibrated from
-``benchmarks/run.py`` CSV output refines the choice with measured
+``name,us_per_call,derived`` CSV rows refines the choice with measured
 microseconds per call.
 
 Store-backed planning (``servable`` given: the stages whose
@@ -201,7 +201,7 @@ class CostModel:
 
     @classmethod
     def from_benchmark_csv(cls, rows: str | Iterable[str]) -> "CostModel":
-        """Calibrate from ``benchmarks/run.py`` output.
+        """Calibrate from ``name,us_per_call,derived`` CSV rows.
 
         Parses the op-throughput rows (``fig58/…``, ``fig910/…``,
         ``fig1112/…``), whose names encode ``…/<op>/<scheme>-<stage_tag>``,

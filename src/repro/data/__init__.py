@@ -1,2 +1,2 @@
-"""Data pipelines: synthetic LM tokens + compressed scientific fields."""
-from . import scientific, tokens
+"""Data: synthetic scientific fields + a compressed field store."""
+from . import scientific

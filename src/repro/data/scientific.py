@@ -85,12 +85,6 @@ class ScientificStore:
         self.root = root
         self._cache: dict[tuple[str, int], CompressedShard] = {}
 
-    def _compressor(self, ndim: int):
-        name = self.comp_name
-        if name.endswith("_nd"):
-            return by_name(name)
-        return by_name(name)
-
     def put_all(self, datasets: list[str] | None = None):
         for name in datasets or DATASETS:
             fields, _ = DATASETS[name]
@@ -110,7 +104,7 @@ class ScientificStore:
                 return shard
         dims = dataset_dims(dataset, self.scale)
         data = synth_field(dataset, field, dims, self.seed)
-        comp = self._compressor(len(dims))
+        comp = by_name(self.comp_name)
         c = comp.compress(jnp.asarray(data), rel_eb=self.rel_eb)
         blob = hsz_encode.serialize(c)
         shard = CompressedShard(dataset, field, blob)
@@ -134,7 +128,7 @@ class ScientificStore:
 
     def raw(self, dataset: str, field: int) -> jax.Array:
         c = self.get(dataset, field).open()
-        comp = self._compressor(len(c.shape))
+        comp = by_name(self.comp_name)
         return comp.decompress(c, Stage.F)
 
     def normalized_batches(self, dataset: str, field: int, batch: int,

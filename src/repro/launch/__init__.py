@@ -1,1 +1,1 @@
-"""Launchers: mesh construction, multi-pod dry-run, train/serve drivers."""
+"""Entry-point support: compile cache, analytics mesh, HLO/jaxpr byte counts."""

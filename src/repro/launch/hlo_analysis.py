@@ -1,8 +1,9 @@
-"""Post-SPMD HLO cost analyzer with while-loop trip-count propagation.
+"""HBM-bytes proxies of a program: compiled HLO (:func:`analyze`) and
+native-lowering jaxprs holding Pallas kernels (:func:`jaxpr_bytes`).
 
 ``Compiled.cost_analysis()`` counts each computation once, but ``lax.scan``
-lowers to a ``while`` whose body runs L times — so for scan-over-layers
-models it undercounts FLOPs/bytes/collectives by ~L.  This analyzer parses
+lowers to a ``while`` whose body runs L times — so for a loop of L steps
+it undercounts FLOPs/bytes/collectives by ~L.  :func:`analyze` parses
 ``compiled.as_text()`` (the per-device, post-partitioning module):
 
   1. split the module into computation blocks;
@@ -20,6 +21,8 @@ from __future__ import annotations
 
 import re
 from collections import defaultdict
+
+import numpy as np
 
 DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -155,7 +158,7 @@ def _analyze_block(b: Block):
             b.bytes += out_bytes
             continue
         if op == "convolution":
-            # rough: 2 * out_numel * (kernel elems) — rare in these models
+            # rough: 2 * out_numel * (kernel elems) — rare in these programs
             b.dot_flops += 2.0 * out_bytes  # conservative placeholder
             b.bytes += out_bytes
             continue
@@ -165,7 +168,7 @@ def _analyze_block(b: Block):
         if op in ("dynamic-update-slice", "scatter"):
             # in-place update: traffic = the written slice, not the buffer
             # (XLA aliases the operand; counting the output would charge the
-            # whole KV cache per decode step)
+            # whole buffer per update)
             ops_m = re.search(r"\(([^)]*)\)", line)
             if ops_m:
                 operands = [o.strip().lstrip("%") for o in ops_m.group(1).split(",")]
@@ -247,3 +250,53 @@ def analyze(text: str, entry_hint: str = "main") -> dict:
         "collectives": {k: dict(v) for k, v in total["collectives"].items()},
         "wire_bytes_total": wire,
     }
+
+
+#: jaxpr primitives that are elementwise or pure layout — free under the
+#: same fusion assumption :data:`ELEMENTWISE` makes for HLO ops.
+_FREE_PRIMS = frozenset({
+    "add", "sub", "mul", "div", "rem", "neg", "sign", "abs", "max", "min",
+    "and", "or", "xor", "not", "shift_left", "shift_right_logical",
+    "shift_right_arithmetic", "eq", "ne", "lt", "le", "gt", "ge",
+    "select_n", "convert_element_type", "integer_pow", "exp", "log",
+    "sqrt", "rsqrt", "floor", "ceil", "round", "stop_gradient", "copy",
+    "reshape", "squeeze", "broadcast_in_dim", "slice", "concatenate",
+    "pad", "iota", "transpose",
+})
+
+
+def _out_bytes(eqn) -> int:
+    return sum(int(np.prod(v.aval.shape)) * v.aval.dtype.itemsize
+               for v in eqn.outvars)
+
+
+def jaxpr_bytes(jaxpr) -> int:
+    """HBM-bytes proxy of a (native-lowering) jaxpr: summed output bytes of
+    every non-elementwise equation, recursing through call wrappers.
+
+    The counterpart of :func:`analyze` for programs containing
+    ``pallas_call`` equations, which cannot be measured from compiled HLO
+    on CPU: interpret mode emulates the grid with per-step dynamic-slice /
+    full-array dynamic-update-slice pairs whose HLO bytes are pure
+    emulation artifact (24-34x the real kernel I/O).  A pallas_call counts
+    its *outputs* only — its operands are the outputs of counted producers
+    (the payload word gather, halo gathers) or program arguments, exactly
+    as HLO op outputs chain in the proxy.
+    """
+    total = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            total += _out_bytes(eqn)
+            continue
+        subs = []
+        for v in eqn.params.values():
+            for s in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(s, "jaxpr", s)
+                if hasattr(inner, "eqns"):
+                    subs.append(inner)
+        if subs:
+            total += sum(jaxpr_bytes(s) for s in subs)
+        elif name not in _FREE_PRIMS:
+            total += _out_bytes(eqn)
+    return total

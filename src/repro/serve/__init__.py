@@ -1,8 +1,6 @@
-"""Serving: batched token generation + batched homomorphic analytics +
-streaming temporal ingest."""
-from .engine import Engine, Request
+"""Serving: batched homomorphic analytics + streaming temporal ingest."""
 from .analytics import AnalyticsFrontend, AnalyticsRequest, AppendRequest
 from .routing import StoreRouter
 
-__all__ = ["Engine", "Request", "AnalyticsFrontend", "AnalyticsRequest",
-           "AppendRequest", "StoreRouter"]
+__all__ = ["AnalyticsFrontend", "AnalyticsRequest", "AppendRequest",
+           "StoreRouter"]
