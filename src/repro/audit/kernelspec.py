@@ -287,8 +287,8 @@ def _check_input_tiles(ctx: _SpecCtx) -> list[Finding]:
             idx = ctx.poly(tile.index[d])
             blk = ctx.poly(tile.block[d])
             ext = ctx.poly(tile.extent[d])
-            lo = idx * blk
-            hi = ext - idx * blk - blk
+            lo = idx if tile.element else idx * blk
+            hi = ext - lo - blk
             if not (ctx.nonneg(lo) and ctx.nonneg(hi)):
                 bad_dim = d
                 break

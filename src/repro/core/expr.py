@@ -675,6 +675,7 @@ def lower(program: ExprProgram, bindings: Sequence,
     seeds = list(seeds) if seeds is not None else [None] * len(bindings)
     precomputed = precomputed or {}
     ctxs: dict[int, Any] = {}
+    vector_vals: dict[int, dict] = {}
 
     def ctx_for(slot: int):
         if slot not in ctxs:
@@ -709,10 +710,18 @@ def lower(program: ExprProgram, bindings: Sequence,
                     "(see repro.analytics.query / oplib.compute_exprs)")
             return precomputed[s]
         if spec.arity == "vector":
-            cs = ctx_for(slot)
-            for c in cs:
-                oplib._check_feasible(spec, c.scheme, stage)
-            return spec.lower_vector(cs, node.axis)
+            # a bundle's vector ops lower together, once per slot (one
+            # kernel pass where the 3-D vector kernel covers them)
+            if slot not in vector_vals:
+                cs = ctx_for(slot)
+                names = [n for n, _ in program.leaf_consumers(slot)]
+                for name in names:
+                    for c in cs:
+                        oplib._check_feasible(oplib.OPS[name], c.scheme,
+                                              stage)
+                vector_vals[slot] = oplib.lower_vectors(cs, names,
+                                                        node.axis)
+            return vector_vals[slot][spec.name]
         ctx = ctx_for(slot)
         oplib._check_feasible(spec, ctx.scheme, stage)
         family = "lorenzo" if ctx.scheme.is_lorenzo else "blockmean"

@@ -32,6 +32,27 @@ Statistics (mean/std) are likewise uncovered: their flat whole-extent
 f32 reductions cannot be reproduced bitwise by a tile-wise kernel
 accumulation.
 
+The 3-D vector operators have one kernel of their own, outside the
+per-op cells: :func:`vector3d` lowers a 3-component vector's whole set of
+divergence and curl in one ``kernels.fused.vector3d_q`` pass, chosen by
+``oplib.lower_vectors`` where :func:`covers_vector3d` accepts the
+components:
+
+==============  ==========================================================
+op set          3 components of rank 3, any scheme, stages ③ ④
+==============  ==========================================================
+divergence      full field, equal shapes, rows in multiples of 8 (at
+curl            least 16), lanes in multiples of 128 (``vector3d_tiles``)
+==============  ==========================================================
+
+It departs from the rule below that the multiply and the slice run
+outside: its outputs are the stencil interior, so integer outputs with
+the float tail in XLA would add a pass over all of them (2.1 GB at NYX's
+512^3).  It keeps the rules' bits another way: the kernel moves only
+integers (the shifted differences), so its float ops are the rules' own
+expressions — one cast and one multiply per term, added in the rules'
+order — and XLA's CPU backend contracts both alike.
+
 Bit-identity contract: every covered cell's fused output equals the XLA
 rule's output *bitwise* (``np.testing.assert_array_equal``), full-field
 and region-windowed, Compressed and Encoded — and the identity must hold
@@ -228,3 +249,41 @@ LAPLACIAN: dict[tuple[Stage, str], FusedRule] = {
     (Stage.Q, "blockmean"): _rule(_lap_blockmean_q, Stage.Q),
     (Stage.F, "blockmean"): _rule(_lap_blockmean_q, Stage.F),
 }
+
+
+# -- 3-D vector operators ----------------------------------------------------
+
+#: the vector ops :func:`vector3d` writes, and the planes each one takes
+VECTOR_OUTPUTS = {"divergence": 1, "curl": 3}
+
+
+def covers_vector3d(ctxs, names) -> bool:
+    """Can one :func:`vector3d` pass serve the op set ``names`` over the
+    component contexts ``ctxs``?  Three components at stage ③ or ④ (both
+    read ``q_spatial``), full field, one shape the kernel tiles.  Region
+    windows, stage ② and 2-component curl keep the XLA rules."""
+    if len(ctxs) != 3 or not set(names) <= set(VECTOR_OUTPUTS):
+        return False
+    if any(c.stage not in (Stage.Q, Stage.F) or c.plan is not None
+           for c in ctxs):
+        return False
+    shapes = {tuple(c.field.shape) for c in ctxs}
+    n_out = sum(VECTOR_OUTPUTS[n] for n in names)
+    return (len(shapes) == 1
+            and fk.vector3d_tiles(shapes.pop(), n_out) is not None)
+
+
+def vector3d(ctxs, names) -> dict:
+    """``{op: result}`` for divergence and/or curl of a 3-D vector in one
+    kernel pass over the components' ``q_spatial`` planes, bitwise the
+    ``oplib`` rules' (see the module docstring)."""
+    div, curl = "divergence" in names, "curl" in names
+    eps = jnp.stack([jnp.asarray(c.eps, jnp.float32) for c in ctxs])
+    outs = iter(fk.vector3d_q(*(c.q_spatial for c in ctxs), eps, div=div,
+                              curl=curl, interpret=_interpret()))
+    out = {}
+    if div:
+        out["divergence"] = next(outs)
+    if curl:
+        out["curl"] = (next(outs), next(outs), next(outs))
+    return out

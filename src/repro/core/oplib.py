@@ -45,6 +45,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ops as kernel_ops
 
 from . import blocking, quantize
@@ -699,6 +700,24 @@ def _curl_vector(ctxs: Sequence[StageContext], axis: int):
     )
 
 
+def lower_vectors(ctxs: Sequence[StageContext], names: Sequence[str],
+                  axis: int = 0, *, vector_kernel: bool = True) -> dict:
+    """Lower a vector leaf's whole op set on its component contexts:
+    ``{op: result}``.  Where the kernels are on and
+    :func:`repro.core.fused.covers_vector3d` accepts the components (3-D,
+    stage ③/④, full field), divergence and curl come from one kernel pass
+    over the three ``q_spatial`` planes, bitwise the rules'; a build on
+    that branch counts in ``obs.counters["vector_fused"]``.  Elsewhere, and
+    where the caller asks for the rules (``vector_kernel=False``: the
+    sharded store's programs), each op's ``lower_vector`` rule runs."""
+    names = canonical_ops(names)
+    if (vector_kernel and kernel_ops.kernels_enabled()
+            and fused_mod.covers_vector3d(ctxs, names)):
+        obs.counters["vector_fused"] += 1
+        return fused_mod.vector3d(ctxs, names)
+    return {n: OPS[n].lower_vector(ctxs, axis) for n in names}
+
+
 def _div_axes(n_components: int) -> tuple[tuple[int, ...], ...]:
     return tuple((i,) for i in range(n_components))
 
@@ -980,7 +999,9 @@ def reads_seed(op: str, c: Field, stage: Stage, *, region=None,
     where the XLA rule still recorrelates it (:attr:`OpSpec.recorrelates`),
     nor where the fused rule covering the context decodes the payload
     instead (:attr:`~repro.core.fused.FusedRule.reads_seed`).  Vector ops
-    dispatch the derivative cells per component (:func:`_derivative_at`)."""
+    dispatch the derivative cells per component (:func:`_derivative_at`),
+    or at ③/④ in 3-D the vector kernel (:func:`lower_vectors`), which
+    reads ``q_spatial`` and so the seed."""
     spec = _ALL_OPS[op]
     stage, fam = Stage(stage), family_of(c.scheme)
     cells = spec.fused if spec.arity == "field" else fused_mod.DERIVATIVE
@@ -1258,7 +1279,8 @@ def _check_feasible(spec: OpSpec, scheme: Scheme, stage: Stage) -> None:
 
 def compute(target, ops: str | Sequence[str], stage: Stage, *,
             axis: int = 0, region: R.RegionSpec | None = None,
-            seed=None, payload_words=None) -> dict[str, jax.Array]:
+            seed=None, payload_words=None,
+            vector_kernel: bool = True) -> dict[str, jax.Array]:
     """Lower an op set onto one shared stage reconstruction.
 
     ``target`` is a single :class:`Compressed`/:class:`Encoded` field for
@@ -1278,6 +1300,9 @@ def compute(target, ops: str | Sequence[str], stage: Stage, *,
     ``target.payload`` — the sharded store's scatter/psum word merge
     produces exactly this set (``repro.shard.exec``).  Requires
     ``region`` and :class:`Encoded` targets.
+
+    ``vector_kernel=False`` keeps a vector set on its per-op rules where
+    the 3-D vector kernel would cover it (:func:`lower_vectors`).
     """
     stage = Stage(stage)
     names = canonical_ops(ops)
@@ -1304,7 +1329,7 @@ def compute(target, ops: str | Sequence[str], stage: Stage, *,
                 f"{len(words)} payload word sets for {len(comps)} components")
         ctxs = [StageContext(c, stage, region, cl, seed=s, words=w)
                 for c, cl, s, w in zip(comps, closures, seeds, words)]
-        return {spec.name: spec.lower_vector(ctxs, axis) for spec in specs}
+        return lower_vectors(ctxs, names, axis, vector_kernel=vector_kernel)
 
     c = target
     for spec in specs:

@@ -54,7 +54,10 @@ Design constraints (why these kernels look the way they do):
   divergence.  The block-mean laplacians are the one exception — their
   contract is a specific f32 accumulation *sequence* — so they emit that
   f32 sum (final op an add, same producer pattern as the XLA rule) and
-  leave only the eps multiply outside.
+  leave only the eps multiply outside.  The 3-D vector kernel
+  (:func:`vector3d_q`) runs its whole float tail inside: its outputs are
+  the stencil interior, and it moves only integers, so its float ops are
+  the rules' own expressions (see its docstring).
 
 * **Full-shape outputs, window slicing outside.**  Stencil-then-slice
   equals slice-then-stencil for every interior element, so kernels emit
@@ -679,3 +682,136 @@ def lorenzo3d_q(payload: jax.Array, shape: tuple, bits: int, *,
         name="lorenzo3d_q",
     )(*args)
     return out[:n0]
+
+
+# ---------------------------------------------------------------------------
+# 3-D vector operators: three stage-③ planes -> divergence and curl
+# ---------------------------------------------------------------------------
+
+VECTOR_ROWS = 32         # output rows of one grid step (a multiple of 8)
+VECTOR_BYTES = 12 << 20  # VMEM of one grid step's blocks, double-buffered
+
+
+def _vector3d_step_bytes(k: int, r: int, n2: int, n_out: int) -> int:
+    """VMEM of one grid step: three ``(k+2, r+8, n2)`` int32 input
+    windows, ``n_out`` ``(k, r, n2-2)`` f32 output blocks (lanes padded to
+    whole 128-lane tiles) and the ``(24, n2)`` eps tile, each
+    double-buffered."""
+    lanes_out = -(-(n2 - 2) // LANES) * LANES
+    return 2 * 4 * (3 * (k + 2) * (r + SUBLANES) * n2 + n_out * k * r
+                    * lanes_out + 3 * SUBLANES * n2)
+
+
+def vector3d_tiles(shape: tuple, n_out: int) -> tuple[int, int] | None:
+    """``(k, r)`` of :func:`vector3d_q` over component planes of ``shape``:
+    ``k`` output planes (the largest divisor of ``n0 - 2`` whose step fits
+    :data:`VECTOR_BYTES`) by ``r`` output rows a grid step, or ``None``
+    when the kernel does not cover the shape: rank 3, rows in whole
+    sublane tiles, at least 16 of them, and lanes in whole 128-lane
+    tiles."""
+    if len(shape) != 3:
+        return None
+    n0, n1, n2 = shape
+    if n0 < 3 or n1 < 2 * SUBLANES or n1 % SUBLANES or n2 % LANES:
+        return None
+    r = min(VECTOR_ROWS, n1 - SUBLANES)
+    fits = [k for k in range(1, n0 - 1) if (n0 - 2) % k == 0
+            and _vector3d_step_bytes(k, r, n2, n_out) <= VECTOR_BYTES]
+    return (max(fits), r) if fits else None
+
+
+def _vector3d_kernel(e_ref, u_ref, v_ref, w_ref, *o_refs, k: int, r: int,
+                     n2: int, div: bool, curl: bool):
+    """``k`` output planes of ``r`` rows each, plane by plane and 8 rows
+    at a time: each central difference the ops need as an exact int32
+    difference, moved onto the output's lanes, then one cast and one
+    multiply by its component's eps, and the rules' adds and subtracts in
+    their order, written as the ``n2 - 2`` interior lanes.  Window row
+    ``i`` holds output row ``i``'s ``-1`` neighbour, and lane ``j`` output
+    lane ``j``'s, so rows are read one or two down and lanes rotated.  All
+    data movement is on integers: the float ops form the rules' own
+    expressions, which XLA's CPU backend then contracts alike."""
+    eps = [e_ref[SUBLANES * c:SUBLANES * (c + 1), :] for c in range(3)]
+    refs = (u_ref, v_ref, w_ref)
+    terms = set()
+    if div:
+        terms |= {(0, 0), (1, 1), (2, 2)}
+    if curl:
+        terms |= {(2, 1), (1, 2), (0, 2), (2, 0), (1, 0), (0, 1)}
+
+    def plane(t, carry):
+        for o in range(0, r, SUBLANES):
+            rows = {}
+
+            def at(c, dt, dr):
+                if (c, dt, dr) not in rows:
+                    rows[c, dt, dr] = refs[c][t + dt,
+                                              o + dr:o + dr + SUBLANES, :]
+                return rows[c, dt, dr]
+
+            f = {}
+            for c, a in sorted(terms):
+                if a == 0:
+                    d = pltpu.roll(at(c, 2, 1) - at(c, 0, 1), n2 - 1, 1)
+                elif a == 1:
+                    d = pltpu.roll(at(c, 1, 2) - at(c, 1, 0), n2 - 1, 1)
+                else:
+                    x = at(c, 1, 1)
+                    d = pltpu.roll(x, n2 - 2, 1) - x
+                f[c, a] = (d.astype(jnp.float32) * eps[c])[:, :n2 - 2]
+            outs = iter(o_refs)
+            if div:
+                next(outs)[t, o:o + SUBLANES, :] = f[0, 0] + f[1, 1] + f[2, 2]
+            if curl:
+                next(outs)[t, o:o + SUBLANES, :] = f[2, 1] - f[1, 2]
+                next(outs)[t, o:o + SUBLANES, :] = f[0, 2] - f[2, 0]
+                next(outs)[t, o:o + SUBLANES, :] = f[1, 0] - f[0, 1]
+        return carry
+
+    jax.lax.fori_loop(0, k, plane, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("div", "curl", "interpret"))
+def vector3d_q(u: jax.Array, v: jax.Array, w: jax.Array, eps: jax.Array, *,
+               div: bool, curl: bool, interpret: bool = False):
+    """Divergence and/or curl of a 3-component vector from its stage-③
+    planes (int32, one shape) and the components' ``eps`` (f32, ``(3,)``),
+    in one pass: ``(div,)``, ``(cx, cy, cz)`` or ``(div, cx, cy, cz)``,
+    each the f32 ``(n0-2, n1-2, n2-2)`` interior.  Bitwise the XLA rules'
+    ``(q[i+1] - q[i-1]).astype(f32) * eps`` terms, summed in their order.
+
+    Unlike the 2-D kernels, the float tail runs here: the outputs are the
+    interior, so integer outputs with the multiply and the crop in XLA
+    would add a pass over the whole output.  Every grid step reads the
+    ``(k+2, r+8, n2)`` window of each plane it needs (the planes and rows
+    after its ``k`` by ``r`` output block are its halos) and nothing
+    carries from one step to the next, so ``vmap`` may add a grid axis.
+    """
+    shape = u.shape
+    n0, n1, n2 = shape
+    n_out = (1 if div else 0) + (3 if curl else 0)
+    tiles = vector3d_tiles(shape, n_out)
+    if tiles is None or not n_out:
+        raise ValueError(f"vector3d_q does not cover {shape}")
+    k, r = tiles
+    nb = -(-(n1 - 2) // r)
+    pad = max(0, nb * r + SUBLANES - n1)
+    window = pl.BlockSpec(
+        (pl.Element(k + 2), pl.Element(r + SUBLANES, (0, pad)),
+         pl.Element(n2)),
+        lambda b, c: (b * k, c * r, 0))
+    block = pl.BlockSpec((k, r, n2 - 2), lambda b, c: (b, c, 0))
+    e = jnp.repeat(eps.astype(jnp.float32), SUBLANES)[:, None]
+    e = jnp.broadcast_to(e, (3 * SUBLANES, n2))
+    out = jax.ShapeDtypeStruct((n0 - 2, n1 - 2, n2 - 2), jnp.float32)
+    return tuple(pl.pallas_call(
+        functools.partial(_vector3d_kernel, k=k, r=r, n2=n2, div=div,
+                          curl=curl),
+        grid=((n0 - 2) // k, nb),
+        in_specs=[pl.BlockSpec((3 * SUBLANES, n2), lambda b, c: (0, 0)),
+                  window, window, window],
+        out_specs=[block] * n_out,
+        out_shape=[out] * n_out,
+        interpret=interpret,
+        name="vector3d_q",
+    )(e, u, v, w))

@@ -42,6 +42,9 @@ class TileSpec:
     ``block`` / ``index`` / ``extent`` are per-dimension expressions:
     the operand's block shape, the *block* index map (what the BlockSpec
     lambda returns for the grid symbols), and the full array extent.
+    ``element`` marks a window of ``pl.Element`` dims: its index map
+    returns element offsets, so windows may overlap (halos), and its
+    extent includes the window's declared padding.
     """
 
     name: str
@@ -49,6 +52,7 @@ class TileSpec:
     index: tuple[str, ...]
     extent: tuple[str, ...]
     dtype_bytes: int = 4
+    element: bool = False
 
 
 @dataclass(frozen=True)
@@ -226,6 +230,37 @@ KERNEL_SPECS: tuple[KernelSpec, ...] = (
               "sequential grid (axis-0 prefix), so the kernel must never "
               "run under vmap: only the store's materialization calls it; "
               "the MXU variant adds the constant (128, 256) [U | J] block",
+    ),
+    # -- 3-D vector operators (divergence, curl) -----------------------------
+    KernelSpec(
+        name="fused.vector3d_q",
+        site=("fused", "vector3d_q", 0),
+        grid=("b", "c"),
+        bounds={"b": ("0", "ng - 1"), "c": ("0", "nb - 1"),
+                "ng": ("1", None), "nb": ("1", None), "k": ("1", None),
+                "rq": ("1", None), "lq": ("1", None)},
+        facts=("n0 == ng*k + 2", "r == 8*rq", "n2 == 128*lq"),
+        inputs=(
+            TileSpec("eps", ("24", "n2"), ("0", "0"), ("24", "n2")),
+            # each component's (k+2, r+8, n2) window at element offsets:
+            # its k planes and r rows, then the next 2 planes and 8 rows
+            # (halos); the rows carry a high padding up to nb*r + 8
+            TileSpec("u", ("k + 2", "r + 8", "n2"), ("b*k", "c*r", "0"),
+                     ("n0", "nb*r + 8", "n2"), element=True),
+            TileSpec("v", ("k + 2", "r + 8", "n2"), ("b*k", "c*r", "0"),
+                     ("n0", "nb*r + 8", "n2"), element=True),
+            TileSpec("w", ("k + 2", "r + 8", "n2"), ("b*k", "c*r", "0"),
+                     ("n0", "nb*r + 8", "n2"), element=True),
+        ),
+        # rows past n1 - 2 of the last band (nb = ceil((n1 - 2) / r)) are
+        # dropped as the block is written back
+        outputs=(TileSpec("out", ("k", "r", "n2 - 2"), ("b", "c", "0"),
+                          ("ng*k", "nb*r", "n2 - 2")),),
+        # the blocks within VECTOR_BYTES = 12 MiB (vector3d_tiles), and
+        # the 8-row values of one step of the unrolled row loop (160 KiB)
+        vmem_elems="3145728 + 40960",
+        notes="one call per op set writes 1 (divergence), 3 (curl) or 4 "
+              "planes through the same output tile spec",
     ),
     # -- bitplane pack / unpack ----------------------------------------------
     KernelSpec(

@@ -40,8 +40,10 @@ storeless planning picks (``repro.analytics.planner``); and the store's
 ``store_materializations_fused`` (built by the one-dispatch 3-D Lorenzo
 kernel, ``repro.store.materialize``), and ``store_evictions`` (cache
 entries dropped, as ``StoreStats.evictions`` counts them), over every
-store.  Spans sit on the host path only: none is
-opened inside traced or jitted code.
+store; ``vector_fused``: programs built with the 3-D divergence/curl
+kernel (``repro.core.oplib.lower_vectors``, counted as each traces).
+Spans sit on the host path only: none is opened inside traced or jitted
+code.
 """
 from __future__ import annotations
 
@@ -68,7 +70,8 @@ RING_SIZE = 1 << 18
 #: store-backed stage promotions of the planner, and store cache churn
 counters = {"jit_hits": 0, "jit_misses": 0, "jit_evictions": 0,
             "plan_resident_promotions": 0, "store_materializations": 0,
-            "store_materializations_fused": 0, "store_evictions": 0}
+            "store_materializations_fused": 0, "store_evictions": 0,
+            "vector_fused": 0}
 
 _ID = {name: i for i, name in enumerate(NAMES)}
 _UNSTEPPED = frozenset({STORE_MATERIALIZE})   # nested: no step serial

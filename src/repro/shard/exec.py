@@ -110,11 +110,12 @@ def region_program(mesh, names: tuple[str, ...], stage: Stage, axis: int,
             merged.append(jax.lax.psum(buf[:n_out], SHARD_AXIS))
         if norm is None:
             # full field: the merge reassembles the entire payload
-            # exactly, so the standard full decode runs unchanged
+            # exactly, so the standard full decode runs unchanged, and
+            # vector sets keep their per-op rules
             full = tuple(dataclasses.replace(ec, payload=m)
                          for ec, m in zip(ecs, merged))
             return oplib.compute(full if vector else full[0], names, stage,
-                                 axis=axis)
+                                 axis=axis, vector_kernel=False)
         return oplib.compute(tuple(ecs) if vector else ecs[0], names, stage,
                              axis=axis, region=norm,
                              payload_words=merged if vector else merged[0])

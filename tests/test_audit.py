@@ -689,6 +689,21 @@ class TestKernelSpecAnalyzer:
                                                           row)))
         assert [f.invariant for f in fs] == ["block-tiling"]
 
+    @pytest.mark.parametrize("change", ["no_padding", "block_index"])
+    def test_element_window_escape_one_finding(self, change):
+        # the vector kernel's (k+2, r+8, n2) halo windows: without the 8
+        # rows of padding the last band's window runs past the plane, and
+        # read as block indices its offsets land k+2 planes apart
+        spec = next(s for s in KERNEL_SPECS if s.name == "fused.vector3d_q")
+        assert kernelspec.check_spec(spec) == []
+        eps, u, *rest = spec.inputs
+        if change == "no_padding":
+            u = replace(u, extent=(u.extent[0], "nb*r", u.extent[2]))
+        else:
+            u = replace(u, element=False)
+        fs = kernelspec.check_spec(replace(spec, inputs=(eps, u, *rest)))
+        assert [f.invariant for f in fs] == ["tile-out-of-bounds"]
+
     def test_unpack_lemma_pins_word_window_slack(self):
         assert kernelspec.check_unpack_lemma(2) == []
         fs = kernelspec.check_unpack_lemma(1)

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import Stage, homomorphic as H, hszp, hszp_nd, hszx, hszx_nd
 
 ALL = [hszp, hszx, hszp_nd, hszx_nd]
@@ -161,3 +162,37 @@ def test_stats_linear_field_exact(comp):
     for stage in (Stage.P, Stage.Q, Stage.F):
         got = float(H.std(c, stage))
         assert abs(got - expect_std) <= max(1e-4 * expect_std, 1e-2), (stage, got)
+
+
+def _grid_3d(shape):
+    return [g.astype(np.float32)
+            for g in np.meshgrid(*map(np.arange, shape), indexing="ij")]
+
+
+#: a shape the 3-D vector kernel covers (rows in 8s, lanes in 128s)
+KERNEL_SHAPE = (6, 16, 128)
+
+
+@pytest.mark.parametrize("oracle", ["rigid_rotation", "radial"])
+@pytest.mark.parametrize("comp", ALL, ids=lambda c: c.scheme.value)
+def test_vector_3d_oracles_on_the_kernel_path(comp, oracle):
+    """The same oracles where ``kernels.fused.vector3d_q`` answers (stages
+    ③ ④): rotation about z, F = (-y, x, 0), has curl (0, 0, 2); the
+    radial F = (x, y, z) has divergence 3."""
+    i, j, k = _grid_3d(KERNEL_SHAPE)
+    if oracle == "rigid_rotation":
+        fields, op = (-j, i, np.zeros_like(i)), H.curl
+        want = (0.0, 0.0, 2.0)
+    else:
+        fields, op = (i, j, k), H.divergence
+        want = (3.0,)
+    comps = [_compress(comp, f) for f in fields]
+    interior = tuple(d - 2 for d in KERNEL_SHAPE)
+    for stage in (Stage.Q, Stage.F):
+        fused0 = obs.counters["vector_fused"]
+        got = op(comps, stage)
+        assert obs.counters["vector_fused"] == fused0 + 1
+        got = got if isinstance(got, tuple) else (got,)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.full(interior, w),
+                                       rtol=1e-5, atol=1e-3)

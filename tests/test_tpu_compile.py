@@ -10,6 +10,10 @@ with a batch of 2, which gives the Pallas grid a batch dimension.  The
 sharded word-merge programs (uint32 scatter-add + ``psum``) compile over a
 ``v5e:2x2`` mesh.
 
+One case runs only on a chip: the 3-D vector kernel against the XLA
+rules, bitwise, at 512^3 (``test_vector3d_q_bitwise_on_chip``; it skips
+elsewhere, where its compile cases stand for it).
+
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and it keeps it until it
 exits.  The kernels are called with ``interpret=False`` because the default
@@ -161,3 +165,55 @@ def test_lorenzo3d_q_program_compiles_for_v5e(one_chip, padded, shape):
                                          shape))
     compiled = fn.lower(words).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+NYX = (512, 512, 512)
+
+
+def _vector_rules(u, v, w, eps, div, curl):
+    """The XLA rules of ``oplib`` at stage ③ in 3-D, term for term."""
+    def d(q, a, e):
+        return oplib._central_diff(q, a, e)
+
+    out = ()
+    if div:
+        out += (d(u, 0, eps[0]) + d(v, 1, eps[1]) + d(w, 2, eps[2]),)
+    if curl:
+        out += (d(w, 1, eps[2]) - d(v, 2, eps[1]),
+                d(u, 2, eps[0]) - d(w, 0, eps[2]),
+                d(v, 0, eps[1]) - d(u, 1, eps[0]))
+    return out
+
+
+@pytest.mark.parametrize("batch", [None, 2], ids=["solo", "vmap2"])
+@pytest.mark.parametrize("div,curl", [(True, True), (True, False),
+                                      (False, True)],
+                         ids=["div+curl", "div", "curl"])
+def test_vector3d_q_compiles_for_v5e(one_chip, div, curl, batch):
+    """The divergence/curl kernel at NYX's 512^3, as ``compute`` and the
+    engine's ``vmap`` run it: one kernel call for the whole op set."""
+    fn = lambda u, v, w, e: fk.vector3d_q(u, v, w, e, div=div, curl=curl)
+    if batch is not None:
+        fn = jax.vmap(fn)
+    lead = () if batch is None else (batch,)
+    q = jax.ShapeDtypeStruct(lead + NYX, jnp.int32, sharding=one_chip)
+    e = jax.ShapeDtypeStruct(lead + (3,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(fn).lower(q, q, q, e).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_vector3d_q_bitwise_on_chip():
+    """On the chip: the kernel at 512^3 equals the XLA rules bit for bit.
+    Skipped off the chip (the compile cases above stand for it there)."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs a TPU: runs the kernel at 512^3")
+    keys = jax.random.split(jax.random.PRNGKey(18), 3)
+    u, v, w = (jax.random.randint(k, NYX, -500, 501, jnp.int32)
+               for k in keys)
+    eps = jnp.asarray([1.3e-3, 7.1e-4, 2.9e-3], jnp.float32)
+    for div, curl in [(True, True), (True, False), (False, True)]:
+        got = jax.jit(lambda *a: fk.vector3d_q(*a, div=div, curl=curl))(
+            u, v, w, eps)
+        want = jax.jit(lambda *a: _vector_rules(*a, div, curl))(u, v, w, eps)
+        for g, x in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
